@@ -147,7 +147,6 @@ sanitize-tsan:
 	cd tests/native && ./tsan.sh
 
 bench:
-	python bench.py
 	python benchmarks/bench_consensus_sim.py --n 64 --eras 2
 
 # perf-regression gate: re-run the headline benches and diff them against
@@ -157,8 +156,6 @@ bench:
 # regression cannot hide inside the batch mean; its threshold floor is
 # wider because in-process CPU era walls are noisy.
 bench-gate:
-	python bench.py | tail -n 1 > /tmp/lachain_bench_now.json
-	python benchmarks/compare.py BENCH_r05.json /tmp/lachain_bench_now.json
 	python benchmarks/bench_consensus_sim.py --n 16 --eras 3 --txs 200 \
 		--pipeline-window 1 | tail -n 1 > /tmp/lachain_sim_now.json
 	python benchmarks/compare.py benchmarks/BENCH_sim_gate.json \

@@ -77,11 +77,10 @@ def main() -> None:
         default=0,
         help="run the TPKE era batches on a ('slot' x 'share') device mesh "
         "(parallel/mesh.MeshEraPipeline): forces the TPU backend + device "
-        "routing for every era batch, and — when the platform is CPU — "
-        "forces this many virtual host devices via XLA_FLAGS. On real "
-        "multi-device hardware the mesh is selected automatically; this "
-        "flag exists to exercise the mesh path anywhere. 0 = default "
-        "backend selection",
+        "routing for every era batch. With JAX_PLATFORMS=cpu given, splits "
+        "the host into this many virtual devices via XLA_FLAGS; without "
+        "it the mesh runs on the real devices and a CPU landing is an "
+        "error. 0 = default backend selection",
     )
     ap.add_argument(
         "--rbc-batch",
@@ -107,12 +106,13 @@ def main() -> None:
 
     if args.mesh_devices > 0:
         # BEFORE any jax import: route era batches to the device pipeline
-        # (the mesh is selected whenever >1 device is visible) and, on
-        # CPU-only hosts, split the host platform into virtual devices
+        # (the mesh is selected whenever >1 device is visible). Virtual
+        # host devices are split off ONLY when the CPU was asked for by
+        # name; otherwise the mesh runs on the real devices, and a process
+        # that lands on the CPU anyway is refused (provider.open_device)
         os.environ["LACHAIN_TPU_BACKEND"] = "tpu"
         os.environ.setdefault("LTPU_TPU_MIN_LANES", "1")
-        if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-            os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
             flags = os.environ.get("XLA_FLAGS", "")
             if "--xla_force_host_platform_device_count" not in flags:
                 os.environ["XLA_FLAGS"] = (
@@ -132,7 +132,7 @@ def main() -> None:
 
     if args.mesh_devices > 0:
         # precompile the mesh-shaped era kernels off the clock (one entry
-        # per (mesh shape, s_pad, k_pad) tier, persisted via kernel_cache)
+        # per (mesh shape, s_pad, k_pad) tier)
         from lachain_tpu.crypto.provider import get_backend
         from lachain_tpu.crypto.warmup import warmup_era_kernels
 

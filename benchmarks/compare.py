@@ -5,8 +5,8 @@ Usage: python benchmarks/compare.py BASELINE CURRENT [--min-threshold-pct P]
 Both inputs accept any of the shapes the bench drivers emit:
   - a bare result object (one JSON line from bench.py /
     bench_consensus_sim.py),
-  - the driver wrapper {"cmd", "rc", "tail", "parsed": {...}} checked in
-    as BENCH_r05.json (the parsed object is used),
+  - a driver wrapper {"cmd", "rc", "tail", "parsed": {...}} (the parsed
+    object is used),
   - a text file whose LAST line is the JSON result (bench stdout piped
     through tee), or "-" for stdin.
 
@@ -16,7 +16,7 @@ lower is better), plus every shared latency side-channel field
 (tpu_era_s, per_node_normalized_latency_s, …). The allowed delta per
 field is max(--min-threshold-pct, baseline trial_spread_pct, current
 trial_spread_pct) — the PR-4 noise fields, so a wide-spread run widens
-its own gate instead of false-failing on tunnel noise.
+its own gate instead of false-failing on noise.
 
 Exit codes: 0 = within thresholds, 1 = regression, 2 = input/schema
 error. Wired into `make bench-gate`.

@@ -211,18 +211,11 @@ class TpkeEraBatcher:
             for o, fin in inflight:
                 out = fin()
                 results[o : o + len(out)] = out
-        except Exception:
+        except BaseException:
+            # a failed era call is an error, not a cue for the per-slot
+            # host path: close the span and let it propagate
             tracing.end(sid, outcome="exception")
-            # device path broken mid-flush: liveness beats acceleration —
-            # every submitter falls back to its per-slot host path
-            import logging
-
-            logging.getLogger("lachain.consensus").exception(
-                "era batch flush failed; host fallback"
-            )
-            for (_jobs, _vks, cb) in batch:
-                cb(None)
-            return len(batch)
+            raise
         # the device kernel pads each chunk's slot axis to a power of two:
         # pad-waste = fraction of padded lanes burnt on dummy slots —
         # the number that tunes max_slots_per_call

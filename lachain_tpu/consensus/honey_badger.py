@@ -199,22 +199,13 @@ class HoneyBadger(Protocol):
         if built is None:
             return
         jobs, vks, cb = built
-        try:
-            with tracing.span(
-                "hb.era_decrypt",
-                cat="crypto",
-                era=self.id.era,
-                slots=len(jobs),
-            ):
-                results = era_fn(jobs, vks)
-        except Exception:
-            # device path unavailable/broken (jax import, compile, OOM):
-            # consensus liveness beats acceleration — host per-slot path
-            from .protocol import logger as _plog
-
-            _plog.exception("tpu era decrypt failed; host fallback")
-            cb(None)
-            return
+        with tracing.span(
+            "hb.era_decrypt",
+            cat="crypto",
+            era=self.id.era,
+            slots=len(jobs),
+        ):
+            results = era_fn(jobs, vks)
         cb(results)
 
     def _build_era_jobs_lazy(self):
@@ -282,16 +273,11 @@ class HoneyBadger(Protocol):
         )
 
     def _era_results_cb(self, ready, results) -> None:
-        """Batcher flush callback: results is None when the batch call
-        itself failed (host per-slot fallback), else per-job (ok, combined)."""
+        """Batcher flush callback: per-job (ok, combined) results."""
         self._inflight.difference_update(ready)
         if self.terminated or self._done:
             return
-        if results is None:
-            for slot in ready:
-                self._try_decrypt(slot)
-        else:
-            self._apply_era_results(ready, results)
+        self._apply_era_results(ready, results)
         # slots whose batch failed may have pruned a share but still hold
         # (or later regain) a quorum: re-queue whatever remains ready
         self._try_decrypt_ready()

@@ -332,26 +332,24 @@ class HoneyBadgerHost:
             self._post(PO_HB_CLEAR_INFLIGHT, a=slot)
         if self.done:
             return
-        if results is None:
-            for slot in ready:
-                self._try_decrypt(slot)
-        else:
-            with tracing.span(
-                "hb.apply_era_results",
-                cat="crypto",
-                era=self.id.era,
-                slots=len(ready),
-            ):
-                for slot, (ok, combined) in zip(ready, results):
-                    if ok:
-                        self._resolve(
-                            slot,
-                            tpke.decrypt_with_combined(
-                                self._ciphertexts[slot], combined
-                            ),
-                        )
-                    else:
-                        self._try_decrypt(slot)
+        with tracing.span(
+            "hb.apply_era_results",
+            cat="crypto",
+            era=self.id.era,
+            slots=len(ready),
+        ):
+            for slot, (ok, combined) in zip(ready, results):
+                if ok:
+                    self._resolve(
+                        slot,
+                        tpke.decrypt_with_combined(
+                            self._ciphertexts[slot], combined
+                        ),
+                    )
+                else:
+                    # the batch REJECTED the slot: prune the bad share on
+                    # the per-share host path
+                    self._try_decrypt(slot)
         self._post(PO_HB_REQUEUE_CHECK)
 
     def _resolve(self, slot: int, plaintext: Optional[bytes]) -> None:
@@ -386,7 +384,7 @@ class HoneyBadgerHost:
         return failures
 
     def _try_decrypt(self, slot: int) -> None:
-        # honey_badger.py::_try_decrypt (host per-slot fallback path)
+        # honey_badger.py::_try_decrypt (per-slot host path: prunes bad shares)
         if slot in self._plaintexts:
             return
         need = self._pub.f + 1

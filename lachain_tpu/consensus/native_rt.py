@@ -25,12 +25,12 @@ from __future__ import annotations
 import ctypes
 import os
 import struct
-import subprocess
 import weakref
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Set
 
 from ..utils import metrics, tracing
+from ..utils.native_build import ensure_built
 from . import messages as M
 from .era import EraRouter
 from .keys import PrivateConsensusKeys, PublicConsensusKeys
@@ -60,7 +60,6 @@ from .native_hosts import (
 from .simulator import DeliveryMode
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libconsensus_rt.so")
 
 # opaque payload kinds (shared contract with consensus_rt.cpp MT_OPAQUE)
 KIND_DECRYPTED = 0
@@ -118,21 +117,10 @@ def load_rt():
         return _lib_cache[0]
     # LACHAIN_CONSENSUS_LIB loads an alternate engine build verbatim (the
     # ASan/TSan gates in tests/native/ point it at instrumented builds) —
-    # no mtime-rebuild, same contract as LACHAIN_LSM_LIB in storage/lsm.py
-    override = os.environ.get("LACHAIN_CONSENSUS_LIB")
-    lib_path = override or _LIB_PATH
-    if not override:
-        sources = [
-            os.path.join(_NATIVE_DIR, "consensus_rt.cpp"),
-            os.path.join(_NATIVE_DIR, "Makefile"),
-        ]
-        if not os.path.exists(_LIB_PATH) or any(
-            os.path.getmtime(_LIB_PATH) < os.path.getmtime(s) for s in sources
-        ):
-            subprocess.run(
-                ["make", "-s", "-C", _NATIVE_DIR], check=True,
-                capture_output=True,
-            )
+    # no rebuild, same contract as LACHAIN_LSM_LIB in storage/lsm.py
+    lib_path = os.environ.get("LACHAIN_CONSENSUS_LIB") or ensure_built(
+        _NATIVE_DIR, "libconsensus_rt.so"
+    )
     lib = ctypes.CDLL(lib_path)
     lib.lt_crt_version.restype = ctypes.c_int
     _crt_ver = lib.lt_crt_version()

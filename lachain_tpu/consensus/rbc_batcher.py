@@ -19,11 +19,12 @@ Two structural wins beyond the fused call:
   post-recheck verdict per (root, k, n) per era and fans it out — n
   interpolations become 1.
 
-* Verdict-identical fallback. Any batch-path failure replays the exact
-  scalar sequence the inline protocol would have run (rs.reencode ->
-  Merkle recheck -> rs.decode), so enabling the batcher can never change a
-  deliver/bad-root decision — tests/test_rs_batch.py pins block-hash
-  identity batched-vs-serial on both engines.
+* Verdict identity. The batched path computes exactly the verdict the
+  inline protocol's scalar sequence would (rs.reencode -> Merkle recheck ->
+  rs.decode; `scalar_verdict` below is that sequence, used inline where
+  no batcher is attached) — tests/test_rs_batch.py pins block-hash identity
+  batched-vs-serial on both engines. A failure inside the batched path is
+  an error and propagates; it is not replayed on the scalar path.
 
 Callback contract: `cb(payload_or_None)` for interpolations (None = bad
 root), `cb(shards_list)` for encodes. Callbacks run inside flush and may
@@ -31,14 +32,11 @@ enqueue further protocol traffic (READY sends, deliveries).
 """
 from __future__ import annotations
 
-import logging
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional
 
 from ..crypto import hashes
 from ..ops import rs, rs_batch
 from ..utils import metrics, tracing
-
-logger = logging.getLogger("lachain.consensus")
 
 _BATCH_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
 
@@ -172,13 +170,9 @@ class RbcEraBatcher:
     def _run_encodes(self, era: int, encs: List[tuple]) -> List[List[bytes]]:
         if not encs:
             return []
-        try:
-            return rs_batch.encode_batch(
-                [(v, k, n) for (v, k, n, _cb) in encs], era=era
-            )
-        except Exception:
-            logger.exception("batched RS encode failed; scalar fallback")
-            return [rs.encode(v, k, n) for (v, k, n, _cb) in encs]
+        return rs_batch.encode_batch(
+            [(v, k, n) for (v, k, n, _cb) in encs], era=era
+        )
 
     def _run_interps(
         self, era: int, uniq: Dict[tuple, tuple], order: List[tuple]
@@ -186,37 +180,27 @@ class RbcEraBatcher:
         verdicts: Dict[tuple, Optional[bytes]] = {}
         if not order:
             return verdicts
-        try:
-            payloads = rs_batch.decode_batch(
-                [(uniq[key][0], uniq[key][1]) for key in order], era=era
+        payloads = rs_batch.decode_batch(
+            [(uniq[key][0], uniq[key][1]) for key in order], era=era
+        )
+        # re-encode the successful reconstructions in one batch, then
+        # recheck every Merkle commitment with ONE fused keccak call
+        payload_of = dict(zip(order, payloads))
+        ok_keys = [key for key, p in zip(order, payloads) if p is not None]
+        reenc = rs_batch.encode_batch(
+            [(payload_of[key], key[1], key[2]) for key in ok_keys],
+            era=era,
+        )
+        flat = [s for shards in reenc for s in shards]
+        flat_leaves = hashes.keccak256_batch(flat)
+        off = 0
+        roots_ok = {}
+        for key, shards in zip(ok_keys, reenc):
+            leaves = flat_leaves[off : off + len(shards)]
+            off += len(shards)
+            roots_ok[key] = hashes.merkle_root(leaves) == key[0]
+        for key, payload in zip(order, payloads):
+            verdicts[key] = (
+                payload if payload is not None and roots_ok[key] else None
             )
-            # re-encode the successful reconstructions in one batch, then
-            # recheck every Merkle commitment with ONE fused keccak call
-            payload_of = dict(zip(order, payloads))
-            ok_keys = [
-                key for key, p in zip(order, payloads) if p is not None
-            ]
-            reenc = rs_batch.encode_batch(
-                [(payload_of[key], key[1], key[2]) for key in ok_keys],
-                era=era,
-            )
-            flat = [s for shards in reenc for s in shards]
-            flat_leaves = hashes.keccak256_batch(flat)
-            off = 0
-            roots_ok = {}
-            for key, shards in zip(ok_keys, reenc):
-                leaves = flat_leaves[off : off + len(shards)]
-                off += len(shards)
-                roots_ok[key] = hashes.merkle_root(leaves) == key[0]
-            for key, payload in zip(order, payloads):
-                verdicts[key] = (
-                    payload if payload is not None and roots_ok[key] else None
-                )
-        except Exception:
-            logger.exception(
-                "batched RS interpolate failed; scalar fallback"
-            )
-            for key in order:
-                shards, k, root = uniq[key]
-                verdicts[key] = scalar_verdict(shards, k, root)
         return verdicts

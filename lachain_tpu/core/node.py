@@ -327,15 +327,11 @@ class Node:
             self._protocol_watchdog()
         )
         # TPU backends: precompile the era-kernel shapes for this validator
-        # set in the background so the first eras don't stall on Mosaic
-        # compiles (35-110 s/shape; crypto/warmup.py). Host backends: no-op.
-        try:
-            from ..crypto.warmup import warmup_era_kernels
+        # set in the background so the first eras don't stall on tracing
+        # and compiling them (crypto/warmup.py). Host backends: no-op.
+        from ..crypto.warmup import warmup_era_kernels
 
-            self._warmup_thread = warmup_era_kernels(self.public_keys.n)
-        except Exception:  # pragma: no cover - warmup must never block start
-            logger.exception("kernel warmup failed to start")
-            self._warmup_thread = None
+        self._warmup_thread = warmup_era_kernels(self.public_keys.n)
 
     @property
     def effective_stall_timeout(self) -> float:

@@ -177,7 +177,10 @@ def _native_lib():
 
                 _native_lib_cache[1] = load_lib()
             except Exception:
-                _native_lib_cache[1] = None
+                # a library that fails to build is an error unless the
+                # Python oracle was asked for (crypto/provider.py rule)
+                if _os.environ.get("LACHAIN_TPU_BACKEND") != "python":
+                    raise
     return _native_lib_cache[1]
 
 
@@ -325,32 +328,24 @@ def recover_hash(msg_hash: bytes, sig: bytes) -> Optional[bytes]:
 import os as _os_mod
 
 _TPU_RECOVER_MIN = int(_os_mod.environ.get("LTPU_TPU_ECDSA_MIN", "2048"))
-_tpu_recover_cache = [False, None]
+_tpu_recoverer: list = []  # [TpuEcdsaRecover] once built
 
 
 def _tpu_recover(hashes, sigs):
-    """TPU batch recovery, or None to fall through to the native path."""
-    if not _tpu_recover_cache[0]:
-        _tpu_recover_cache[0] = True
-        try:
-            import jax
+    """TPU batch recovery, or None when this process owns no chip
+    (crypto/provider.device_platform decides; a host-backend process never
+    imports jax here). A failure on the chip propagates."""
+    from .provider import device_platform
 
-            if jax.default_backend() == "tpu":
-                from ..ops.psecp import TpuEcdsaRecover
+    if device_platform() != "tpu":
+        return None
+    if not _tpu_recoverer:
+        from ..ops.psecp import TpuEcdsaRecover
 
-                _tpu_recover_cache[1] = TpuEcdsaRecover()
-        except Exception:
-            _tpu_recover_cache[1] = None
-    rec = _tpu_recover_cache[1]
-    if rec is None:
-        return None
-    try:
-        out = rec.recover_batch(list(hashes), list(sigs))
-        metrics.inc("crypto_tpu_ecdsa_recover_batches_total")
-        return out
-    except Exception:
-        metrics.inc("crypto_tpu_ecdsa_recover_fallbacks_total")
-        return None
+        _tpu_recoverer.append(TpuEcdsaRecover())
+    out = _tpu_recoverer[0].recover_batch(list(hashes), list(sigs))
+    metrics.inc("crypto_tpu_ecdsa_recover_batches_total")
+    return out
 
 
 @metrics.timed("crypto_ec_recover_batch")

@@ -74,7 +74,10 @@ def _native_lib():
 
                 _native_cache[1] = load_lib()
             except Exception:
-                _native_cache[1] = None
+                # a library that fails to build is an error unless the
+                # Python oracle was asked for (crypto/provider.py rule)
+                if _os.environ.get("LACHAIN_TPU_BACKEND") != "python":
+                    raise
     return _native_cache[1]
 
 

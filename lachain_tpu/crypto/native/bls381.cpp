@@ -2770,4 +2770,8 @@ int lt_tpke_verify_shares_serial(const uint8_t *uis, const uint8_t *yis,
 }
 
 int lt_version() { return 1; }
+
+// 1 when the ADX/BMI2 multiplier was compiled in AND passed the start-up
+// self-check (chip_smoke.py prints it: the library is built per machine)
+int lt_have_adx() { return HAVE_ADX ? 1 : 0; }
 }

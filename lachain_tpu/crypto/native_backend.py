@@ -13,46 +13,26 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 from typing import List, Sequence, Tuple
 
 from . import bls12381 as bls
+from ..utils.native_build import ensure_built
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libbls381.so")
-
-
-def _build_if_needed() -> None:
-    # rebuild when ANY source is newer than the .so — a stale library built
-    # before a source file was added would load fine (lt_version exists)
-    # but lack newer symbols, crashing callers with AttributeError
-    import glob
-
-    sources = glob.glob(os.path.join(_NATIVE_DIR, "*.cpp")) + [
-        os.path.join(_NATIVE_DIR, "Makefile")
-    ]
-    if os.path.exists(_LIB_PATH) and all(
-        os.path.getmtime(_LIB_PATH) >= os.path.getmtime(s) for s in sources
-    ):
-        return
-    subprocess.run(
-        ["make", "-s", "-C", _NATIVE_DIR], check=True, capture_output=True
-    )
 
 
 def load_lib():
     # LACHAIN_BLS_LIB loads an alternate backend build verbatim (the
     # ASan/TSan gates in tests/native/ point it at instrumented builds) —
-    # no mtime-rebuild, same contract as LACHAIN_LSM_LIB in storage/lsm.py
-    override = os.environ.get("LACHAIN_BLS_LIB")
-    if override:
-        lib_path = override
-    else:
-        _build_if_needed()
-        lib_path = _LIB_PATH
+    # no rebuild, same contract as LACHAIN_LSM_LIB in storage/lsm.py
+    lib_path = os.environ.get("LACHAIN_BLS_LIB") or ensure_built(
+        _NATIVE_DIR, "libbls381.so"
+    )
     lib = ctypes.CDLL(lib_path)
     lib.lt_version.restype = ctypes.c_int
     assert lib.lt_version() == 1
+    lib.lt_have_adx.restype = ctypes.c_int
+    lib.lt_have_adx.argtypes = []
     return lib
 
 

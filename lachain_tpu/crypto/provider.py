@@ -21,7 +21,7 @@ exactly the shape TPUs are good at.
 from __future__ import annotations
 
 import os
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 from . import bls12381 as bls
 
@@ -184,14 +184,99 @@ def select_distinct(shares, key, count: int):
 
 
 _BACKEND = None
+_DEVICE_PLATFORM: list = []  # [platform] once this process has opened JAX
+
+# <checkout>/.jax_cache: a fixed path derived from the package's own
+# location (never a temp name, a pid or ~), git-ignored
+_DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
+def compile_cache_dir() -> str:
+    """Where compiled device programs persist: $JAX_COMPILATION_CACHE_DIR
+    when set (jax reads it itself; nothing is set in code), else
+    <checkout>/.jax_cache."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_COMPILE_CACHE
+
+
+def open_device() -> str:
+    """Import jax for this process, place its compile cache, and return the
+    platform it resolved to. Called only on behalf of a device backend
+    (TpuBackend); the ONE place that reads jax.default_backend().
+
+    A device was asked for, so landing on the CPU is an error (chip held by
+    another process, libtpu failing to initialise) unless JAX_PLATFORMS
+    names exactly `cpu` — how the tests ask for the host emulation."""
+    if _DEVICE_PLATFORM:
+        return _DEVICE_PLATFORM[0]
+    import jax
+
+    platform = jax.default_backend()
+    if platform == "cpu":
+        # the emulation keeps no compile cache: XLA:CPU executables are
+        # tied to the compiling machine's features, and a copied tree
+        # would load them elsewhere
+        if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+            raise RuntimeError(
+                "the tpu backend was asked for but jax resolved to the CPU "
+                "(chip held by another process, or libtpu failed to "
+                "start); set JAX_PLATFORMS=cpu to run the host emulation "
+                "on purpose"
+            )
+    else:
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update(
+                "jax_compilation_cache_dir", _DEFAULT_COMPILE_CACHE
+            )
+        # every program is worth keeping: the RS matmul compiles in well
+        # under jax's default 1 s floor and would recompile on every start
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    _count_compiles()
+    _DEVICE_PLATFORM.append(platform)
+    return platform
+
+
+def _count_compiles() -> None:
+    """Mirror jax's own compile log into /metrics: every program build
+    (device_compile_requests_total) and how many of them the persistent
+    cache answered (device_compile_cache_hits_total). Warm-up is over
+    when the first stops moving; a cold start is requests minus hits."""
+    from jax import monitoring
+
+    from ..utils import metrics
+
+    def on_duration(event: str, _secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            metrics.inc("device_compile_requests_total")
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            metrics.inc("device_compile_cache_hits_total")
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+
+
+def device_platform() -> Optional[str]:
+    """Platform of the jax device this process owns, or None when the
+    active backend is a host backend — in which case jax is never
+    imported, so several node processes can share a host with one chip."""
+    if getattr(get_backend(), "name", None) != "tpu":
+        return None
+    return open_device()
 
 
 def get_backend():
     """Singleton accessor (role of CryptoProvider.GetCrypto in the reference).
 
-    Resolution order: $LACHAIN_TPU_BACKEND if set, else native C++ if the
-    shared library built, else the Python oracle.
-    """
+    $LACHAIN_TPU_BACKEND picks python | native | tpu; unset means native.
+    A native library that fails to build is an error, not the Python
+    oracle; `tpu` on a process that landed on the CPU is an error too
+    (open_device)."""
     global _BACKEND
     if _BACKEND is not None:
         return _BACKEND
@@ -199,18 +284,16 @@ def get_backend():
     if choice == "tpu":
         from .tpu_backend import TpuBackend
 
+        open_device()
         _BACKEND = TpuBackend()
-        return _BACKEND
-    if choice in ("native", "auto"):
-        try:
-            from .native_backend import NativeBackend
+    elif choice == "python":
+        _BACKEND = PythonBackend()
+    elif choice in ("native", "auto"):
+        from .native_backend import NativeBackend
 
-            _BACKEND = NativeBackend()
-            return _BACKEND
-        except Exception:
-            if choice == "native":
-                raise
-    _BACKEND = PythonBackend()
+        _BACKEND = NativeBackend()
+    else:
+        raise ValueError(f"unknown LACHAIN_TPU_BACKEND={choice!r}")
     return _BACKEND
 
 

@@ -276,17 +276,7 @@ def era_verify_combine(
                 h=_hash_to_sig_point(msg),
             )
         )
-    try:
-        results = era_fn(jobs, key_set.keys, rng=rng)
-    except Exception:
-        # device path unavailable/broken: liveness beats acceleration —
-        # same degradation rule as HoneyBadger._try_decrypt_ready
-        import logging
-
-        logging.getLogger("lachain.crypto").exception(
-            "tpu coin era path failed; host fallback"
-        )
-        return host_path()
+    results = era_fn(jobs, key_set.keys, rng=rng)
     for idx, (ok, comb) in zip(live, results):
         out[idx] = Signature(comb) if ok else None
     return out

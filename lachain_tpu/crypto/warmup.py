@@ -1,9 +1,10 @@
 """Background kernel warmup: precompile the era-kernel shapes a node will hit.
 
-Round-3 finding (ROUND3_NOTES.md #1 / round-3 review weak #3): Mosaic kernels
-are not covered by the XLA persistent compilation cache on this platform, and
-the first era at a new (S_pad, K_pad) shape stalls 35-110 s while compiling —
-a validator joining a running chain burns its first eras compiling.
+The first era at a new (S_pad, K_pad) shape stalls while its program is
+traced and compiled (tens of seconds cold; jax's persistent compile cache,
+placed by crypto/provider.open_device, removes the compile on later starts
+but not the trace) — a validator joining a running chain would burn its
+first eras on it.
 
 The reachable shapes are known a priori: the slot axis pads to a power of two
 bounded by N, the share axis is fixed at pow2(N) — log2(N)+1 shapes total
@@ -12,7 +13,8 @@ thread at node start, LARGEST FIRST (a healthy chain's first flush carries
 close to N slots), so by the time the node's first era tick reaches the
 device the hot shape is already compiled. JAX serializes compilations
 internally, so a real call racing the warmup simply waits for the same
-compile instead of duplicating it.
+compile instead of duplicating it. A shape that fails to compile is an
+error: the thread dies with its traceback and join() re-raises.
 
 Reference contrast: the reference has no analogous cost (MCL is AOT-compiled
 C++) — this is TPU-specific operational machinery.
@@ -70,13 +72,9 @@ def warmup_era_kernels(
         # mesh pipelines pad the (pow2) slot tiers again to a multiple of
         # the 'slot' mesh axis, collapsing the small tiers onto one padded
         # kernel shape — dedupe so warmup compiles each (mesh shape, s_pad,
-        # k_pad) entry exactly once (through kernel_cache.call_mesh, which
-        # also persists it to disk for the next process)
-        try:
-            pipe = backend._get_pipeline()
-        except Exception:
-            pipe = None
-        if pipe is not None and hasattr(pipe, "padded_shape"):
+        # k_pad) entry exactly once
+        pipe = backend._get_pipeline()
+        if hasattr(pipe, "padded_shape"):
             seen: set = set()
             deduped = []
             for s in todo:
@@ -87,39 +85,50 @@ def warmup_era_kernels(
                 deduped.append(s)
             todo = deduped
         for s in todo:
-            try:
-                jobs = [
-                    EraSlotJob(
-                        u_by_validator=[None] * k,
-                        lagrange_row=[0] * k,
-                        h=bls.G2_GEN,
-                        w=bls.G2_GEN,
-                    )
-                    for _ in range(s)
-                ]
-                vks = _dummy_vks(k)
-                backend.tpke_era_verify_combine(jobs, vks)
-                logger.info("warmed TPKE era shape S=%d K=%d", s, k)
-            except Exception:
-                logger.exception("era warmup failed at S=%d", s)
-                return
+            jobs = [
+                EraSlotJob(
+                    u_by_validator=[None] * k,
+                    lagrange_row=[0] * k,
+                    h=bls.G2_GEN,
+                    w=bls.G2_GEN,
+                )
+                for _ in range(s)
+            ]
+            backend.tpke_era_verify_combine(jobs, _dummy_vks(k))
+            logger.info("warmed TPKE era shape S=%d K=%d", s, k)
         if include_ts and hasattr(backend, "ts_era_verify_combine"):
-            try:
-                jobs = [
-                    CoinJob(
-                        sigma_by_signer=[None] * k,
-                        lagrange_row=[0] * k,
-                        h=bls.G2_GEN,
-                    )
-                ]
-                backend.ts_era_verify_combine(jobs, _dummy_ts_keys(k))
-                logger.info("warmed TS coin-era shape K=%d", k)
-            except Exception:
-                logger.exception("ts era warmup failed")
+            jobs = [
+                CoinJob(
+                    sigma_by_signer=[None] * k,
+                    lagrange_row=[0] * k,
+                    h=bls.G2_GEN,
+                )
+            ]
+            backend.ts_era_verify_combine(jobs, _dummy_ts_keys(k))
+            logger.info("warmed TS coin-era shape K=%d", k)
 
-    t = threading.Thread(target=run, name="ltpu-kernel-warmup", daemon=True)
+    t = _WarmupThread(target=run, name="ltpu-kernel-warmup", daemon=True)
     t.start()
     return t
+
+
+class _WarmupThread(threading.Thread):
+    """A thread whose failure is not lost: the exception kills the thread
+    (threading's hook prints the traceback) and join() raises it again."""
+
+    error: Optional[BaseException] = None
+
+    def run(self) -> None:
+        try:
+            super().run()
+        except BaseException as exc:
+            self.error = exc
+            raise
+
+    def join(self, timeout: Optional[float] = None) -> None:
+        super().join(timeout)
+        if self.error is not None:
+            raise self.error
 
 
 _DUMMY_VKS_CACHE: dict = {}

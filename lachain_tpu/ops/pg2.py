@@ -44,28 +44,24 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import msm, pg1
 from ..crypto import bls12381 as bls
 from .pg1 import (
-    INTERPRET,
     NLIMBS,
     POINT_ROWS,
     TABLE,
     WINDOW,
     _add,
     _const_args,
-    _CONST_SPECS,
     _consts,
     _conv,
-    _crush,
     _fold,
+    _lane_call,
     _mul_small,
-    _pad_lanes,
-    _select_entry,
+    _per_platform,
     _sub,
+    make_msm_scan,
 )
 
 COMP_ROWS = 48  # one Fp2 component per 48-row slot (44 limbs + 4 zero
@@ -205,16 +201,6 @@ def _g2_add_val(p, q, c):
 # ---------------------------------------------------------------------------
 
 
-def _tile_width2(n: int) -> int:
-    floor = 8 if INTERPRET else 128
-    return min(LANE_TILE2, max(floor, n))
-
-
-def _padded2(n: int) -> int:
-    t = _tile_width2(n)
-    return ((n + t - 1) // t) * t
-
-
 def _dbl2_kernel(mlo_ref, mhi_ref, wrap_ref, p_ref, o_ref):
     o_ref[:] = _g2_dbl_val(p_ref[:], _consts(mlo_ref, mhi_ref, wrap_ref))
 
@@ -225,132 +211,33 @@ def _add2_kernel(mlo_ref, mhi_ref, wrap_ref, p_ref, q_ref, o_ref):
     )
 
 
+@jax.jit
 def pl_dbl2(p):
     """(288, n) -> (288, n) Jacobian G2 doubling on-device."""
-    if INTERPRET:
-        return _g2_dbl_val(p, _const_args())
-    n = p.shape[-1]
-    w = _padded2(n)
-    t = _tile_width2(n)
-    out = pl.pallas_call(
-        _dbl2_kernel,
-        grid=(w // t,),
-        in_specs=_CONST_SPECS + [
-            pl.BlockSpec((POINT2_ROWS, t), lambda i: (0, i),
-                         memory_space=pltpu.VMEM)
-        ],
-        out_specs=pl.BlockSpec((POINT2_ROWS, t), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((POINT2_ROWS, w), jnp.int32),
-        interpret=INTERPRET,
-    )(*_const_args(), _pad_lanes(p, w))
-    return out[:, :n]
+    return _per_platform(
+        lambda p: _lane_call(_dbl2_kernel, POINT2_ROWS, p, tile=LANE_TILE2),
+        lambda p: _g2_dbl_val(p, _const_args()),
+        p,
+    )
 
 
+@jax.jit
 def pl_add2(p, q):
     """(288, n) x (288, n) -> (288, n) incomplete G2 add on-device."""
-    if INTERPRET:
-        return _g2_add_val(p, q, _const_args())
-    n = p.shape[-1]
-    w = _padded2(n)
-    t = _tile_width2(n)
-    out = pl.pallas_call(
-        _add2_kernel,
-        grid=(w // t,),
-        in_specs=_CONST_SPECS + [
-            pl.BlockSpec((POINT2_ROWS, t), lambda i: (0, i),
-                         memory_space=pltpu.VMEM)
-        ] * 2,
-        out_specs=pl.BlockSpec((POINT2_ROWS, t), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((POINT2_ROWS, w), jnp.int32),
-        interpret=INTERPRET,
-    )(*_const_args(), _pad_lanes(p, w), _pad_lanes(q, w))
-    return out[:, :n]
+    return _per_platform(
+        lambda p, q: _lane_call(
+            _add2_kernel, POINT2_ROWS, p, q, tile=LANE_TILE2
+        ),
+        lambda p, q: _g2_add_val(p, q, _const_args()),
+        p,
+        q,
+    )
 
 
-def _msm2_kernel(mlo_ref, mhi_ref, wrap_ref, table_ref, dig_ref,
-                 acc_ref, flag_ref):
-    """Same structure as pg1._msm_kernel: grid (tiles, windows), window
-    innermost; accumulator + table blocks VMEM-resident across windows."""
-    c = _consts(mlo_ref, mhi_ref, wrap_ref)
-    w = pl.program_id(1)
-    d = dig_ref[0]
-    keep = d == 0
-    entry = _select_entry(table_ref[:], d)
-
-    @pl.when(w == 0)
-    def _():
-        acc_ref[:] = entry
-        flag_ref[:] = keep.astype(jnp.int32)
-
-    @pl.when(w > 0)
-    def _():
-        acc = acc_ref[:]
-        flag = flag_ref[:] != 0
-        acc = jax.lax.fori_loop(
-            0, WINDOW, lambda _, a: _g2_dbl_val(a, c), acc
-        )
-        added = _g2_add_val(acc, entry, c)
-        acc_new = jnp.where(keep, acc, jnp.where(flag, entry, added))
-        acc_ref[:] = acc_new
-        flag_ref[:] = (flag & keep).astype(jnp.int32)
-
-
-def _msm2_emulate(table, digits):
-    """INTERPRET-mode path: same per-window math as _msm2_kernel as plain
-    jnp (see pg1._msm_emulate for why)."""
-    c = _const_args()
-    acc = None
-    flag = None
-    for w in range(digits.shape[0]):
-        d = digits[w]
-        keep = d == 0
-        entry = _select_entry(table, d)
-        if acc is None:
-            acc, flag = entry, keep
-            continue
-        a4 = jax.lax.fori_loop(
-            0, WINDOW, lambda _, a: _g2_dbl_val(a, c), acc
-        )
-        added = _g2_add_val(a4, entry, c)
-        acc = jnp.where(keep, a4, jnp.where(flag, entry, added))
-        flag = flag & keep
-    return acc, flag[0]
-
-
-def _msm2_scan(table, digits):
-    """table (16, 288, n), digits (W, 1, n) -> ((288, n), (n,) flags)."""
-    if INTERPRET:
-        return _msm2_emulate(table, digits)
-    nw = digits.shape[0]
-    n = table.shape[-1]
-    w = _padded2(n)
-    t = _tile_width2(n)
-    table = _pad_lanes(table, w)
-    digits = _pad_lanes(digits, w)
-    acc, flag = pl.pallas_call(
-        _msm2_kernel,
-        grid=(w // t, nw),
-        in_specs=_CONST_SPECS + [
-            pl.BlockSpec((TABLE, POINT2_ROWS, t), lambda i, j: (0, 0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, t), lambda i, j: (j, 0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((POINT2_ROWS, t), lambda i, j: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, t), lambda i, j: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((POINT2_ROWS, w), jnp.int32),
-            jax.ShapeDtypeStruct((1, w), jnp.int32),
-        ],
-        interpret=INTERPRET,
-    )(*_const_args(), table, digits)
-    return acc[:, :n], flag[0, :n] != 0
+# table (16, 288, n), digits (W, 1, n) -> ((288, n), (n,) flags): pg1's
+# window scan (accumulator + table blocks VMEM-resident across windows)
+# over the G2 group law
+_msm2_scan = make_msm_scan(_g2_dbl_val, _g2_add_val, tile=LANE_TILE2)
 
 
 def build_table2(lanes):

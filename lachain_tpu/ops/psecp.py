@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..crypto import ecdsa
-from .pg1 import INTERPRET, TABLE, WINDOW, _select_entry
+from .pg1 import TABLE, _lane_call, _per_platform, make_msm_scan
 
 NLIMBS = 26
 BASE = 10
@@ -72,9 +72,10 @@ for _j in range(3):
         _FOLD_M[:, _j * CONVLEN + _k] = _int_to_limbs(
             (1 << (BASE * (_k + _j))) % P_INT
         )
-_FOLD_LO = jnp.asarray((_FOLD_M & 31).astype(np.float32))
-_FOLD_HI = jnp.asarray((_FOLD_M >> 5).astype(np.float32))
-_WRAP_COL = jnp.asarray(_int_to_limbs((1 << (BASE * NLIMBS)) % P_INT)[:, None])
+# numpy, not jnp: importing this module must not open the jax backend
+_FOLD_LO = (_FOLD_M & 31).astype(np.float32)
+_FOLD_HI = (_FOLD_M >> 5).astype(np.float32)
+_WRAP_COL = _int_to_limbs((1 << (BASE * NLIMBS)) % P_INT)[:, None]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -213,25 +214,6 @@ def _consts(mlo_ref, mhi_ref, wrap_ref):
     return (mlo_ref[:], mhi_ref[:], wrap_ref[:])
 
 
-def _tile_width(n: int) -> int:
-    floor = 8 if INTERPRET else 128
-    return min(LANE_TILE, max(floor, n))
-
-
-def _padded(n: int) -> int:
-    t = _tile_width(n)
-    return ((n + t - 1) // t) * t
-
-
-def _pad_lanes(a, width: int):
-    if a.shape[-1] == width:
-        return a
-    pad = width - a.shape[-1]
-    return jnp.concatenate(
-        [a, jnp.zeros(a.shape[:-1] + (pad,), a.dtype)], axis=-1
-    )
-
-
 def _dbl_kernel(mlo, mhi, wrap, p_ref, o_ref):
     o_ref[:] = _pt_dbl_val(p_ref[:], _consts(mlo, mhi, wrap))
 
@@ -240,125 +222,37 @@ def _add_kernel(mlo, mhi, wrap, p_ref, q_ref, o_ref):
     o_ref[:] = _pt_add_val(p_ref[:], q_ref[:], _consts(mlo, mhi, wrap))
 
 
+_SECP_CONSTS = (_const_args(), _CONST_SPECS)  # pg1._lane_call's `consts`
+
+
+@jax.jit
 def pl_dbl(p):
-    if INTERPRET:
-        return _pt_dbl_val(p, _const_args())
-    n = p.shape[-1]
-    w = _padded(n)
-    t = _tile_width(n)
-    out = pl.pallas_call(
-        _dbl_kernel,
-        grid=(w // t,),
-        in_specs=_CONST_SPECS + [
-            pl.BlockSpec((POINT_ROWS, t), lambda i: (0, i),
-                         memory_space=pltpu.VMEM)
-        ],
-        out_specs=pl.BlockSpec((POINT_ROWS, t), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((POINT_ROWS, w), jnp.int32),
-        interpret=INTERPRET,
-    )(*_const_args(), _pad_lanes(p, w))
-    return out[:, :n]
+    return _per_platform(
+        lambda p: _lane_call(
+            _dbl_kernel, POINT_ROWS, p, tile=LANE_TILE, consts=_SECP_CONSTS
+        ),
+        lambda p: _pt_dbl_val(p, _const_args()),
+        p,
+    )
 
 
+@jax.jit
 def pl_add(p, q):
-    if INTERPRET:
-        return _pt_add_val(p, q, _const_args())
-    n = p.shape[-1]
-    w = _padded(n)
-    t = _tile_width(n)
-    out = pl.pallas_call(
-        _add_kernel,
-        grid=(w // t,),
-        in_specs=_CONST_SPECS + [
-            pl.BlockSpec((POINT_ROWS, t), lambda i: (0, i),
-                         memory_space=pltpu.VMEM)
-        ] * 2,
-        out_specs=pl.BlockSpec((POINT_ROWS, t), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((POINT_ROWS, w), jnp.int32),
-        interpret=INTERPRET,
-    )(*_const_args(), _pad_lanes(p, w), _pad_lanes(q, w))
-    return out[:, :n]
+    return _per_platform(
+        lambda p, q: _lane_call(
+            _add_kernel, POINT_ROWS, p, q, tile=LANE_TILE,
+            consts=_SECP_CONSTS,
+        ),
+        lambda p, q: _pt_add_val(p, q, _const_args()),
+        p,
+        q,
+    )
 
 
-def _msm_kernel(mlo, mhi, wrap, table_ref, dig_ref, acc_ref, flag_ref):
-    """Same structure as pg1._msm_kernel at secp parameters."""
-    c = _consts(mlo, mhi, wrap)
-    w = pl.program_id(1)
-    d = dig_ref[0]
-    keep = d == 0
-    entry = _select_entry(table_ref[:], d)
-
-    @pl.when(w == 0)
-    def _():
-        acc_ref[:] = entry
-        flag_ref[:] = keep.astype(jnp.int32)
-
-    @pl.when(w > 0)
-    def _():
-        acc = acc_ref[:]
-        flag = flag_ref[:] != 0
-        acc = jax.lax.fori_loop(
-            0, WINDOW, lambda _, a: _pt_dbl_val(a, c), acc
-        )
-        added = _pt_add_val(acc, entry, c)
-        acc_new = jnp.where(keep, acc, jnp.where(flag, entry, added))
-        acc_ref[:] = acc_new
-        flag_ref[:] = (flag & keep).astype(jnp.int32)
-
-
-def _msm_emulate(table, digits):
-    c = _const_args()
-    acc = None
-    flag = None
-    for w in range(digits.shape[0]):
-        d = digits[w]
-        keep = d == 0
-        entry = _select_entry(table, d)
-        if acc is None:
-            acc, flag = entry, keep
-            continue
-        a4 = jax.lax.fori_loop(
-            0, WINDOW, lambda _, a: _pt_dbl_val(a, c), acc
-        )
-        added = _pt_add_val(a4, entry, c)
-        acc = jnp.where(keep, a4, jnp.where(flag, entry, added))
-        flag = flag & keep
-    return acc, flag[0]
-
-
-def _msm_scan(table, digits):
-    if INTERPRET:
-        return _msm_emulate(table, digits)
-    nw = digits.shape[0]
-    n = table.shape[-1]
-    w = _padded(n)
-    t = _tile_width(n)
-    table = _pad_lanes(table, w)
-    digits = _pad_lanes(digits, w)
-    acc, flag = pl.pallas_call(
-        _msm_kernel,
-        grid=(w // t, nw),
-        in_specs=_CONST_SPECS + [
-            pl.BlockSpec((TABLE, POINT_ROWS, t), lambda i, j: (0, 0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, t), lambda i, j: (j, 0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((POINT_ROWS, t), lambda i, j: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, t), lambda i, j: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((POINT_ROWS, w), jnp.int32),
-            jax.ShapeDtypeStruct((1, w), jnp.int32),
-        ],
-        interpret=INTERPRET,
-    )(*_const_args(), table, digits)
-    return acc[:, :n], flag[0, :n] != 0
+# pg1's window scan at secp parameters
+_msm_scan = make_msm_scan(
+    _pt_dbl_val, _pt_add_val, tile=LANE_TILE, consts=_SECP_CONSTS
+)
 
 
 def build_table(lanes):
@@ -406,7 +300,7 @@ def ints_from_limbs(arr) -> list:
     """(26, n) limb planes -> python ints mod p. Device limbs are LOOSE
     (possibly >10-bit or negative), so the shift-accumulate runs in
     python-int space per lane — 26 multiword ops/lane, ~0.15 s per 10k
-    lanes, a known slice of the host budget (ROUND3_NOTES gap #2)."""
+    lanes, a known slice of the host budget."""
     arr = np.asarray(arr).astype(np.int64).T  # (n, 26)
     out = []
     for row in arr:
@@ -580,9 +474,8 @@ class TpuEcdsaRecover:
         for _ in range(m_pad - m):
             pts.extend([g_aff, g_aff])
             u_digits.extend([0, 0])
-        kernel = recover_kernel if INTERPRET else recover_kernel_jit
         fused = np.asarray(
-            kernel(
+            recover_kernel_jit(
                 jnp.asarray(pt_pack(pts)),
                 jnp.asarray(digits_col(u_digits)),
             )

@@ -10,10 +10,11 @@ This module computes them that way, batched: all pending items that share a
 column-concatenated into ONE matrix product per group, which is the shape
 "the designated second TPU kernel" (ops/rs.py docstring, PAPER.md §2a)
 wants: a log/exp table gather plus an XOR reduction over the contraction
-axis. When a non-CPU jax backend is visible (or LACHAIN_RS_DEVICE=1 forces
-it) the product is jitted and dispatched to the device, sharded across the
-PR 14 mesh along the column (slot-payload) axis; otherwise the same gather +
-XOR runs vectorized in numpy. Both paths use the identical exp/log tables,
+axis. When this process owns an accelerator (crypto/provider.py decides; or
+LACHAIN_RS_DEVICE=1 forces it) products of at least _DEVICE_MIN_COLS
+columns are jitted and dispatched to the device, sharded across the PR 14
+mesh along the column (slot-payload) axis; otherwise the same gather + XOR
+runs vectorized in numpy. Both paths use the identical exp/log tables,
 so results are bit-identical to ops/rs.py (tests/test_rs_batch.py pins a
 200-seed differential).
 
@@ -26,15 +27,12 @@ engine-internal fallback when no host shim is attached).
 """
 from __future__ import annotations
 
-import logging
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..utils import tracing
-
-logger = logging.getLogger("lachain.rs_batch")
 
 # device dispatch is worth its ferry cost only past a column threshold;
 # below it the numpy path wins outright
@@ -202,32 +200,22 @@ def _inverse_for(
 
 # -- device dispatch ---------------------------------------------------------
 
-# {None: unprobed} -> bool; separate broken flag so one device failure
-# degrades the process to numpy permanently instead of retrying every call
-_DEVICE_ON: List[Optional[bool]] = [None]
-_DEVICE_BROKEN: List[bool] = [False]
 _JIT_CACHE: Dict[int, object] = {}
 _EXP_DEV: Dict[int, object] = {}
 
 
 def device_enabled() -> bool:
     """True when RS matmuls should dispatch to a jax device. Env knob
-    LACHAIN_RS_DEVICE: "1" forces on, "0" forces off; unset auto-enables
-    iff the default jax backend is not the CPU interpreter."""
-    if _DEVICE_ON[0] is None:
-        env = os.environ.get("LACHAIN_RS_DEVICE")
-        if env == "0":
-            _DEVICE_ON[0] = False
-        elif env == "1":
-            _DEVICE_ON[0] = True
-        else:
-            try:
-                import jax
+    LACHAIN_RS_DEVICE: "1" forces on, "0" forces off; unset asks the one
+    place that decides whether this process owns a device
+    (crypto/provider.device_platform) — a host-backend process answers no
+    without importing jax."""
+    env = os.environ.get("LACHAIN_RS_DEVICE")
+    if env in ("0", "1"):
+        return env == "1"
+    from ..crypto.provider import device_platform
 
-                _DEVICE_ON[0] = jax.default_backend() != "cpu"
-            except Exception:
-                _DEVICE_ON[0] = False
-    return bool(_DEVICE_ON[0]) and not _DEVICE_BROKEN[0]
+    return device_platform() not in (None, "cpu")
 
 
 def _device_jit(bits: int):
@@ -290,30 +278,23 @@ def _matmul_device(field: GF, a: np.ndarray, b: np.ndarray, era=None):
             exp_dev = _EXP_DEV[field.bits] = jax.device_put(field.exp)
         args = (log_b, mask_b)
         if ndev > 1 and c_pad % ndev == 0:
-            try:
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
 
-                from ..parallel.mesh import make_mesh
+            from ..parallel.mesh import make_mesh
 
-                sharding = NamedSharding(make_mesh(), P(None, "shares"))
-                args = tuple(jax.device_put(x, sharding) for x in args)
-            except Exception:  # pragma: no cover - mesh-less jax builds
-                pass
+            sharding = NamedSharding(make_mesh(), P(None, "shares"))
+            args = tuple(jax.device_put(x, sharding) for x in args)
         out = _device_jit(field.bits)(exp_dev, log_a, mask_a, *args)
         out = np.asarray(jax.device_get(out))
     return out[:, :c]
 
 
 def _matmul(field: GF, a: np.ndarray, b: np.ndarray, era=None) -> np.ndarray:
+    # a device failure propagates: the numpy path is for small products
+    # and host-backend processes, not a landing for exceptions
     if b.shape[1] >= _DEVICE_MIN_COLS and device_enabled():
-        try:
-            return _matmul_device(field, a, b, era=era)
-        except Exception:
-            _DEVICE_BROKEN[0] = True
-            logger.exception(
-                "RS device matmul failed; numpy fallback for this process"
-            )
+        return _matmul_device(field, a, b, era=era)
     return field.matmul(a, b)
 
 

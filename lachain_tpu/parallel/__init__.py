@@ -1,63 +1,18 @@
 """Device-mesh parallelism package.
 
-:mod:`.mesh` is written against the top-level ``jax.shard_map`` API
-(keyword mesh/in_specs/out_specs, ``check_vma``). Older jax builds keep
-shard_map in ``jax.experimental.shard_map`` with a ``check_rep`` kwarg
-instead; :func:`get_shard_map` papers over the difference so the mesh
-pipeline runs on both. Probe with :func:`mesh_unsupported_reason` before
-importing :mod:`.mesh` — tests skip on the probe instead of erroring at
-collection, and single-device hosts fall back to the host/Pallas
-pipelines (crypto/tpu_backend.py).
+:mod:`.mesh` shards the era kernels with ``jax.shard_map`` over a device
+mesh. Probe with :func:`mesh_unsupported_reason` before driving it — tests
+skip on the probe, and single-device hosts run the host/Pallas pipelines
+(crypto/tpu_backend.py).
 """
 from __future__ import annotations
 
 from typing import Optional
 
 
-def get_shard_map():
-    """Return a ``shard_map(f, mesh=..., in_specs=..., out_specs=...,
-    check_vma=...)`` callable, or None when this jax build has neither the
-    top-level export nor the experimental one.
-
-    The wrapper normalizes the two historical calling conventions:
-    new-style ``jax.shard_map`` takes ``check_vma``; the experimental
-    module spells the same knob ``check_rep``.
-    """
-    try:
-        from jax import shard_map as _sm  # new-style top-level export
-
-        return _sm
-    except ImportError:
-        pass
-    try:
-        from jax.experimental.shard_map import shard_map as _xsm
-    except ImportError:
-        return None
-
-    def _compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _xsm(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=bool(check_vma),
-        )
-
-    return _compat
-
-
-def shard_map_available() -> bool:
-    """True when some usable shard_map exists on this jax build (top-level
-    or experimental — :mod:`.mesh` handles both via get_shard_map)."""
-    return get_shard_map() is not None
-
-
 def mesh_unsupported_reason() -> Optional[str]:
     """None when the mesh pipeline can actually run here; otherwise a
-    human-readable skip reason (no shard_map at all, or a single-device
-    host)."""
-    if not shard_map_available():
-        return "this jax build has no shard_map (top-level or experimental)"
+    human-readable skip reason (a single-device host)."""
     import jax
 
     if len(jax.devices()) < 2:

@@ -13,10 +13,6 @@ ICI within a pod slice and DCN across slices — this is the framework's
 distributed communication backend for the crypto data plane (SURVEY.md §5
 "Distributed communication backend"). Control-plane consensus messages stay
 on the host network (lachain_tpu/network).
-
-shard_map is resolved through :func:`lachain_tpu.parallel.get_shard_map`,
-which papers over the top-level vs jax.experimental calling conventions;
-importing this module raises ImportError on jax builds with neither.
 """
 from __future__ import annotations
 
@@ -27,15 +23,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import get_shard_map
 from ..ops import curve
 from ..utils import metrics, tracing
-
-shard_map = get_shard_map()
-if shard_map is None:  # pragma: no cover - guarded by mesh_unsupported_reason
-    raise ImportError("this jax build has no shard_map (top-level or experimental)")
 
 logger = logging.getLogger("lachain.mesh")
 
@@ -379,7 +371,6 @@ class MeshEraPipeline:
         from jax.sharding import NamedSharding
 
         from ..crypto import bls12381 as bls
-        from ..crypto import kernel_cache
         from ..ops import msm
         from ..ops.verify import era_rlc
 
@@ -448,15 +439,8 @@ class MeshEraPipeline:
                 allgather_mb=round(ag_mb, 3),
             )
             t_dispatch = metrics.monotonic()
-            pts, flags = kernel_cache.call_mesh(
-                self._step,
-                "mesh_glv_era",
-                self.mesh,
-                args[0],
-                y_dev,
-                args[1],
-                args[2],
-                args[3],
+            pts, flags = self._step(
+                args[0], y_dev, args[1], args[2], args[3]
             )
         self.calls += 1
 

@@ -33,15 +33,14 @@ import ctypes
 import os
 import signal
 import struct
-import subprocess
 import threading
 import weakref
 from typing import Dict, Iterator, List, Optional, Tuple
 
+from ..utils.native_build import ensure_built
 from .kv import KVStore
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libllsm.so")
 _lib_cache: list = [None]
 
 # lsm_stats() slot order (keep in sync with Lsm::fill_stats)
@@ -80,20 +79,9 @@ _next_trace_pid = iter(range(3, 1 << 30))  # pid 1 = python, 2 = consensus
 def _load_lib():
     if _lib_cache[0] is not None:
         return _lib_cache[0]
-    override = os.environ.get("LACHAIN_LSM_LIB")
-    lib_path = override or _LIB_PATH
-    if not override:
-        sources = [
-            os.path.join(_NATIVE_DIR, "lsm.cpp"),
-            os.path.join(_NATIVE_DIR, "Makefile"),
-        ]
-        if not os.path.exists(_LIB_PATH) or any(
-            os.path.getmtime(_LIB_PATH) < os.path.getmtime(s) for s in sources
-        ):
-            subprocess.run(
-                ["make", "-s", "-C", _NATIVE_DIR], check=True,
-                capture_output=True,
-            )
+    lib_path = os.environ.get("LACHAIN_LSM_LIB") or ensure_built(
+        _NATIVE_DIR, "libllsm.so"
+    )
     lib = ctypes.CDLL(lib_path)
     lib.lsm_open.restype = ctypes.c_void_p
     lib.lsm_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64]

@@ -411,7 +411,7 @@ def test_compare_direction_lower_is_better(tmp_path):
 def test_compare_wrapper_and_schema_errors(tmp_path):
     import benchmarks.compare as compare
 
-    # the checked-in BENCH_r05.json driver envelope is accepted
+    # the driver's {cmd, rc, parsed} envelope is accepted
     wrapped = {"cmd": "python bench.py", "rc": 0, "parsed": _result()}
     b = tmp_path / "wrapped.json"
     b.write_text(json.dumps(wrapped))
@@ -492,12 +492,15 @@ def test_rpc_and_cli_era_report_surface():
 
 
 def test_compare_checked_in_baseline_self_gate():
-    """The Makefile bench-gate wiring: BENCH_r05.json vs itself passes."""
+    """The Makefile bench-gate wiring: a checked-in baseline vs itself
+    passes."""
     import os
 
     import benchmarks.compare as compare
 
-    base = os.path.join(os.path.dirname(__file__), "..", "BENCH_r05.json")
+    base = os.path.join(
+        os.path.dirname(__file__), "..", "benchmarks", "BENCH_sim_gate.json"
+    )
     assert compare.main([base, base]) == 0
 
 

@@ -306,4 +306,5 @@ def test_mesh_vs_glv_differential(n, n_devices):
     s_pad, k_pad = pipe.padded_shape(len(slots), len(y_points))
     waste = 1.0 - (len(slots) * len(y_points)) / (s_pad * k_pad)
     assert metrics.gauge_value("mesh_devices") == n_devices
-    assert abs(metrics.gauge_value("mesh_pad_waste_fraction") - waste) < 1e-9
+    # the gauge is published rounded to four places
+    assert abs(metrics.gauge_value("mesh_pad_waste_fraction") - waste) < 1e-4

@@ -16,7 +16,6 @@ import random
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from lachain_tpu.crypto import bls12381 as bls
@@ -154,42 +153,6 @@ def test_era_pack_roundtrip(rng):
     assert (np.asarray(rest[0]) == l1).all()
     assert (np.asarray(rest[1]) == l2).all()
 
-
-@pytest.mark.skipif(
-    jax.default_backend() != "tpu", reason="full-width era needs the chip"
-)
-def test_era_kernel_full_width_tpu(rng):
-    """On real hardware: the production W64/W128 window counts at a small
-    but multi-tile width, against the oracle."""
-    s, k = 4, 8
-    n = s * k
-    u_pts = [bls.g1_mul(bls.G1_GEN, rng.randrange(1, bls.R)) for _ in range(n)]
-    y_pts = [bls.g1_mul(bls.G1_GEN, rng.randrange(1, bls.R)) for _ in range(n)]
-    rlc = [rng.randrange(1, 1 << 64) for _ in range(n)]
-    lag = [rng.randrange(bls.R) for _ in range(n)]
-    halves = [msm.glv_split(v) for v in lag]
-    buf = jnp.asarray(
-        pg1.era_pack_inputs(
-            pg1.g1_pack(u_pts),
-            pg1.digits_col(rlc, pg1.W64),
-            pg1.digits_col([h[0] for h in halves], pg1.W128),
-            pg1.digits_col([h[1] for h in halves], pg1.W128),
-        )
-    )
-    fused = np.asarray(
-        pg1.era_kernel_packed_jit(buf, jnp.asarray(pg1.g1_pack(y_pts)), k, n)
-    )
-    cols = pg1.g1_unpack(fused[:132], fused[132] != 0)
-    for si in range(s):
-        u_agg = y_agg = comb = bls.G1_INF
-        for i in range(si * k, (si + 1) * k):
-            u_agg = bls.g1_add(u_agg, bls.g1_mul(u_pts[i], rlc[i]))
-            y_agg = bls.g1_add(y_agg, bls.g1_mul(y_pts[i], rlc[i]))
-            comb = bls.g1_add(comb, bls.g1_mul(u_pts[i], lag[i]))
-        assert bls.g1_eq(cols[si], u_agg)
-        assert bls.g1_eq(cols[s + si], y_agg)
-        got_comb = bls.g1_add(cols[2 * s + si], cols[3 * s + si])
-        assert bls.g1_eq(got_comb, comb)
 
 
 def test_pallas_era_pipeline_end_to_end():

@@ -171,7 +171,8 @@ def test_node_watchdog_names_window_floor_era(caplog):
         era=5, window_floor=3, result_of=lambda pid: None
     )
     fake = SimpleNamespace(
-        _native_watch=("", 0.0, 0), stall_timeout=1.0, pipeline_window=2
+        _native_watch=("", 0.0, 0), effective_stall_timeout=1.0,
+        pipeline_window=2,
     )
     assert Node._check_native_stall(fake, router, "stuck-state", 0.0) == 0
     with caplog.at_level(logging.WARNING, logger="lachain_tpu.core.node"):
@@ -182,7 +183,8 @@ def test_node_watchdog_names_window_floor_era(caplog):
     # window off: the legacy single-era attribution stays
     caplog.clear()
     fake2 = SimpleNamespace(
-        _native_watch=("", 0.0, 0), stall_timeout=1.0, pipeline_window=0
+        _native_watch=("", 0.0, 0), effective_stall_timeout=1.0,
+        pipeline_window=0,
     )
     Node._check_native_stall(fake2, router, "stuck-state", 0.0)
     with caplog.at_level(logging.WARNING, logger="lachain_tpu.core.node"):

@@ -12,7 +12,6 @@ import random
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from lachain_tpu.crypto import ecdsa
@@ -89,21 +88,6 @@ def test_validate_matches_oracle_edges(rng):
     assert psecp.TpuEcdsaRecover._validate(h, zero_r) is None
 
 
-@pytest.mark.skipif(
-    jax.default_backend() != "tpu", reason="full recover needs the chip"
-)
-def test_recover_batch_on_chip(rng):
-    privs = [ecdsa.generate_private_key() for _ in range(6)]
-    hs = [bytes([rng.randrange(256) for _ in range(32)]) for _ in privs]
-    sigs = [ecdsa.sign_hash(p, h) for p, h in zip(privs, hs)]
-    bad = bytearray(sigs[2])
-    bad[40] ^= 0xFF
-    sigs[2] = bytes(bad)
-    got = psecp.TpuEcdsaRecover().recover_batch(hs, sigs)
-    want = [ecdsa.recover_hash(h, s) for h, s in zip(hs, sigs)]
-    assert got == want
-
-
 def _degenerate_sig():
     """Adversarial signature with u1*R == u2*G: R = kG, s = (N-z)/k, so
     the kernel's incomplete pairwise add degenerates (Z=0) and the host
@@ -126,17 +110,9 @@ def test_degenerate_validation_path():
     want = ecdsa.recover_hash(h, sig)
     assert want is not None
     # host-side validation accepts it (the kernel-vs-oracle equivalence on
-    # this input is asserted on-chip below)
+    # this input is chip_smoke.py's check (d))
     assert psecp.TpuEcdsaRecover._validate(h, sig) is not None
 
-
-@pytest.mark.skipif(
-    jax.default_backend() != "tpu", reason="needs the chip"
-)
-def test_degenerate_recover_on_chip():
-    h, sig = _degenerate_sig()
-    got = psecp.TpuEcdsaRecover().recover_batch([h], [sig])
-    assert got == [ecdsa.recover_hash(h, sig)]
 
 # slice marker: crypto/accelerator kernels ("make test-kernel")
 pytestmark = pytest.mark.kernel

@@ -9,7 +9,6 @@ and every loss count from 1 to N-1. On top sit the era-batcher semantics
 and the end-to-end anchor: a devnet era produces bit-identical block hashes
 with batching on vs off, on BOTH engines.
 """
-import os
 import random
 
 import pytest
@@ -264,23 +263,25 @@ def test_env_kill_switch_disables_batcher(monkeypatch):
         net.close()
 
 
-def test_device_path_falls_back_clean(monkeypatch):
-    """With the device path forced on but jit broken, the first failure
-    latches numpy for the process — results stay correct."""
+def test_device_failure_propagates(monkeypatch):
+    """With the device path on, a device exception comes out of
+    rs_batch._matmul (and the batcher above it): the numpy path is not a
+    landing for exceptions, and nothing latches the device off."""
     monkeypatch.setenv("LACHAIN_RS_DEVICE", "1")
-    monkeypatch.setattr(rs_batch, "_DEVICE_ON", [None])
-    monkeypatch.setattr(rs_batch, "_DEVICE_BROKEN", [False])
 
     def boom(*a, **k):
         raise RuntimeError("no device for you")
 
     monkeypatch.setattr(rs_batch, "_matmul_device", boom)
     data = bytes(range(256)) * 64  # big enough to cross _DEVICE_MIN_COLS
-    shards = rs_batch.encode(data, 3, 7)
-    assert rs_batch._DEVICE_BROKEN[0] is True
-    assert shards == rs.encode(data, 3, 7)
-    # second call goes straight to numpy (latched), still identical
-    assert rs_batch.encode(data, 3, 7) == shards
+    with pytest.raises(RuntimeError, match="no device for you"):
+        rs_batch.encode(data, 3, 7)
+    batcher = RbcEraBatcher()
+    batcher.submit_encode(1, data, 3, 7, lambda shards: None)
+    with pytest.raises(RuntimeError, match="no device for you"):
+        batcher.flush(1)
+    # below the column floor the numpy path is the chosen path, not a fallback
+    assert rs_batch.encode(b"small", 3, 7) == rs.encode(b"small", 3, 7)
 
 
 # --- end-to-end: block-hash identity on vs off, both engines -----------------

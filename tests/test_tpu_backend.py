@@ -227,62 +227,6 @@ def test_era_batch_records_pad_waste_and_route_metrics(tpu_backend):
     assert lat["count"] == 1 and lat["sum"] > 0
 
 
-def test_kernel_cache_hit_miss_counters(tmp_path, monkeypatch):
-    """kernel_cache.call/warm tier counters: compile on first sight, memo
-    on re-use, disk on a fresh-process load. The compile itself is faked
-    (the real Mosaic path is covered by test_kernel_cache.py); here only
-    the counter plumbing is under test."""
-    import numpy as np
-
-    from lachain_tpu.crypto import kernel_cache as kc
-    from lachain_tpu.utils import metrics
-
-    monkeypatch.setenv("LACHAIN_TPU_KERNEL_CACHE", str(tmp_path))
-    monkeypatch.setattr(kc, "_single_device", lambda: True)
-    monkeypatch.setitem(kc.__dict__, "_memo", {})
-    metrics.reset_all_for_tests()
-
-    class FakeCompiled:
-        def __call__(self, *a):
-            return "ran"
-
-    class FakeLowered:
-        def compile(self):
-            return FakeCompiled()
-
-    class FakeJit:
-        def lower(self, *a, **k):
-            return FakeLowered()
-
-    arg = np.zeros((2, 2), dtype=np.int32)
-    assert kc.call(FakeJit(), "fake_kernel", arg) == "ran"
-    tiers = lambda t: metrics.counter_value(  # noqa: E731
-        "kernel_cache_requests_total", labels={"tier": t}
-    )
-    assert tiers("compile") == 1
-    assert tiers("memo") == 0
-    # FakeCompiled can't serialize -> no disk entry; second call memo-hits
-    assert kc.call(FakeJit(), "fake_kernel", arg) == "ran"
-    assert tiers("memo") == 1
-    assert tiers("compile") == 1
-    # compile latency histogram observed exactly once
-    assert (
-        metrics.histogram_snapshot("kernel_cache_compile_seconds")["count"]
-        == 1
-    )
-    # warm() counters share the tier scheme
-    assert kc.warm(FakeJit(), "fake_kernel", arg) is True
-    assert (
-        metrics.counter_value("kernel_cache_warm_total", labels={"tier": "memo"})
-        == 1
-    )
-    assert kc.warm(FakeJit(), "other_kernel", arg) is False
-    assert (
-        metrics.counter_value("kernel_cache_warm_total", labels={"tier": "compile"})
-        == 1
-    )
-
-
 def test_adaptive_device_msm_routing(tpu_backend, monkeypatch):
     """g1_msm/g2_msm route big batches to the device path and small ones
     to the host. The device kernel math is covered by test_pg1/test_pg2

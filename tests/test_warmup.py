@@ -51,61 +51,5 @@ def test_warmup_noop_on_host_backend():
     assert warmup_era_kernels(4, backend=PythonBackend()) is None
 
 
-@pytest.mark.mesh
-@pytest.mark.slow
-@pytest.mark.skipif(
-    mesh_unsupported_reason() is not None,
-    reason=f"mesh stack unavailable: {mesh_unsupported_reason()}",
-)
-def test_mesh_warm_cache_zero_compile_events(tmp_path, monkeypatch):
-    """Satellite: a warm persistent kernel cache gives ZERO compile events.
-
-    First warmup populates the on-disk cache; clearing the in-process memo
-    simulates a fresh node process; the second warmup must serve every mesh
-    shape from disk (tier="disk") without a single tier="compile" request."""
-    from lachain_tpu.crypto import kernel_cache
-    from lachain_tpu.crypto.tpu_backend import TpuBackend
-    from lachain_tpu.utils import metrics
-
-    monkeypatch.setenv("LACHAIN_TPU_KERNEL_CACHE", str(tmp_path))
-    # drop any executables earlier tests memoized so the first warmup
-    # really compiles + disk-stores into tmp_path (order independence)
-    kernel_cache._memo.clear()
-
-    backend = TpuBackend(min_device_lanes=1)
-    t = warmup_era_kernels(2, backend=backend, include_ts=False)
-    assert t is not None
-    t.join(timeout=600)
-    assert not t.is_alive()
-    assert backend.era_calls >= 1  # the warmup thread swallows exceptions
-
-    # fresh-process simulation: drop the in-memory executable memo so the
-    # second warmup must go through the persistent on-disk cache
-    kernel_cache._memo.clear()
-    compiles_before = metrics.counter_value(
-        "kernel_cache_requests_total", labels={"tier": "compile"}
-    )
-    disk_before = metrics.counter_value(
-        "kernel_cache_requests_total", labels={"tier": "disk"}
-    )
-
-    backend2 = TpuBackend(min_device_lanes=1)
-    t2 = warmup_era_kernels(2, backend=backend2, include_ts=False)
-    assert t2 is not None
-    t2.join(timeout=600)
-    assert not t2.is_alive()
-    assert backend2.era_calls == backend.era_calls
-
-    compiles_after = metrics.counter_value(
-        "kernel_cache_requests_total", labels={"tier": "compile"}
-    )
-    disk_after = metrics.counter_value(
-        "kernel_cache_requests_total", labels={"tier": "disk"}
-    )
-    assert compiles_after == compiles_before, (
-        "warm cache must not compile"
-    )
-    assert disk_after > disk_before  # served from the persistent cache
-
 # slice marker: crypto/accelerator kernels ("make test-kernel")
 pytestmark = pytest.mark.kernel
