@@ -191,11 +191,11 @@ def _build_node(cfg, config_path=None):
     if cfg.trace_capacity is not None:
         # resize the merged rings now; native engines created after this
         # point (LSM store below, consensus engine per era) size their
-        # in-engine rings from the same knob via tracing.DEFAULT_CAPACITY
+        # in-engine rings from the same knob via tracing.capacity().
+        # 0 turns the whole recorder off
         from .utils import tracing
 
-        tracing.DEFAULT_CAPACITY = max(int(cfg.trace_capacity), 0)
-        tracing.set_capacity(max(tracing.DEFAULT_CAPACITY, 1))
+        tracing.set_capacity(cfg.trace_capacity)
     if cfg.tx_sample_shift is not None:
         # tx lifecycle sampling density: 1-in-2^shift transactions carry
         # stage stamps (observability.txSampleShift; 0 = stamp every tx)

@@ -841,6 +841,10 @@ struct Engine {
   // per-era exclusive dispatch time by protocol family (TP_*); std::map so
   // flush order is deterministic across identically-seeded runs
   std::map<uint32_t, std::array<uint64_t, 8>> phase_acc;
+  // the same nanoseconds by family over the engine's life: phase_acc is
+  // erased at every era advance and its records pass through the ring, so
+  // this is what rt_phase_totals reads (nothing can evict it)
+  uint64_t phase_total[8] = {0};
   uint64_t cross_ns = 0;  // crossing time inside the current deliver()
   // queue-empty starvation tracking: set when run() exits with nothing to
   // dispatch, resolved into one TK_WAIT record when the host pumps again
@@ -1162,7 +1166,10 @@ struct Engine {
           cross_ns = 0;
           deliver(e);
           uint64_t dt = trace_now_ns() - t0;
-          if (dt > cross_ns) phase_acc[era][ph] += dt - cross_ns;
+          if (dt > cross_ns) {
+            phase_acc[era][ph] += dt - cross_ns;
+            phase_total[ph] += dt - cross_ns;
+          }
         } else {
           deliver(e);
         }
@@ -2511,6 +2518,13 @@ size_t rt_trace_drain(void* h, uint8_t* buf, size_t cap) {
   std::memcpy(buf, out.data(), out.size());
   r.count = 0;  // consumed (w stays: the ring keeps filling from there)
   return out.size();
+}
+
+// Exclusive dispatch nanoseconds by protocol family (index = TP_*, 0 unused)
+// since the engine was built: what phase_acc sums, without the ring. Stands
+// still while recording is off (capacity 0), as the clock reads do.
+void rt_phase_totals(void* h, uint64_t* out8) {
+  std::memcpy(out8, static_cast<Engine*>(h)->phase_total, 8 * sizeof(uint64_t));
 }
 
 // test/fuzz hook: drive rs_decode with arbitrary shard vectors (lens[i]==0

@@ -74,6 +74,9 @@ OWN_ROOT = 4
 # labeled counter of every engine->Python boundary crossing; op
 # "opaque_message" is the legacy per-message callback the batched ops replace
 CROSSINGS_METRIC = "consensus_callback_crossings_total"
+# the engine's exclusive per-message dispatch time by protocol family
+# (TP_NAMES): callbacks into Python subtracted, no interval to put a span on
+DISPATCH_METRIC = "consensus_engine_dispatch_seconds_total"
 
 _OPAQUE_CB = ctypes.CFUNCTYPE(
     None,
@@ -230,6 +233,10 @@ def load_rt():
     lib.rt_trace_configure.argtypes = [ctypes.c_void_p, ctypes.c_size_t]
     lib.rt_trace_dropped.restype = ctypes.c_uint64
     lib.rt_trace_dropped.argtypes = [ctypes.c_void_p]
+    lib.rt_phase_totals.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_uint64),
+    ]
     lib.rt_trace_drain.restype = ctypes.c_size_t
     lib.rt_trace_drain.argtypes = [
         ctypes.c_void_p,
@@ -352,6 +359,15 @@ def decode_consensus_trace(
                 "wait_seconds", dur / 1e9, labels={"resource": res}
             )
     return evs
+
+
+def _cross_begin(op: str, era: int, vid: int) -> int:
+    """One engine->Python callback: counted under CROSSINGS_METRIC and
+    opened as the span `cross.<op>`, which its caller ends."""
+    metrics.inc(CROSSINGS_METRIC, labels={"op": op})
+    if not tracing.capacity():
+        return 0
+    return tracing.begin("cross." + op, "engine", era=era, vid=vid)
 
 
 @dataclass(frozen=True)
@@ -874,6 +890,7 @@ class NativeSimulatedNetwork:
         self._trace_dropped_closed = 0
         self._trace_backlog: List[dict] = []
         self._trace_capacity = 0
+        self._phase_seen: Dict[int, List[int]] = {}  # handle -> ns by TP_*
         self._h = self._lib.rt_new(
             self.n,
             public_keys.f,
@@ -958,13 +975,14 @@ class NativeSimulatedNetwork:
         self._trace_offset = clock_offset(self._lib.rt_monotonic_ns)
         self._trace_dropped_seen = 0
         self._trace_source = f"consensus-{id(self):x}"
-        self.trace_configure(tracing.DEFAULT_CAPACITY)
+        self.trace_configure(tracing.capacity())
         ref = weakref.ref(self)
         tracing.register_native_source(
             self._trace_source,
             lambda: (
                 [] if ref() is None else ref()._drain_trace()  # noqa: B023
             ),
+            lambda n: None if ref() is None else ref().trace_configure(n),
         )
 
     # -- per-era engine lifecycle ---------------------------------------------
@@ -1030,6 +1048,8 @@ class NativeSimulatedNetwork:
             self._trace_backlog.extend(self._drain_engine_trace(h))
         except Exception:  # pragma: no cover - tracing must never kill an era
             pass
+        self._fold_dispatch(h)
+        del self._phase_seen[h]  # a later engine may get the same address
         self._native_handled_closed += int(self._lib.rt_native_handled(h))
         self._trace_dropped_closed += int(self._lib.rt_trace_dropped(h))
         self._lib.rt_free(h)
@@ -1041,6 +1061,23 @@ class NativeSimulatedNetwork:
         self._trace_capacity = max(int(capacity), 0)
         for h in self._live_engines():
             self._lib.rt_trace_configure(h, self._trace_capacity)
+
+    def _fold_dispatch(self, h: int) -> None:
+        """What engine `h` spent dispatching since the last call, added to
+        DISPATCH_METRIC by family. Read from the engine's running totals,
+        not from its ring: nothing evicts them, and they stand still while
+        recording is off."""
+        now = (ctypes.c_uint64 * 8)()
+        self._lib.rt_phase_totals(h, now)
+        seen = self._phase_seen.setdefault(h, [0] * 8)
+        for ph, family in TP_NAMES.items():
+            if now[ph] > seen[ph]:
+                metrics.inc(
+                    DISPATCH_METRIC,
+                    (now[ph] - seen[ph]) / 1e9,
+                    labels={"family": family},
+                )
+                seen[ph] = now[ph]
 
     def trace_dropped(self) -> int:
         total = self._trace_dropped_closed
@@ -1277,8 +1314,8 @@ class NativeSimulatedNetwork:
     def _cb_opaque(self, target, sender, era, kind, agreement, epoch, data, length):
         if self._cb_errors:
             return
+        sid = _cross_begin("opaque_message", era, target)
         try:
-            metrics.inc(CROSSINGS_METRIC, labels={"op": "opaque_message"})
             blob = ctypes.string_at(data, length) if length else b""
             self.routers[target]._on_opaque(
                 sender, era, kind, agreement, epoch, blob
@@ -1296,12 +1333,14 @@ class NativeSimulatedNetwork:
                     self._lib.rt_request_stop(h)
         except BaseException as exc:  # noqa: BLE001
             self._stash_cb_error(era, exc)
+        finally:
+            tracing.end(sid)
 
     def _cb_acs(self, target, era, nslots, slots, datas, lens):
         if self._cb_errors:
             return
+        sid = _cross_begin("acs_result", era, target)
         try:
-            metrics.inc(CROSSINGS_METRIC, labels={"op": "acs_result"})
             result = {
                 int(slots[i]): (
                     ctypes.string_at(datas[i], lens[i]) if lens[i] else b""
@@ -1311,30 +1350,41 @@ class NativeSimulatedNetwork:
             self.routers[target]._on_acs_result(era, result)
         except BaseException as exc:  # noqa: BLE001
             self._stash_cb_error(era, exc)
+        finally:
+            tracing.end(sid)
 
     def _cb_coinreq(self, target, era, agreement, epoch):
         if self._cb_errors:
             return
+        sid = _cross_begin("coin_request", era, target)
         try:
-            metrics.inc(CROSSINGS_METRIC, labels={"op": "coin_request"})
             self.routers[target]._on_coin_request(era, agreement, epoch)
         except BaseException as exc:  # noqa: BLE001
             self._stash_cb_error(era, exc)
+        finally:
+            tracing.end(sid)
 
     def _cb_cross(self, target, era, op, a, b, data, length):
         if self._cb_errors:
             return
+        sid = _cross_begin(XO_NAMES.get(op, f"op{op}"), era, target)
         try:
-            metrics.inc(
-                CROSSINGS_METRIC,
-                labels={"op": XO_NAMES.get(op, f"op{op}")},
-            )
             blob = ctypes.string_at(data, length) if length else b""
             self.routers[target]._on_cross(era, op, a, b, blob)
         except BaseException as exc:  # noqa: BLE001
             self._stash_cb_error(era, exc)
+        finally:
+            tracing.end(sid)
 
     # -- execution (simulator.py::run contract) --------------------------------
+    def _run_engine(self, h: int, chunk: int, era: int) -> int:
+        """One rt_run call under the span `engine.pump`: the engine's own
+        dispatch and every callback it makes meanwhile (cross.* inside)."""
+        sid = tracing.begin("engine.pump", "engine", era=era)
+        processed = self._lib.rt_run(h, chunk)
+        tracing.end(sid, processed=processed)
+        return processed
+
     def post_request(self, validator: int, pid, value) -> None:
         self._sync_ownership()
         # proposal injection does the RBC encode (erasure coding) before
@@ -1355,7 +1405,9 @@ class NativeSimulatedNetwork:
     ) -> bool:
         try:
             while not done():
-                processed = self._lib.rt_run(self._h, chunk)
+                processed = self._run_engine(
+                    self._h, chunk, self.routers[0].era
+                )
                 self.delivered_count += processed
                 self._raise_cb_error()
                 metrics.set_gauge(
@@ -1398,6 +1450,7 @@ class NativeSimulatedNetwork:
                     )
             return True
         finally:
+            self._fold_dispatch(self._h)
             metrics.set_gauge(
                 "consensus_native_handled_messages", self.native_handled()
             )
@@ -1478,7 +1531,7 @@ class NativeSimulatedNetwork:
             raise RuntimeError(f"era {era} engine is not open")
         delivered = 0
         while not done():
-            processed = self._lib.rt_run(h, chunk)
+            processed = self._run_engine(h, chunk, era)
             delivered += processed
             self.delivered_count += processed
             self._raise_cb_error(era)
@@ -1514,6 +1567,7 @@ class NativeSimulatedNetwork:
                     f"era {era} {lane}: message cap {max_messages} "
                     "exceeded — livelock?"
                 )
+        self._fold_dispatch(h)
 
     def run_front(
         self, era: int, max_messages: int = 2_000_000, chunk: int = 16384

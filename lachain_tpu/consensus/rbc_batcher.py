@@ -159,13 +159,17 @@ class RbcEraBatcher:
             "rbc_batch_size", len(encs) + len(uniq), buckets=_BATCH_BUCKETS
         )
         self.flushes += 1
-        for (_v, _k, _n, cb), shards in zip(encs, enc_out):
-            cb(shards)
-        for key in order:
-            memo[key] = verdicts[key]
-            for cb in waiters[key]:
-                cb(verdicts[key])
-        return len(encs) + len(interps)
+        # the answers go back into the engine one validator at a time; each
+        # post is engine work outside any rt_run, so it gets its own name
+        posts = len(encs) + len(interps)
+        with tracing.span("rbc.fanout", "engine", era=era, posts=posts):
+            for (_v, _k, _n, cb), shards in zip(encs, enc_out):
+                cb(shards)
+            for key in order:
+                memo[key] = verdicts[key]
+                for cb in waiters[key]:
+                    cb(verdicts[key])
+        return posts
 
     def _run_encodes(self, era: int, encs: List[tuple]) -> List[List[bytes]]:
         if not encs:

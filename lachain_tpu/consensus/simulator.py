@@ -330,5 +330,8 @@ class SimulatedNetwork:
                 requeued += router.replay_outbox(router.era, requester)
         return requeued > 0
 
+    def close(self) -> None:
+        """Nothing to release here; the native network frees its engine."""
+
     def results(self, pid) -> List[Any]:
         return [r.result_of(pid) for r in self.routers]

@@ -272,7 +272,7 @@ class NodeConfig:
         """Flight-recorder ring capacity (events) for BOTH the Python span
         ring and the native engine rings. Optional and additive (no config
         version bump): absent means the LACHAIN_TRACE_CAPACITY env / the
-        built-in default decides. 0 disables native recording."""
+        built-in default decides. 0 turns the recorder off, spans included."""
         cap = self.raw.get("observability", {}).get("traceCapacity")
         return None if cap is None else int(cap)
 

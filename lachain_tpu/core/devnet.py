@@ -247,8 +247,9 @@ class Devnet:
         # the era span is the flight recorder's attribution window: the
         # era report and the clock-alignment tests anchor on it
         with tracing.span("era", era=era):
-            for router in self.net.routers:
-                router.advance_era(era)
+            with tracing.span("era.advance", "engine", era=era):
+                for router in self.net.routers:
+                    router.advance_era(era)
             pid = M.RootProtocolId(era=era)
             for i in range(self.n):
                 self.net.post_request(i, pid, None)
@@ -402,9 +403,11 @@ class Devnet:
     # -- helpers ------------------------------------------------------------------
     def close(self) -> None:
         """Release per-node stores (no-op for MemoryKV; required for the
-        durable engines a kv_factory may supply)."""
+        durable engines a kv_factory may supply) and the native engine,
+        which is otherwise left to the collector."""
         for node in self.nodes:
             node.kv.close()
+        self.net.close()
 
     def balance(self, addr: bytes, node: int = 0) -> int:
         return get_balance(self.nodes[node].state.new_snapshot(), addr)

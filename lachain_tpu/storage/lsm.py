@@ -203,11 +203,12 @@ class LsmKV(KVStore):
         self._trace_dropped_seen = 0
         self._trace_pid = next(_next_trace_pid)
         self._trace_source = f"lsm-{os.path.basename(path) or path}-{id(self):x}"
-        self._lib.lsm_trace_configure(self._h, tracing.DEFAULT_CAPACITY)
+        self._lib.lsm_trace_configure(self._h, tracing.capacity())
         ref = weakref.ref(self)
         tracing.register_native_source(
             self._trace_source,
             lambda: [] if ref() is None else ref()._drain_trace(),
+            lambda n: None if ref() is None else ref().trace_configure(n),
         )
 
     # -- flight recorder -------------------------------------------------------

@@ -20,17 +20,22 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Dict, List, Optional
 
-_lock = threading.Lock()
+# re-entrant: a collector run while the lock is held may finalise a native
+# source's owner, whose close() drains into this module on the same thread
+_lock = threading.RLock()
 _ids = itertools.count(1)
 # finished spans, oldest evicted first; 8192 spans ≈ a few dozen eras at
 # N=16 — enough history to explain a stall without unbounded growth.
 # LACHAIN_TRACE_CAPACITY (env, or config observability.traceCapacity via
-# set_capacity) resizes both this ring and the native-engine rings.
-DEFAULT_CAPACITY = int(os.environ.get("LACHAIN_TRACE_CAPACITY") or 8192)
+# set_capacity) sizes this ring and the native-engine rings, those that are
+# registered and those built later (capacity()). 0 turns the recorder off:
+# `_done.maxlen` is the one switch every entry point reads.
+DEFAULT_CAPACITY = max(int(os.environ.get("LACHAIN_TRACE_CAPACITY") or 8192), 0)
 _done: deque = deque(maxlen=DEFAULT_CAPACITY)
+_OFF = nullcontext(0)  # what span() and wait() hand out while off
 _open: "Dict[int, _Span]" = {}
 # monotonic epoch so exported timestamps are small positive microseconds
 _epoch = time.monotonic()
@@ -42,7 +47,8 @@ _epoch = time.monotonic()
 # seconds (the source applies its clock-offset handshake before handing
 # events over). Events carrying `replace_key` are cumulative snapshots
 # (per-era dispatch-phase totals): only the latest per key is kept.
-_native_sources: "Dict[str, Callable[[], List[dict]]]" = {}
+# name -> (drain, resize or None)
+_native_sources: "Dict[str, tuple]" = {}
 _native_done: deque = deque(maxlen=DEFAULT_CAPACITY)
 _native_acc: Dict[tuple, dict] = {}
 # ring evictions (silent truncation made visible: satellite of ISSUE 6)
@@ -95,6 +101,8 @@ class _Span:
 
 def begin(name: str, cat: str = "era", **args) -> int:
     """Open a span; returns its id (pass to end()/annotate())."""
+    if not _done.maxlen:
+        return 0
     sid = next(_ids)
     sp = _Span(sid, name, cat, time.monotonic(), args)
     with _lock:
@@ -104,6 +112,8 @@ def begin(name: str, cat: str = "era", **args) -> int:
 
 def annotate(sid: int, **args) -> None:
     """Merge args into a still-open span (no-op once closed)."""
+    if not _done.maxlen:
+        return
     with _lock:
         sp = _open.get(sid)
         if sp is not None:
@@ -113,6 +123,8 @@ def annotate(sid: int, **args) -> None:
 def end(sid: int, **args) -> None:
     """Close a span; idempotent (a GC sweep and a normal completion may
     both try to close the same protocol span)."""
+    if not _done.maxlen:
+        return
     with _lock:
         sp = _open.pop(sid, None)
         if sp is None:
@@ -127,6 +139,8 @@ def end(sid: int, **args) -> None:
 
 def instant(name: str, cat: str = "era", **args) -> None:
     """Record a zero-duration event (block persisted, watchdog firing)."""
+    if not _done.maxlen:
+        return
     sp = _Span(next(_ids), name, cat, time.monotonic(), args)
     sp.end = sp.start
     with _lock:
@@ -135,9 +149,13 @@ def instant(name: str, cat: str = "era", **args) -> None:
         _done.append(sp)
 
 
-@contextmanager
 def span(name: str, cat: str = "era", **args):
     """Scoped begin/end; yields the span id for annotate()."""
+    return _span(name, cat, args) if _done.maxlen else _OFF
+
+
+@contextmanager
+def _span(name: str, cat: str, args: dict):
     sid = begin(name, cat, **args)
     try:
         yield sid
@@ -163,11 +181,15 @@ _WAIT_PRIORITY = {
 }
 
 
-@contextmanager
 def wait(resource: str, **args):
     """Scoped wait-state span: wraps a blocking call (queue get, fsync,
     device sync, socket read) so era_report() can attribute the idle it
     causes to `resource`. Also feeds the wait_seconds{resource} histogram."""
+    return _wait(resource, args) if _done.maxlen else _OFF
+
+
+@contextmanager
+def _wait(resource: str, args: dict):
     sid = begin(f"wait.{resource}", cat="wait", resource=resource, **args)
     t0 = time.monotonic()
     try:
@@ -212,8 +234,12 @@ def snapshot(limit: Optional[int] = None) -> List[dict]:
     now = time.monotonic()
     with _lock:
         done = list(_done)
-        live = sorted(_open.values(), key=lambda s: (s.start, s.sid))
-        out = [s.to_dict(now) for s in done + live]
+        # the few open ones under the lock: annotate() may still write them
+        out = [s.to_dict(now) for s in _open.values()]
+    # a finished span no longer changes: its dict is built with the lock
+    # free, so a long ring neither holds up the recording threads nor
+    # allocates (and so collects) inside the lock
+    out += [s.to_dict() for s in done]
     out.sort(key=lambda d: (d["start"], d["id"]))
     if limit is not None and limit > 0:
         out = out[-limit:]
@@ -248,15 +274,20 @@ def clock_offset(native_now_ns: Callable[[], int], samples: int = 5) -> float:
     return best_off
 
 
-def register_native_source(name: str, fn: Callable[[], List[dict]]) -> None:
+def register_native_source(
+    name: str,
+    fn: Callable[[], List[dict]],
+    resize: Optional[Callable[[int], None]] = None,
+) -> None:
     """Register a drain callback for a native engine's trace ring.
 
     `fn` returns event dicts with monotonic-aligned `start`/`end` seconds
     (the binding applies its clock-offset handshake), plus `pid`, `tid`,
-    `pname`, `tname` lane hints for the Chrome export. Re-registering a
-    name replaces the previous callback (engine restart)."""
+    `pname`, `tname` lane hints for the Chrome export. `resize(capacity)`
+    is what set_capacity() calls to resize the engine's own ring (0 = off).
+    Re-registering a name replaces the previous callbacks (engine restart)."""
     with _lock:
-        _native_sources[name] = fn
+        _native_sources[name] = (fn, resize)
 
 
 def unregister_native_source(name: str) -> None:
@@ -268,9 +299,11 @@ def drain_native() -> None:
     """Pull pending events out of every registered native ring into the
     merged buffer. Cheap when rings are empty; callers sprinkle this at
     quiescent points (era end, snapshot/export time)."""
+    if not _done.maxlen:
+        return
     with _lock:
-        sources = list(_native_sources.items())
-    for name, fn in sources:
+        sources = list(_native_sources.values())
+    for fn, _resize in sources:
         try:
             evs = fn()
         except Exception:
@@ -937,13 +970,30 @@ def critical_path_table(report: Optional[dict] = None) -> str:
     return "\n".join(lines) if lines else "<no completed eras in trace ring>"
 
 
+def capacity() -> int:
+    """The rings' size now; a native engine sizes its ring from it when it
+    is built. 0 = the recorder is off."""
+    return _done.maxlen
+
+
 def set_capacity(n: int) -> None:
-    """Resize the merged span rings (keeps the newest spans). Native
-    in-engine ring capacities are configured via their bindings."""
+    """Resize the merged span rings (keeps the newest spans) and the ring
+    of every registered native engine. 0 turns the recorder off: nothing
+    is recorded, held or counted as dropped, and the engines stop reading
+    their clocks."""
     global _done, _native_done
+    n = max(int(n), 0)
+    drain_native()  # resizing an engine's ring empties it
     with _lock:
-        _done = deque(_done, maxlen=max(int(n), 1))
-        _native_done = deque(_native_done, maxlen=max(int(n), 1))
+        _done = deque(_done, maxlen=n)
+        _native_done = deque(_native_done, maxlen=n)
+        if n == 0:
+            _open.clear()
+            _native_acc.clear()
+        sources = list(_native_sources.values())
+    for _drain, resize in sources:
+        if resize is not None:
+            resize(n)
 
 
 def reset_for_tests() -> None:
