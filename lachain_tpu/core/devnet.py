@@ -26,8 +26,8 @@ from ..utils.serialization import write_u64
 from . import system_contracts
 from .block_manager import BlockManager
 from .block_producer import BlockProducer
-from .execution import get_balance, get_nonce, set_balance
-from .tx_pool import TransactionPool
+from .execution import get_balance, set_balance
+from .tx_pool import StateNonces, TransactionPool
 from .types import (
     ZERO_HASH,
     Block,
@@ -129,7 +129,7 @@ class Devnet:
             pool = TransactionPool(
                 kv,
                 chain_id,
-                account_nonce=self._nonce_reader(state),
+                account_nonce=StateNonces(state),
             )
             producer = BlockProducer(bm, pool, n, txs_per_block, proposal_seed=i)
             self.nodes.append(
@@ -217,27 +217,22 @@ class Devnet:
 
             install_adversary(adversary, self.net)
 
-    @staticmethod
-    def _nonce_reader(state: StateManager):
-        def read(addr: bytes) -> int:
-            return get_nonce(state.new_snapshot(), addr)
-
-        return read
-
     # -- tx ingress -------------------------------------------------------------
     def submit_tx(self, stx: SignedTransaction, to_node: int = 0) -> bool:
         """Reference path: eth_sendRawTransaction -> TransactionPool.Add; the
         devnet gossips the tx to every node's pool (BroadcastLocalTransaction
         role)."""
-        from ..utils import txtrace
+        from ..utils import tracing, txtrace
 
-        txtrace.stamp(stx.hash(), "submit")
-        ok = self.nodes[to_node].pool.add(stx)
-        if ok:
-            for node in self.nodes:
-                if node.index != to_node:
-                    node.pool.add(stx)
-        return ok
+        # between eras, so outside every `era` span: the hand-over of load
+        with tracing.span("devnet.submit_tx", "pool"):
+            txtrace.stamp(stx.hash(), "submit")
+            ok = self.nodes[to_node].pool.add(stx)
+            if ok:
+                for node in self.nodes:
+                    if node.index != to_node:
+                        node.pool.add(stx)
+            return ok
 
     # -- era loop ----------------------------------------------------------------
     def run_era(self, era: int, max_messages: int = 2_000_000) -> List[Block]:

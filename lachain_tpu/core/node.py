@@ -29,10 +29,10 @@ from ..storage.kv import EntryPrefix, KVStore, MemoryKV, prefixed
 from ..storage.state import StateManager
 from .block_manager import BlockManager
 from .block_producer import BlockProducer
-from .execution import TransactionExecuter, get_nonce
+from .execution import TransactionExecuter
 from .keygen_manager import KeyGenManager
 from .synchronizer import BlockSynchronizer
-from .tx_pool import TransactionPool
+from .tx_pool import StateNonces, TransactionPool
 from .types import (
     Block,
     SignedTransaction,
@@ -111,7 +111,7 @@ class Node:
             validator_pubs=list(public_keys.ecdsa_pub_keys),
         )
         self.pool = TransactionPool(
-            self.kv, chain_id, account_nonce=self._account_nonce
+            self.kv, chain_id, account_nonce=StateNonces(self.state)
         )
         # crash-restore: repopulate from the persisted pool repository (the
         # repository existed but was never replayed on open — a restart
@@ -628,9 +628,6 @@ class Node:
             )
             logger.info("rejoin: requested replay for eras %s", self._rejoin_eras)
             self._rejoin_eras = []
-
-    def _account_nonce(self, addr: bytes) -> int:
-        return get_nonce(self.state.new_snapshot(), addr)
 
     # -- tx ingress + gossip -----------------------------------------------
 

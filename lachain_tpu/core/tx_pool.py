@@ -16,9 +16,18 @@ recovery — runs OUTSIDE every lock. `txpool_admit_lock_wait_seconds`
 histograms the time an admitting thread spends blocked on its shard lock,
 which is the direct measure of residual admission contention.
 
+The pool is INDEXED: a shard keeps each sender's transactions as one
+nonce-ordered chain, every entry with its fee key computed once at
+admission, and the pool keeps a hash -> sender map. Proposal, eviction and
+sanitize then cost what they touch: one state nonce read per pooled sender
+(`txpool_state_nonce_reads_total` counts them), not one per pooled
+transaction, and a hash names its shard without a probe of all of them.
+
 Lock ordering: shard lock -> `_nonce_lock` (state-trie nonce reads; the
 trie's LRU cache is not thread-safe). No path acquires two shard locks
-at once, so there is no cross-shard ordering to get wrong.
+at once, so there is no cross-shard ordering to get wrong. `peek` copies
+each shard's chains under its lock; its nonce reads and the merge run
+outside every shard lock.
 """
 from __future__ import annotations
 
@@ -26,10 +35,13 @@ import heapq
 import random
 import threading
 import time
+from bisect import bisect_left
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
+from ..storage.crashpoints import crash_point
 from ..storage.kv import EntryPrefix, KVStore, prefixed
-from ..utils import metrics, txtrace
+from ..utils import metrics, tracing, txtrace
+from .execution import get_nonce
 from .types import SignedTransaction
 
 _N_SHARDS = 16
@@ -38,18 +50,43 @@ _N_SHARDS = 16
 # interesting range (lock convoy under ingest bursts)
 _LOCK_WAIT_BUCKETS = (1e-6, 1e-5, 1e-4, 1e-3, 1e-2, 1e-1)
 
+# byte-wise 255 - b: among equal fees the LARGER hash is proposed first
+_INVERT = bytes(255 - b for b in range(256))
+
+# (nonce, (-gas_price, inverted hash), hash, tx). A chain is a list of
+# these in nonce order; nonces are unique within it, so `(nonce,)` bisects
+# it and no comparison ever reaches the transaction.
+_Entry = Tuple[int, Tuple[int, bytes], bytes, SignedTransaction]
+
+
+class StateNonces:
+    """The pool's nonce reader over a StateManager: account nonces of the
+    committed state. `version()` is the committed roots object, which every
+    commit and rollback replaces; a pool whose reader has one keeps a
+    sender's nonce until that identity changes, so a commit by any path
+    (block producer, synchronizer) is seen by the very next read."""
+
+    def __init__(self, state) -> None:
+        self._state = state
+
+    def __call__(self, addr: bytes) -> int:
+        return get_nonce(self._state.new_snapshot(), addr)
+
+    def version(self) -> object:
+        return self._state.committed
+
 
 class _PoolShard:
     """One lock domain: the slice of the pool whose senders hash here."""
 
-    __slots__ = ("lock", "txs", "senders", "by_nonce")
+    __slots__ = ("lock", "txs", "chains")
 
     def __init__(self) -> None:
         self.lock = threading.RLock()
         self.txs: Dict[bytes, SignedTransaction] = {}
-        self.senders: Dict[bytes, bytes] = {}  # tx hash -> sender
-        # (sender, nonce) -> tx hash (reference TransactionHashTrackerByNonce)
-        self.by_nonce: Dict[Tuple[bytes, int], bytes] = {}
+        # sender -> its pooled txs in nonce order (reference
+        # TransactionHashTrackerByNonce); never left empty
+        self.chains: Dict[bytes, List[_Entry]] = {}
 
 
 class TransactionPool:
@@ -65,19 +102,37 @@ class TransactionPool:
         self._account_nonce_fn = account_nonce
         self.min_gas_price = min_gas_price
         self._shards = [_PoolShard() for _ in range(_N_SHARDS)]
+        # tx hash -> sender, over all shards: written under the sender's
+        # shard lock, read without one (dedup, and to name a hash's shard)
+        self._sender_of: Dict[bytes, bytes] = {}
         # state-trie nonce reads go through the trie's LRU cache, which is
         # not safe under concurrent mutation — serialize them
         self._nonce_lock = threading.Lock()
+        # nonces read at `_nonce_version` (see StateNonces); under _nonce_lock
+        self._nonce_version: object = None
+        self._nonce_memo: Dict[bytes, int] = {}
 
     def _shard(self, sender: bytes) -> _PoolShard:
         return self._shards[sender[0] % _N_SHARDS]
 
     def _account_nonce(self, sender: bytes) -> int:
         with self._nonce_lock:
-            return self._account_nonce_fn(sender)
+            version_of = getattr(self._account_nonce_fn, "version", None)
+            if version_of is None:
+                metrics.inc("txpool_state_nonce_reads_total")
+                return self._account_nonce_fn(sender)
+            version = version_of()
+            if version is not self._nonce_version:
+                self._nonce_version = version
+                self._nonce_memo = {}
+            nonce = self._nonce_memo.get(sender)
+            if nonce is None:
+                metrics.inc("txpool_state_nonce_reads_total")
+                nonce = self._nonce_memo[sender] = self._account_nonce_fn(sender)
+            return nonce
 
     def __len__(self) -> int:
-        return sum(len(s.txs) for s in self._shards)
+        return len(self._sender_of)
 
     # -- ingress --------------------------------------------------------------
     def precheck(self, stx: SignedTransaction) -> bool:
@@ -85,24 +140,24 @@ class TransactionPool:
         signature recovery. Bulk-ingest callers filter through this BEFORE
         paying for batch sender recovery, so re-gossiped duplicates cost a
         hash lookup, not an ECDSA recover. Advisory by design (add()
-        re-checks under the shard lock), so the dict probes run lock-free."""
+        re-checks under the shard lock), so the dict probe runs lock-free."""
         if stx.tx.gas_price < self.min_gas_price:
             return False
-        h = stx.hash()
-        return all(h not in s.txs for s in self._shards)
+        return stx.hash() not in self._sender_of
 
     def add(self, stx: SignedTransaction) -> bool:
         """Verify + admit. Returns False (and drops) on any rule violation."""
         h = stx.hash()
         if stx.tx.gas_price < self.min_gas_price:
             return False
-        if any(h in s.txs for s in self._shards):
+        if h in self._sender_of:
             return False  # lock-free dedup; re-checked under the shard lock
         # ECDSA recovery is the expensive step — outside every lock
         sender = stx.sender(self.chain_id)
         if sender is None:
             return False
         shard = self._shard(sender)
+        nonce = stx.tx.nonce
         t0 = time.perf_counter()
         with shard.lock:
             metrics.observe_hist(
@@ -112,24 +167,28 @@ class TransactionPool:
             )
             if h in shard.txs:
                 return False
-            current = self._account_nonce(sender)
-            if stx.tx.nonce < current:
+            if nonce < self._account_nonce(sender):
                 return False  # already used
-            key = (sender, stx.tx.nonce)
-            if key in shard.by_nonce:
-                # replacement only for strictly higher fee
-                old = shard.txs.get(shard.by_nonce[key])
-                if old is not None and stx.tx.gas_price <= old.tx.gas_price:
-                    return False
-                self._evict_in_shard(shard, shard.by_nonce[key])
+            entry = (nonce, (-stx.tx.gas_price, h.translate(_INVERT)), h, stx)
+            chain = shard.chains.get(sender)
+            if chain is None:
+                shard.chains[sender] = [entry]
+            else:
+                i = bisect_left(chain, (nonce,))
+                if i < len(chain) and chain[i][0] == nonce:
+                    # replacement only for strictly higher fee
+                    old = chain[i]
+                    if stx.tx.gas_price <= old[3].tx.gas_price:
+                        return False
+                    self._forget(shard, old[2])
+                    chain[i] = entry
+                else:
+                    chain.insert(i, entry)
             shard.txs[h] = stx
-            shard.senders[h] = sender
-            shard.by_nonce[key] = h
+            self._sender_of[h] = sender
             # the pool's crash window: admitted to memory, not yet in the
             # crash-restore repository — a kill here loses the tx from the
             # restart (best-effort by design; gossip re-fills)
-            from ..storage.crashpoints import crash_point
-
             crash_point("pool.save.mid")
             self._kv.put(prefixed(EntryPrefix.POOL_TX, h), stx.encode())
         # tx lifecycle stamp OUTSIDE the shard lock (admission succeeded;
@@ -144,8 +203,11 @@ class TransactionPool:
         shard = self._shard(sender)
         with shard.lock:
             nonce = self._account_nonce(sender)
-            while (sender, nonce) in shard.by_nonce:
+            chain = shard.chains.get(sender, ())
+            i = bisect_left(chain, (nonce,))
+            while i < len(chain) and chain[i][0] == nonce:
                 nonce += 1
+                i += 1
             return nonce
 
     def peek(
@@ -175,121 +237,89 @@ class TransactionPool:
         the per-sender chain start past their nonces — state reads still
         see the committed trie, which is exactly the sequential outcome
         once the in-flight blocks land."""
-        if rng is not None:
-            window = self._peek_ordered_with_senders(
-                window_txs if window_txs is not None else 4 * max_txs,
-                exclude=exclude,
-                nonce_override=nonce_override,
-            )
-            if len(window) > max_txs:
-                by_sender: Dict[bytes, List[SignedTransaction]] = {}
-                order: List[bytes] = []
-                for s, stx in window:
-                    if s not in by_sender:
-                        by_sender[s] = []
-                        order.append(s)
-                    by_sender[s].append(stx)
-                rng.shuffle(order)
-                picked: List[SignedTransaction] = []
-                for s in order:
-                    take = min(len(by_sender[s]), max_txs - len(picked))
-                    picked.extend(by_sender[s][:take])
-                    if len(picked) >= max_txs:
-                        break
-                return picked
-            return [stx for _, stx in window]
-        return self._peek_ordered(
-            max_txs, exclude=exclude, nonce_override=nonce_override
-        )
+        with tracing.span("pool.peek", "pool", size=len(self)):
+            chains = self._executable_chains(exclude, nonce_override)
+            if rng is None:
+                return [e[3] for _, e in _merge(chains, max_txs)]
+            window = window_txs if window_txs is not None else 4 * max_txs
+            executable = sum(len(c) for c in chains)
+            if min(executable, window) <= max_txs:
+                return [e[3] for _, e in _merge(chains, window)]
+            if executable <= window:
+                # the window holds every executable tx: senders first
+                # appear in it in the order of their chain heads' keys,
+                # each with its whole chain — no merge needed to know that
+                sampled = sorted(chains, key=lambda c: c[0][1])
+            else:
+                in_window: Dict[int, List[_Entry]] = {}
+                for j, e in _merge(chains, window):
+                    in_window.setdefault(j, []).append(e)
+                sampled = list(in_window.values())
+            rng.shuffle(sampled)
+            picked: List[SignedTransaction] = []
+            for chain in sampled:
+                take = min(len(chain), max_txs - len(picked))
+                picked.extend(e[3] for e in chain[:take])
+                if len(picked) >= max_txs:
+                    break
+            return picked
 
-    def _snapshot(self) -> List[Tuple[bytes, bytes, SignedTransaction]]:
-        """(hash, sender, tx) triples — each shard copied under its own
-        lock, the union processed lock-free by the caller."""
-        out: List[Tuple[bytes, bytes, SignedTransaction]] = []
+    def _executable_chains(
+        self,
+        exclude: Optional[Set[bytes]],
+        nonce_override: Optional[Dict[bytes, int]],
+    ) -> List[List[_Entry]]:
+        """Per sender, the run of pooled txs that starts at its next nonce
+        (state's, or the overlay's) with no gap; senders with none left out."""
+        pooled: List[Tuple[bytes, List[_Entry]]] = []
         for shard in self._shards:
             with shard.lock:
-                out.extend(
-                    (h, shard.senders[h], stx) for h, stx in shard.txs.items()
-                )
-        return out
-
-    def _peek_ordered(
-        self,
-        max_txs: int,
-        exclude: Optional[Set[bytes]] = None,
-        nonce_override: Optional[Dict[bytes, int]] = None,
-    ) -> List[SignedTransaction]:
-        return [
-            stx
-            for _, stx in self._peek_ordered_with_senders(
-                max_txs, exclude=exclude, nonce_override=nonce_override
-            )
-        ]
-
-    def _peek_ordered_with_senders(
-        self,
-        max_txs: int,
-        exclude: Optional[Set[bytes]] = None,
-        nonce_override: Optional[Dict[bytes, int]] = None,
-    ) -> List[Tuple[bytes, SignedTransaction]]:
-        per_sender: Dict[bytes, List[SignedTransaction]] = {}
-        for h, sender, stx in self._snapshot():
-            if exclude is not None and h in exclude:
-                continue  # claimed by an in-flight block
-            per_sender.setdefault(sender, []).append(stx)
-        # per-sender executable chains, nonce-ascending
-        chains: Dict[bytes, List[SignedTransaction]] = {}
-        for sender, txs in per_sender.items():
-            txs.sort(key=lambda t: t.tx.nonce)
+                pooled.extend((s, c[:]) for s, c in shard.chains.items())
+        chains: List[List[_Entry]] = []
+        for sender, chain in pooled:
+            if exclude:
+                # claimed by an in-flight block
+                chain = [e for e in chain if e[2] not in exclude]
+                if not chain:
+                    continue
             if nonce_override is not None and sender in nonce_override:
                 nonce = nonce_override[sender]
             else:
                 nonce = self._account_nonce(sender)
-            chain = []
-            for t in txs:
-                if t.tx.nonce != nonce:
-                    break  # gap: later nonces are unexecutable
-                chain.append(t)
-                nonce += 1
+            # the chain's FIRST tx has to be the next nonce: a stale one
+            # ahead of it (not yet sanitized) strands the sender, and a gap
+            # makes every later nonce unexecutable
+            k = 0
+            while k < len(chain) and chain[k][0] == nonce + k:
+                k += 1
+            del chain[k:]  # the copy is ours
             if chain:
-                chains[sender] = chain
-        # repeatedly take the highest-fee among the next-executable txs,
-        # so a cheap prerequisite nonce never strands an expensive later
-        # one (chain heads advance as they are picked). Heap keys are
-        # precomputed — one hash per tx, not per comparison.
-        def heap_key(stx: SignedTransaction):
-            h = stx.hash()
-            return (-stx.tx.gas_price, bytes(255 - b for b in h))
-
-        picked: List[Tuple[bytes, SignedTransaction]] = []
-        heap = [(heap_key(chain[0]), s, 0) for s, chain in chains.items()]
-        heapq.heapify(heap)
-        while len(picked) < max_txs and heap:
-            _, s, i = heapq.heappop(heap)
-            picked.append((s, chains[s][i]))
-            if i + 1 < len(chains[s]):
-                heapq.heappush(heap, (heap_key(chains[s][i + 1]), s, i + 1))
-        return picked
+                chains.append(chain)
+        return chains
 
     # -- lifecycle --------------------------------------------------------------
     def remove_included(self, tx_hashes) -> None:
-        for h in tx_hashes:
-            self._evict(h)
+        tx_hashes = list(tx_hashes)
+        with tracing.span("pool.remove_included", "pool", n=len(tx_hashes)):
+            for h in tx_hashes:
+                self._evict(h)
 
     def sanitize(self) -> int:
         """Drop txs whose nonce is now stale (reference sanitize-on-persist,
         TransactionPool.cs:79-90). Returns number evicted."""
         n = 0
-        for shard in self._shards:
-            with shard.lock:
-                stale = [
-                    h
-                    for h, stx in shard.txs.items()
-                    if stx.tx.nonce < self._account_nonce(shard.senders[h])
-                ]
-                for h in stale:
-                    self._evict_in_shard(shard, h)
-                n += len(stale)
+        with tracing.span("pool.sanitize", "pool") as sid:
+            for shard in self._shards:
+                with shard.lock:
+                    for sender, chain in list(shard.chains.items()):
+                        stale = bisect_left(chain, (self._account_nonce(sender),))
+                        for entry in chain[:stale]:
+                            self._forget(shard, entry[2])
+                        del chain[:stale]
+                        if not chain:
+                            del shard.chains[sender]
+                        n += stale
+            tracing.annotate(sid, evicted=n)
         return n
 
     def restore(self) -> int:
@@ -310,22 +340,32 @@ class TransactionPool:
         return count
 
     def _evict(self, h: bytes) -> None:
-        # hash alone does not name the shard — probe each, one lock at a
-        # time (never nested, so shard locks stay unordered)
-        for shard in self._shards:
+        sender = self._sender_of.get(h)
+        if sender is not None:
+            shard = self._shard(sender)
             with shard.lock:
                 if h in shard.txs:
                     self._evict_in_shard(shard, h)
                     return
+        # not pooled (or evicted since the lookup): the repository may
+        # still hold it
+        self._kv.delete(prefixed(EntryPrefix.POOL_TX, h))
+
+    def _forget(self, shard: _PoolShard, h: bytes) -> None:
+        """Caller holds shard.lock and `h` is pooled there. Drops everything
+        of `h` but its chain entry, which the caller replaces or deletes."""
+        del shard.txs[h]
+        del self._sender_of[h]
         self._kv.delete(prefixed(EntryPrefix.POOL_TX, h))
 
     def _evict_in_shard(self, shard: _PoolShard, h: bytes) -> None:
-        """Caller holds shard.lock."""
-        stx = shard.txs.pop(h, None)
-        sender = shard.senders.pop(h, None)
-        if stx is not None and sender is not None:
-            shard.by_nonce.pop((sender, stx.tx.nonce), None)
-        self._kv.delete(prefixed(EntryPrefix.POOL_TX, h))
+        """Caller holds shard.lock and `h` is pooled there."""
+        sender, nonce = self._sender_of[h], shard.txs[h].tx.nonce
+        self._forget(shard, h)
+        chain = shard.chains[sender]
+        del chain[bisect_left(chain, (nonce,))]
+        if not chain:
+            del shard.chains[sender]
 
     def tx_hashes(self) -> set:
         """Snapshot of pooled tx hashes (pending-tx filters)."""
@@ -363,8 +403,27 @@ class TransactionPool:
         return n
 
     def get(self, h: bytes) -> Optional[SignedTransaction]:
-        for shard in self._shards:
-            stx = shard.txs.get(h)
-            if stx is not None:
-                return stx
-        return None
+        sender = self._sender_of.get(h)
+        if sender is None:
+            return None
+        return self._shard(sender).txs.get(h)
+
+
+def _merge(
+    chains: List[List[_Entry]], limit: int
+) -> List[Tuple[int, _Entry]]:
+    """The first `limit` entries of the chains' fee-ordered merge, each with
+    its chain's index: repeatedly the best key among the next-executable
+    txs, so a cheap prerequisite nonce never strands an expensive later one
+    (chain heads advance as they are picked). Keys are unique, so the heap
+    compares nothing past them."""
+    picked: List[Tuple[int, _Entry]] = []
+    heap = [(chain[0][1], j, 0) for j, chain in enumerate(chains)]
+    heapq.heapify(heap)
+    while len(picked) < limit and heap:
+        _, j, i = heapq.heappop(heap)
+        chain = chains[j]
+        picked.append((j, chain[i]))
+        if i + 1 < len(chain):
+            heapq.heappush(heap, (chain[i + 1][1], j, i + 1))
+    return picked
