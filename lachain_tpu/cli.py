@@ -246,19 +246,10 @@ def _build_node(cfg, config_path=None):
         node.idle_alert_fraction = float(cfg.idle_alert_fraction)
     if cfg.wan_shaper:
         # network.wanShaper: emulated WAN matrix on this node's outbound
-        # frames (network/faults.py LinkShaper). Every node in the fleet
-        # carries the same spec, so the pairwise latency/bandwidth matrix
-        # is consistent even though each node only shapes its own sends.
-        # Validator indices are the shaper's node ids — the same striping
-        # keygen --regions writes into network.region.
-        from .network.faults import FaultPlan, LinkShaper
-
-        shaper = LinkShaper.parse(cfg.wan_shaper)
-        node.network.install_faults(
-            FaultPlan(seed=cfg.genesis.chain_id, shaper=shaper), idx
+        # frames (network/faults.py LinkShaper), seeded with the chain id
+        node.network.install_wan_shaper(
+            cfg.wan_shaper, idx, pub.ecdsa_pub_keys, cfg.genesis.chain_id
         )
-        for j, vpub in enumerate(pub.ecdsa_pub_keys):
-            node.network.map_fault_peer(vpub, j)
     peers = []
     for spec in cfg.network.peers:
         host, port, pubhex = spec.rsplit(":", 2)

@@ -32,7 +32,7 @@ import time
 from typing import Dict, List, Optional
 
 from ..consensus.keys import trusted_key_gen
-from ..network.faults import FaultPlan, LinkShaper
+from ..network.faults import LinkShaper
 from .node import Node
 from .types import SignedTransaction
 
@@ -106,11 +106,9 @@ class TcpFleet:
     def _install_shaper(self, node: Node, i: int) -> None:
         if self.shaper is None:
             return
-        node.network.install_faults(
-            FaultPlan(seed=self.fault_seed, shaper=self.shaper), i
+        node.network.install_wan_shaper(
+            self.shaper, i, self.public_keys.ecdsa_pub_keys, self.fault_seed
         )
-        for j, pub in enumerate(self.public_keys.ecdsa_pub_keys):
-            node.network.map_fault_peer(pub, j)
 
     async def start(self, first_era: int = 1) -> None:
         for i in range(self.n):

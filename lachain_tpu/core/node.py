@@ -890,11 +890,15 @@ class Node:
                 done = asyncio.ensure_future(self._era_done.wait())
                 height = asyncio.ensure_future(self._height_event.wait())
                 try:
-                    await asyncio.wait(
-                        [done, height],
-                        timeout=remaining,
-                        return_when=asyncio.FIRST_COMPLETED,
-                    )
+                    # the era parks here while reader tasks feed the
+                    # router: what the loop's thread then spends in
+                    # select(), nothing ready, is the era's network idle
+                    with tracing.loop_idle("era.net_idle", cat="net", era=era):
+                        await asyncio.wait(
+                            [done, height],
+                            timeout=remaining,
+                            return_when=asyncio.FIRST_COMPLETED,
+                        )
                 finally:
                     for fut in (done, height):
                         fut.cancel()

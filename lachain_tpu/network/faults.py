@@ -399,6 +399,9 @@ class FaultSession:
             delays = [d + shaped for d in delays]
             self.stats["shaped"] += 1
             metrics.inc("fault_injected_total", labels={"action": "shape"})
+            # the seconds those frames were held: what a WAN costs this
+            # process's sends, beside how many frames it touched
+            metrics.inc("network_shaped_delay_seconds_total", shaped)
         self.stats["delivered"] += 1
         return delays
 

@@ -12,7 +12,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import zlib
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 from ..utils import metrics
 from . import wire
@@ -494,6 +494,26 @@ class NetworkManager:
         """Tell the installed fault filter which plan node a transport
         identity is (link-level partitions/crashes need the mapping)."""
         getattr(self, "_fault_peer_ids", {})[public_key] = node_id
+
+    def install_wan_shaper(
+        self, spec, my_id: int, validator_pubs: Sequence[bytes], seed: int
+    ):
+        """The one way a validator gets its emulated WAN (config
+        network.wanShaper, the fleet harness, the benchmark's hb16-wan):
+        `spec` is a LinkShaper or its spec string, `my_id` this validator's
+        index, `validator_pubs` the committee's ECDSA keys in index order —
+        validator indices are the shaper's node ids, the striping `keygen
+        --regions` writes into network.region. Every node of a fleet
+        carries the same spec and seed, so the pairwise matrix is
+        consistent although each node only shapes its own sends. Returns
+        the TcpFrameFilter (its session holds the stats)."""
+        from .faults import FaultPlan, LinkShaper
+
+        shaper = LinkShaper.parse(spec) if isinstance(spec, str) else spec
+        filt = self.install_faults(FaultPlan(seed=seed, shaper=shaper), my_id)
+        for j, pub in enumerate(validator_pubs):
+            self.map_fault_peer(pub, j)
+        return filt
 
     def _reconnect_allowed(self, public_key: bytes, now: float) -> bool:
         """Spend one token from `public_key`'s reconnect bucket. Refill is

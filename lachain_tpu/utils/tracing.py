@@ -208,6 +208,49 @@ def _wait(resource: str, args: dict):
             pass
 
 
+# selector -> how many loop_idle() scopes are open on its loop
+_idle_scopes: Dict[Any, int] = {}
+
+
+@contextmanager
+def loop_idle(name: str, cat: str = "wait", **args):
+    """While the scope is open, every blocking select() of the running
+    asyncio loop is one span `name`: the stretches in which the loop's
+    thread has nothing ready — no frame read, no handler, no timer due.
+    One thread parks in one select at a time, so the spans never overlap
+    and their lengths add (what the per-connection wait.net cannot give).
+    Scopes nest (a fleet of nodes on one loop): the outermost one's name
+    and args are recorded. Loops without a selector (not asyncio's own)
+    record nothing."""
+    import asyncio
+
+    selector = getattr(asyncio.get_running_loop(), "_selector", None)
+    if selector is None or not _done.maxlen:
+        yield
+        return
+    if selector not in _idle_scopes:
+        inner = selector.select
+
+        def select(timeout=None):
+            if timeout is not None and timeout <= 0:
+                return inner(timeout)  # a poll: work is ready
+            sid = begin(name, cat, **args)
+            try:
+                return inner(timeout)
+            finally:
+                end(sid)
+
+        selector.select = select  # shadows the method on this instance
+    _idle_scopes[selector] = _idle_scopes.get(selector, 0) + 1
+    try:
+        yield
+    finally:
+        _idle_scopes[selector] -= 1
+        if not _idle_scopes[selector]:
+            del _idle_scopes[selector]
+            del selector.select
+
+
 def open_spans() -> List[dict]:
     """Snapshot of currently-open spans, oldest first (the watchdog's
     view of what the node is stuck inside)."""

@@ -162,6 +162,47 @@ def test_shaped_two_region_fleet_is_bit_identical():
     assert runs[0][2]["shaped"] > 0
 
 
+@pytest.mark.parametrize("caller", ["cli", "fleet"])
+def test_install_wan_shaper_decides_as_the_two_old_blocks_did(caller):
+    """cli._build_node and TcpFleet._install_shaper each carried this
+    block — parse (the CLI only), install_faults, one map_fault_peer per
+    validator; NetworkManager.install_wan_shaper is now the one copy. For
+    one seed, the session it installs holds the same frames to the same
+    peers for the same time."""
+    from lachain_tpu.network.hub import PeerAddress
+
+    spec = "regions=us,eu,ap,sa;default=40ms/5ms;us-eu=25ms/3ms;intra=2ms/1ms;burst=0.01x8"
+    pubs = [ecdsa.public_key_bytes(_priv(100 + j)) for j in range(8)]
+    me, seed = 5, 225
+
+    old = NetworkManager(_priv(100 + me))
+    old_filt = old.install_faults(
+        FaultPlan(seed=seed, shaper=LinkShaper.parse(spec)), me
+    )
+    for j, pub in enumerate(pubs):
+        old.map_fault_peer(pub, j)
+
+    new = NetworkManager(_priv(100 + me))
+    new_filt = new.install_wan_shaper(
+        spec if caller == "cli" else LinkShaper.parse(spec), me, pubs, seed
+    )
+    assert new.hub.frame_filter is new_filt
+
+    rng = random.Random(9)
+    frames = [
+        (PeerAddress(pubs[rng.randrange(8)], "127.0.0.1", 1), bytes(rng.randrange(40, 900)))
+        for _ in range(600)
+    ]
+    stranger = PeerAddress(b"\x02" * 33, "127.0.0.1", 1)  # no validator: unshaped
+    frames.insert(300, (stranger, b"x" * 50))
+    got = [new_filt.outbound(peer, data) for peer, data in frames]
+    assert got == [old_filt.outbound(peer, data) for peer, data in frames]
+    assert got[300] == [0.0] and sum(d == [0.0] for d in got) > 1  # frames to itself too
+    assert all(d[0] >= 0.002 for d in got if d != [0.0])
+    assert new_filt.session.stats == old_filt.session.stats
+    assert new_filt.session.stats["shaped"] > 400
+
+
 def test_native_engine_rejects_shaper_plans():
     sh = LinkShaper.parse("regions=a,b;default=3")
     with pytest.raises(ValueError, match="link shaper"):
