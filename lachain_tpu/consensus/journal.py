@@ -11,9 +11,32 @@ it already voted on — and two signed values for one slot is Byzantine
 behavior that honest peers will use against us.
 
 This journal records every outbound consensus payload (era, target, wire
-bytes) under the ``EntryPrefix.CONSENSUS_STATE`` keyspace, written through
-the KV's batched fsynced path before the payload reaches the transport.
-On restart the node replays it to:
+bytes) under the ``EntryPrefix.CONSENSUS_STATE`` keyspace, durable before
+any frame that carries the payload leaves the node. The rule has two
+halves, and ``tools/check_invariants.py`` rule P holds both:
+
+  * ``record`` is called before the payload reaches the transport
+    (``EraRouter._durable_send``) and submits the record to the KV's WAL;
+  * ``barrier`` returns only once every submitted record is fsynced. A
+    transport that delivers at once has no later moment to wait at, so
+    ``record`` barriers itself before it returns. A transport with a frame
+    boundary (``core/node.Node``: payloads queue on per-peer workers that
+    transmit at their flush tick) takes ``frame_barrier()`` and hands it
+    to the network, which calls it in front of every write to a socket
+    (``network/worker.durable_before_wire``: a worker's frame, and a
+    relay's reverse delivery to a client it has no worker for), and
+    ``record`` only submits: the node's one thread waits for its fsync
+    once a frame, not once a record, while the WAL writer group-commits
+    the records between.
+
+A crash between ``record`` and the barrier leaves the record either absent
+(not journaled, and no frame carried it) or present (recovery re-arms it
+and the re-run re-sends it byte-identically): the states a crash between
+``record`` and the flush tick always left. On a KV without an overlapping
+WAL (sqlite, memory) ``write_batch_async`` is the synchronous write and
+there is no ticket to wait for.
+
+On restart the node replays the journal to:
 
   * re-arm the era router's "already sent" latches — when the re-run era
     reaches the same decision point again, the RECORDED bytes are re-sent,
@@ -32,10 +55,11 @@ Key layout: ``CONSENSUS_STATE | era u64 | seq u64`` ->
 """
 from __future__ import annotations
 
-from typing import Dict, Iterator, Optional, Tuple
+from typing import Callable, Dict, Iterator, Optional, Tuple
 
+from ..storage.crashpoints import crash_point
 from ..storage.kv import EntryPrefix, KVStore, prefixed
-from ..utils import metrics
+from ..utils import metrics, tracing
 from ..utils.serialization import Reader, write_bytes, write_i64, write_u64
 
 from . import messages as M
@@ -78,10 +102,12 @@ def send_slot(payload) -> Optional[tuple]:
 class ConsensusJournal:
     """Append-only send journal over the node's KV store.
 
-    Writes ride ``write_batch`` — the KV's fsynced path — so a record is
-    durable before the send it covers leaves the node. Sequence numbers
-    are per-era and continue across restarts (seeded from a prefix scan at
-    construction), so replayed entries keep their original send order.
+    Records ride ``write_batch_async`` + ``write_barrier`` — the KV's WAL,
+    fsynced — so a record is durable before the send it covers leaves the
+    node (module docstring: where the wait for the fsync sits). Sequence
+    numbers are per-era and continue across restarts (seeded from a prefix
+    scan at construction), so replayed entries keep their original send
+    order.
     """
 
     def __init__(self, kv: KVStore):
@@ -90,17 +116,54 @@ class ConsensusJournal:
         for era, seq, _target, _data in self.entries():
             if seq >= self._next_seq.get(era, 0):
                 self._next_seq[era] = seq + 1
+        # newest write_batch_async ticket not yet waited for (tickets are
+        # WAL sequences: the newest covers every earlier one) and its era
+        self._ticket = None
+        self._ticket_era = 0
+        # False once a frame boundary has taken the wait (frame_barrier)
+        self._barrier_in_record = True
 
     def record(self, era: int, target: Optional[int], payload_bytes: bytes) -> None:
-        """Durably append one send BEFORE it is transmitted."""
-        seq = self._next_seq.get(era, 0)
-        key = _PREFIX + write_u64(era) + write_u64(seq)
-        value = write_i64(-1 if target is None else target) + write_bytes(
-            payload_bytes
-        )
-        self._kv.write_batch([(key, value)])
-        self._next_seq[era] = seq + 1
+        """Append one send BEFORE it reaches the transport: durable on
+        return, or — once frame_barrier() was taken — by the barrier in
+        front of the frame that carries it."""
+        with tracing.span("journal.record", cat="journal", era=era):
+            seq = self._next_seq.get(era, 0)
+            key = _PREFIX + write_u64(era) + write_u64(seq)
+            value = write_i64(-1 if target is None else target) + write_bytes(
+                payload_bytes
+            )
+            self._ticket = self._kv.write_batch_async([(key, value)])
+            self._ticket_era = era
+            self._next_seq[era] = seq + 1
+            if self._barrier_in_record:
+                self._wait()
         metrics.inc("consensus_journal_records_total")
+
+    def barrier(self) -> None:
+        """Return once every record submitted so far is durable. With no
+        ticket pending it returns at once, without a call into the KV."""
+        if self._ticket is None:
+            return
+        with tracing.span("journal.barrier", cat="journal", era=self._ticket_era):
+            self._wait()
+
+    def frame_barrier(self) -> Callable[[], None]:
+        """Hand the wait to a transport with a frame boundary: the caller
+        runs the returned hook before every frame leaves the node, and
+        record() stops waiting itself."""
+        self._barrier_in_record = False
+        return self.barrier
+
+    def _wait(self) -> None:
+        if self._ticket is None:
+            return
+        crash_point("journal.barrier.pre")
+        # forgotten only once durable: a barrier that raises (a failed WAL)
+        # leaves the ticket, so no later frame passes on an empty one
+        self._kv.write_barrier(self._ticket)
+        self._ticket = None
+        metrics.inc("consensus_journal_barriers_total")
 
     def entries(self) -> Iterator[Tuple[int, int, Optional[int], bytes]]:
         """Yield (era, seq, target, payload_bytes) in (era, seq) order.

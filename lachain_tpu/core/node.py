@@ -144,6 +144,10 @@ class Node:
             port,
             flush_interval=flush_interval,
             advertise_host=advertise_host,
+            # the network only queues a consensus payload, so the journal's
+            # fsync wait moves to where it writes to a socket: once a
+            # frame, not once a record
+            barrier=self.journal.frame_barrier(),
         )
         self._relay_spec = relay
         self.network.on_consensus = self._on_consensus

@@ -19,7 +19,7 @@ from . import wire
 from .hub import Hub, PeerAddress
 from .rtt import RttTracker
 from .wire import MessageBatch, MessageFactory, NetworkMessage
-from .worker import ClientWorker
+from .worker import ClientWorker, durable_before_wire
 
 logger = logging.getLogger(__name__)
 
@@ -33,7 +33,13 @@ class NetworkManager:
         *,
         flush_interval: float = 0.25,
         advertise_host: Optional[str] = None,
+        barrier: Optional[Callable[[], None]] = None,
     ):
+        # persist-before-transmit, the frame's half: run in front of every
+        # write of ours to a socket (worker.durable_before_wire) — by each
+        # worker before its frame, and by _send_inbound before a reverse
+        # delivery. The manager is its one owner.
+        self._barrier = barrier
         # the address peers should DIAL — differs from the bind host when
         # binding a wildcard (0.0.0.0) or behind NAT in multi-host deploys
         self.advertise_host = advertise_host or host
@@ -295,6 +301,7 @@ class NetworkManager:
                 peer, self.factory, self.hub,
                 flush_interval=self._flush_interval,
                 transport=self._relay_transport(peer.public_key, relay_pub),
+                barrier=self._barrier,
             )
             self._workers[peer.public_key] = worker
             worker.start()
@@ -335,6 +342,7 @@ class NetworkManager:
         worker = ClientWorker(
             peer, self.factory, self.hub,
             flush_interval=self._flush_interval,
+            barrier=self._barrier,
         )
         self._workers[peer.public_key] = worker
         worker.start()
@@ -433,7 +441,11 @@ class NetworkManager:
             return
 
         async def deliver():
-            ok = await self.hub.send_on_conn(conn_id, data)
+            # no worker stands between send_to and this socket, so the
+            # journal's barrier is taken here
+            ok = durable_before_wire(self._barrier) and (
+                await self.hub.send_on_conn(conn_id, data)
+            )
             if not ok and msg is not None:
                 self._buffer_undelivered(public_key, msg)
 

@@ -8,14 +8,15 @@ flushed at ~4 Hz — but as an asyncio task instead of a thread.
 from __future__ import annotations
 
 import asyncio
+import logging
 import random
 import zlib
 from collections import deque
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 from ..utils import metrics
 from .hub import Hub, PeerAddress
-from .wire import MessageBatch, MessageFactory, NetworkMessage, PRIORITY
+from .wire import MessageFactory, NetworkMessage, PRIORITY
 
 MAX_BATCH_BYTES = 64 * 1024
 FLUSH_INTERVAL = 0.25
@@ -24,6 +25,27 @@ FLUSH_INTERVAL = 0.25
 # the highest priority so they shed last
 MAX_QUEUE_BYTES = 8 * 1024 * 1024
 BACKOFF_MAX = 8.0
+
+
+logger = logging.getLogger(__name__)
+
+
+def durable_before_wire(barrier: Optional[Callable[[], None]]) -> bool:
+    """Persist-before-transmit, the frame's half (consensus/journal.py):
+    the step in front of every write to a socket. `barrier` returns once
+    whatever the node journaled is durable; None means the owner journals
+    nothing, or waits inside its own record. False when the barrier raised
+    (a WAL that cannot fsync): the frame must not leave, and the caller
+    treats it as a send that failed — keeps the messages and tries again."""
+    if barrier is None:
+        return True
+    try:
+        barrier()
+    except Exception:
+        logger.exception("journal barrier failed: frame held back")
+        metrics.inc("network_barrier_failures_total")
+        return False
+    return True
 
 
 class ClientWorker:
@@ -36,18 +58,19 @@ class ClientWorker:
         flush_interval: float = FLUSH_INTERVAL,
         max_batch_bytes: int = MAX_BATCH_BYTES,
         transport=None,
+        barrier: Optional[Callable[[], None]] = None,
     ):
         self.peer = peer
         self._factory = factory
         self._hub = hub
-        # transport(peer, batch_bytes) -> bool; default dials the peer
-        # directly. Relay-routed peers get a transport that wraps the
-        # signed batch in a relay_forward envelope instead (the envelope
-        # preserves end-to-end authentication — the inner batch carries
-        # OUR signature and only the target verifies it).
-        self._transport = transport or (
-            lambda p, data: self._hub.send_raw(p, data)
-        )
+        # transport(peer, batch_bytes) -> bool; None dials the peer
+        # directly (_transmit). Relay-routed peers get a transport that
+        # wraps the signed batch in a relay_forward envelope instead (the
+        # envelope preserves end-to-end authentication — the inner batch
+        # carries OUR signature and only the target verifies it).
+        self._transport = transport
+        # run before every batch leaves (durable_before_wire)
+        self._barrier = barrier
         self._flush_interval = flush_interval
         self._max_batch_bytes = max_batch_bytes
         # one FIFO deque per priority level (PRIORITY values are a small
@@ -145,8 +168,7 @@ class ClientWorker:
             self._wakeup.clear()
             while self._pending():
                 msgs = self._drain_batch()
-                batch: MessageBatch = self._factory.batch(msgs)
-                ok = await self._transport(self.peer, batch.encode())
+                ok = await self._transmit(msgs)
                 if ok:
                     self._backoff = self._flush_interval
                     self.consecutive_failures = 0
@@ -170,7 +192,16 @@ class ClientWorker:
                     break
         # final flush on stop
         if self._pending():
-            msgs = self._drain_batch()
-            await self._transport(
-                self.peer, self._factory.batch(msgs).encode()
-            )
+            await self._transmit(self._drain_batch())
+
+    async def _transmit(self, msgs: List[NetworkMessage]) -> bool:
+        """Where a worker's frame leaves: sign the batch, wait for the
+        journal, hand it to the wire (tools/check_invariants.py rule P
+        holds the order). A barrier that fails is a send that failed: the
+        caller requeues the batch and backs off."""
+        data = self._factory.batch(msgs).encode()
+        if not durable_before_wire(self._barrier):
+            return False
+        if self._transport is None:
+            return await self._hub.send_raw(self.peer, data)
+        return await self._transport(self.peer, data)

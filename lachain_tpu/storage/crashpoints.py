@@ -51,6 +51,10 @@ Instrumented point names:
                                       the root record — leaves durable
                                       orphan nodes with no referencing
                                       root; fsck-clean, replay recommits
+  journal.barrier.pre                 consensus journal: records submitted
+                                      to the WAL writer, the fsync not yet
+                                      waited for, no frame carrying them
+                                      sent — each is absent or re-armed
 
 The lsm.* sites leave REAL torn native state (lsm.py calls the engine's
 partial-execution debug APIs before dying), identical bytes on disk in

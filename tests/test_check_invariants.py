@@ -192,6 +192,78 @@ def test_nested_def_sends_not_misattributed(tmp_path, capsys):
     assert rc == 0, out
 
 
+def test_frame_without_barrier_is_flagged(tmp_path, capsys):
+    # the frame's half of the pair: the final flush forgets the step, and a
+    # barrier AFTER the transport protects nothing
+    rc, out, _ = run_lint(tmp_path, {
+        "network/worker.py": """
+            class ClientWorker:
+                async def _run(self):
+                    await self._transport(self.peer, b"batch")
+                    durable_before_wire(self._barrier)
+
+                async def _final_flush(self):
+                    await self._hub.send_raw(self.peer, b"batch")
+        """,
+    }, capsys)
+    assert rc == 1
+    assert out.count("[persist-before-transmit]") == 2
+    assert "self._transport(...) in _run()" in out
+    assert "self.send_raw(...) in _final_flush()" in out
+    assert "barrier hook" in out
+
+
+def test_reverse_delivery_without_barrier_is_flagged(tmp_path, capsys):
+    # a relay client has no worker: the manager writes to its inbound
+    # connection itself, and a barrier in the ENCLOSING function does not
+    # cover the task that runs a loop turn later
+    rc, out, _ = run_lint(tmp_path, {
+        "network/manager.py": """
+            class NetworkManager:
+                def _send_inbound(self, conn_id, data):
+                    durable_before_wire(self._barrier)
+
+                    async def deliver():
+                        await self.hub.send_on_conn(conn_id, data)
+
+                    create_task(deliver())
+        """,
+    }, capsys)
+    assert rc == 1
+    assert out.count("[persist-before-transmit]") == 1
+    assert "self.send_on_conn(...) in deliver()" in out
+
+
+def test_barrier_before_frame_is_clean(tmp_path, capsys):
+    rc, out, _ = run_lint(tmp_path, {
+        "network/worker.py": """
+            class ClientWorker:
+                async def _transmit(self, msgs):
+                    data = self._factory.batch(msgs).encode()
+                    if not durable_before_wire(self._barrier):
+                        return False
+                    if self._transport is None:
+                        return await self._hub.send_raw(self.peer, data)
+                    return await self._transport(self.peer, data)
+        """,
+        "network/manager.py": """
+            class NetworkManager:
+                def _send_inbound(self, conn_id, data):
+                    async def deliver():
+                        ok = durable_before_wire(self._barrier) and (
+                            await self.hub.send_on_conn(conn_id, data)
+                        )
+        """,
+        # the rule is the network's: another package's _transport is its own
+        "rpc/other.py": """
+            class Relay:
+                async def forward(self, data):
+                    await self._transport(self.peer, data)
+        """,
+    }, capsys)
+    assert rc == 0, out
+
+
 # -- rule L: lock order ------------------------------------------------------
 
 

@@ -28,13 +28,28 @@ L. **Lock order** — every `threading.Lock()`/`RLock()` in the repo is
    linter cannot distinguish sibling instances, so RLock classes like the
    pool shards rely on their documented no-two-shards rule).
 
-P. **Persist-before-transmit** — in `consensus/`, a raw transport send
-   (`self._send(...)`, `self._engine_transport(...)`) must be dominated by
-   a journal write (`_durable_send` / `_native_send` /
-   `<journal>.record`) in the same function, approximated as "a journal
-   call appears on an earlier line of the same function body". Functions
-   that REPLAY already-journaled bytes are whitelisted below, with the
-   reason recorded next to the name.
+P. **Persist-before-transmit** — a consensus payload is durable in the
+   journal before any frame that carries it leaves the node. The rule is a
+   pair, and only the two halves together are the guarantee:
+   (1) in `consensus/`, a raw transport send (`self._send(...)`,
+   `self._engine_transport(...)`) must be dominated by a journal write
+   (`_durable_send` / `_native_send` / `<journal>.record`) in the same
+   function: every payload is SUBMITTED to the journal's WAL before the
+   transport sees it. Functions that REPLAY already-journaled bytes are
+   whitelisted below, with the reason recorded next to the name.
+   (2) under `network/`, every call that reaches the wire — a worker's
+   `self._transport(...)`, the hub's `send_raw(...)` (a dialed connection)
+   and `send_on_conn(...)` (reverse delivery to a relay client, which has
+   no worker) — must be dominated by the before-wire step
+   (`durable_before_wire(self._barrier)`) in the same function: the
+   node's transport only queues, so a served node's `record` does not wait
+   for its fsync; the wire's callers wait, once a frame, until everything
+   submitted is DURABLE. Half (1) alone lets a frame outrun the fsync;
+   half (2) alone has nothing to wait for. A journal whose barrier nobody
+   took waits inside `record` (the simulators, the native engine: no frame
+   boundary), and then half (1) is the whole rule, as it was.
+   Dominance is approximated as "the dominating call appears on an
+   earlier line of the same function body".
 
 E. **Evidence durability** — the Byzantine-evidence counters
    (`consensus_equivocations_total`, `consensus_invalid_shares_total`) may
@@ -108,6 +123,12 @@ ENTROPY_MODULES = ("secrets",)
 # rule P: raw transport callees and the journal calls that must dominate them
 TRANSPORT_CALLEES = ("_send", "_engine_transport")
 JOURNAL_CALLEES = ("_durable_send", "_native_send", "record")
+# rule P, the frame's half: the calls under network/ that put bytes on a
+# socket (or hand them to a worker's transport), and the step that must
+# run first
+FRAME_PACKAGE = "network/"
+FRAME_TRANSPORT_CALLEES = ("_transport", "send_raw", "send_on_conn")
+FRAME_BARRIER_CALLEES = ("durable_before_wire",)
 # functions allowed to transport without journaling, and why. Keyed by
 # function name within lachain_tpu/consensus/.
 TRANSMIT_WHITELIST = {
@@ -584,12 +605,22 @@ class LockOrderChecker:
 
 
 def check_persist_before_transmit(
-    relpath: str, tree: ast.Module, src_lines: List[str]
+    relpath: str,
+    tree: ast.Module,
+    src_lines: List[str],
+    transport_callees: Tuple[str, ...] = TRANSPORT_CALLEES,
+    dominating_callees: Tuple[str, ...] = JOURNAL_CALLEES,
+    whitelist=TRANSMIT_WHITELIST,
+    missing: str = (
+        "a journal record (_durable_send/_native_send/journal.record)"
+    ),
 ) -> List[Violation]:
+    """Every `self.<...>.<transport_callee>(...)` must follow a call of one
+    of `dominating_callees` in the same function (both halves of rule P)."""
     out: List[Violation] = []
 
     def scan_fn(fn) -> None:
-        if fn.name in TRANSMIT_WHITELIST:
+        if fn.name in whitelist:
             return
         journal_lines: List[int] = []
         transports: List[Tuple[int, str]] = []
@@ -612,15 +643,19 @@ def check_persist_before_transmit(
                 name = node.func.attr
             elif isinstance(node.func, ast.Name):
                 name = node.func.id
-            if name in JOURNAL_CALLEES:
+            if name in dominating_callees:
                 journal_lines.append(node.lineno)
-            elif name in TRANSPORT_CALLEES:
-                # only SELF-owned transports count: self._send(...) — a
-                # nested def named _send, or a local callable, is the
-                # transport's own definition, not a use
-                if isinstance(node.func, ast.Attribute) and isinstance(
-                    node.func.value, ast.Name
-                ) and node.func.value.id == "self":
+            elif name in transport_callees:
+                # only SELF-owned transports count: self._send(...),
+                # self.hub.send_on_conn(...) — a nested def named _send, or
+                # a local callable, is the transport's own definition, not
+                # a use
+                root = node.func
+                while isinstance(root, ast.Attribute):
+                    root = root.value
+                if root is not node.func and isinstance(
+                    root, ast.Name
+                ) and root.id == "self":
                     transports.append((node.lineno, name))
         if not transports:
             return
@@ -632,8 +667,7 @@ def check_persist_before_transmit(
                 out.append(Violation(
                     relpath, line, "persist-before-transmit",
                     f"transport call self.{name}(...) in {fn.name}() is "
-                    "not dominated by a journal record "
-                    "(_durable_send/_native_send/journal.record)",
+                    f"not dominated by {missing}",
                 ))
 
     # transport-definition sites (functions ASSIGNED to self._send, e.g. the
@@ -859,6 +893,17 @@ def run(root: str) -> int:
         if rel_in_pkg.startswith("consensus/"):
             violations += check_persist_before_transmit(
                 relpath, tree, src_lines
+            )
+        if rel_in_pkg.startswith(FRAME_PACKAGE):
+            violations += check_persist_before_transmit(
+                relpath, tree, src_lines,
+                transport_callees=FRAME_TRANSPORT_CALLEES,
+                dominating_callees=FRAME_BARRIER_CALLEES,
+                whitelist=(),
+                missing=(
+                    "the journal's barrier hook "
+                    "(durable_before_wire(self._barrier))"
+                ),
             )
         violations += check_evidence_durability(
             relpath, rel_in_pkg, tree, src_lines
