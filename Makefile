@@ -9,7 +9,7 @@ PYTEST_ARGS ?= -q
 
 .PHONY: test test-kernel test-fast test-chaos test-byzantine test-storage \
 	test-observability test-sync test-pipeline test-exec test-trie \
-	test-mesh test-wan test-rs native bench bench-gate lint sanitize \
+	test-mesh test-wan test-rs native lint sanitize \
 	sanitize-tsan
 
 # crypto/accelerator kernels: BLS12-381 group law + subgroup checks,
@@ -56,7 +56,7 @@ test-storage:
 	$(PYTEST) $(PYTEST_ARGS) -m storage
 
 # flight recorder + metrics: span tracer, native trace rings + merge
-# layer, era phase reports, Prometheus surface, compare.py gate
+# layer, era phase reports, Prometheus surface
 test-observability:
 	$(PYTEST) $(PYTEST_ARGS) -m observability
 
@@ -145,30 +145,3 @@ sanitize:
 # unsuppressed report fails the target (TSAN_OPTIONS exitcode + log scan).
 sanitize-tsan:
 	cd tests/native && ./tsan.sh
-
-bench:
-	python benchmarks/bench_consensus_sim.py --n 64 --eras 2
-
-# perf-regression gate: re-run the headline benches and diff them against
-# the checked-in baselines with noise-derived thresholds (exit 1 =
-# regression). The consensus-sim leg runs a small PIPELINED devnet and
-# compares per-era walls too (era_phase_report_s), so a single-era
-# regression cannot hide inside the batch mean; its threshold floor is
-# wider because in-process CPU era walls are noisy.
-bench-gate:
-	python benchmarks/bench_consensus_sim.py --n 16 --eras 3 --txs 200 \
-		--pipeline-window 1 | tail -n 1 > /tmp/lachain_sim_now.json
-	python benchmarks/compare.py benchmarks/BENCH_sim_gate.json \
-		/tmp/lachain_sim_now.json --min-threshold-pct 40
-	python benchmarks/bench_storage_commit.py --engines lsm \
-		| tail -n 1 > /tmp/lachain_commit_now.json
-	python benchmarks/compare.py benchmarks/results_r10.json \
-		/tmp/lachain_commit_now.json --min-threshold-pct 25
-	python benchmarks/bench_consensus_sim.py --n 7 --eras 2 --txs 64 \
-		--mesh-devices 8 | tail -n 1 > /tmp/lachain_mesh_now.json
-	python benchmarks/compare.py benchmarks/MULTICHIP_sim_gate.json \
-		/tmp/lachain_mesh_now.json --min-threshold-pct 60
-	python benchmarks/bench_wan_sim.py --n 4 --eras 3 \
-		| tail -n 1 > /tmp/lachain_wan_now.json
-	python benchmarks/compare.py benchmarks/BENCH_wan_gate.json \
-		/tmp/lachain_wan_now.json --min-threshold-pct 60

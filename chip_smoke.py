@@ -18,8 +18,7 @@ that needs jax). It fails unless jax found a TPU, and then:
             f=21, 1000-tx blocks, native engine, batched RBC, the TPU
             backend installed through provider.set_backend with every era
             batch routed to the device. Three eras of 1000 signed transfers
-            from 256 funded accounts (the mix benchmarks/
-            bench_consensus_sim.py builds from its seed). Every era batch
+            from 256 funded accounts, made from the seed. Every era batch
             on the device, none on the host, no slot rejected, an rs.device
             span per era, no compilation after warm-up.
   reference the same Devnet, seed and transactions on the native host
@@ -344,7 +343,7 @@ def check_ecdsa(host) -> None:
 
 
 def run_devnet(eras: int, on_device: bool) -> list:
-    """Three eras of the bench_consensus_sim.py transfer mix; returns per
+    """Three eras of seeded signed transfers; returns per
     era (block hash, state root, tx count, wall seconds)."""
     from lachain_tpu.core.devnet import Devnet
     from lachain_tpu.core.types import Transaction, sign_transaction

@@ -41,7 +41,7 @@ composition):
       on the legacy (pre-handshake) wire, optionally WAN-shaped into
       emulated regions, and rolls node-by-node onto the LTRX versioned
       wire under paced traffic. Gated on /healthz staying ok and zero
-      fleet missed eras; prints a compare.py-readable JSON result
+      fleet missed eras; prints one JSON result line
       (DEPLOY.md, WAN operations & rolling upgrades).
   lachain-tpu fsck --config netdir/config0.json [--deep] [--no-repair]
       storage invariant scan: detects torn states (orphan block, lost
@@ -745,7 +745,7 @@ def cmd_fleet_upgrade(args) -> int:
     upgraded wire while the survivors keep committing eras under paced
     open-loop traffic. Gates: /healthz stays `ok` on every live node at
     every era checkpoint and the FLEET misses zero eras (a rolling node
-    sitting one out is the expected shape). Prints a compare.py-readable
+    sitting one out is the expected shape). Prints one
     JSON result line (era_latency_p99_s + rtt_ms)."""
     import random
     import time

@@ -14,8 +14,8 @@ Behavioral parity with
 TPU-first redesign of the hot path: instead of verifying each share with 2
 pairings on arrival, shares accumulate per slot and are verified IN BATCH
 (random-linear-combination: 2 pairings + MSM for the whole slot) exactly when
-a slot reaches F+1 candidates — the batched kernel shape that bench.py
-measures (BASELINE.md).
+a slot reaches F+1 candidates — the batched kernel shape of BASELINE.md,
+which the `hb64.*` cells of the benchmark run (PERF.md).
 """
 from __future__ import annotations
 

@@ -25,8 +25,8 @@ with the design the hardware actually wants:
 
 Reference role: the batched replacement for the per-share MCL pairing loop
 (/root/reference/src/Lachain.Crypto/TPKE/PublicKey.cs:55-92 via
-HoneyBadger.cs:205-247). bench.py drives `tpke_era_glv_kernel` as the
-flagship kernel.
+HoneyBadger.cs:205-247). `tpke_era_glv_kernel` is the kernel the mesh
+shards (parallel/mesh.py); one chip runs the Pallas form (ops/pg1.py).
 """
 from __future__ import annotations
 

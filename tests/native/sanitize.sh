@@ -1,7 +1,7 @@
 #!/bin/bash
 # ASan+UBSan gate for the native engines (VERDICT r4 #6 / SURVEY §5).
 # Builds the crypto + consensus TUs with sanitizers and runs:
-#   1. the MSM/pairing differential harness (benchmarks/native/check_msm)
+#   1. the MSM/pairing differential harness (check_msm.cpp, beside this script)
 #   2. a time-boxed decoder fuzzer (structured + random mutations)
 #   3. a time-boxed consensus-engine fuzzer (hostile shards, live engines)
 #   4. a time-boxed LSM corruption fuzzer
@@ -24,7 +24,7 @@ BUILD=./.sanitize-build
 mkdir -p "$BUILD"
 
 echo "== building sanitized harnesses =="
-g++ $CXXFLAGS -o "$BUILD/check_msm" ../../benchmarks/native/check_msm.cpp
+g++ $CXXFLAGS -o "$BUILD/check_msm" check_msm.cpp
 g++ $CXXFLAGS -o "$BUILD/fuzz_decoders" fuzz_decoders.cpp
 g++ $CXXFLAGS -o "$BUILD/fuzz_consensus" fuzz_consensus.cpp
 g++ $CXXFLAGS -o "$BUILD/fuzz_lsm" fuzz_lsm.cpp

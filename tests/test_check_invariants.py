@@ -539,3 +539,47 @@ def test_metric_name_lint_allow_escape(tmp_path, capsys):
     }, capsys)
     assert rc == 0, out
     assert "1 lint-allow line(s)" in err
+
+
+# -- rule B: one benchmark ---------------------------------------------------
+
+
+def test_one_benchmark_clean_head():
+    # no script, gate or baseline beside perfbench/, and README.md quotes
+    # BENCHMARK.json's command: the real tree, without the slow AST passes
+    assert [str(v) for v in ci.check_one_benchmark(REPO_ROOT)] == []
+
+
+@pytest.mark.parametrize("planted", [
+    "bench.py",
+    "benchmarks/bench_consensus_sim.py",
+    "benchmarks/compare.py",
+    "benchmarks/BENCH_sim_gate.json",
+    "MULTICHIP_r01.json",
+])
+def test_second_benchmark_is_flagged(tmp_path, capsys, planted):
+    import json
+
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(
+        {"command": ["python3", "perfbench/run.py"], "paths": ["perfbench"]}
+    ))
+    (tmp_path / "README.md").write_text(
+        "measure with `python3 perfbench/run.py --workload <cell>`\n"
+    )
+    (tmp_path / ".gitignore").write_text("_checkout/\n*.so\n")
+    # the benchmark's own directory and a git-ignored copy of another
+    # commit may hold such names; neither is the tree
+    for own in ("perfbench/compare.py", "_checkout/parent/bench.py"):
+        (tmp_path / own).parent.mkdir(parents=True, exist_ok=True)
+        (tmp_path / own).write_text("")
+    files = {"utils/ok.py": "X = 1\n"}
+    assert run_lint(tmp_path, files, capsys)[0] == 0
+    (tmp_path / planted).parent.mkdir(parents=True, exist_ok=True)
+    (tmp_path / planted).write_text("")
+    rc, out, _ = run_lint(tmp_path, files, capsys)
+    assert rc == 1
+    assert out.count("[one-benchmark]") == 1 and planted in out
+    (tmp_path / planted).unlink()
+    (tmp_path / "README.md").write_text("python benchmarks/bench_x.py\n")
+    rc, out, _ = run_lint(tmp_path, files, capsys)
+    assert rc == 1 and "README.md does not quote" in out

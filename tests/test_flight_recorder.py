@@ -1,6 +1,6 @@
 """Cross-language flight recorder: native trace rings drained into the
-Python tracer, clock alignment across the language boundary, per-era
-phase attribution, and the compare.py perf-regression gate.
+Python tracer, clock alignment across the language boundary and per-era
+phase attribution.
 
 The determinism tests pin the ISSUE-6 contract: two identical seeded
 runs must produce identical native event SEQUENCES (kinds/lanes/args —
@@ -360,115 +360,6 @@ def test_native_ring_capacity_and_drop_counter():
     net.close()
 
 
-# -- compare.py regression gate ----------------------------------------------
-
-
-def _result(value=1000.0, era_s=0.5, spread=5.0, metric="x_per_s"):
-    return {
-        "metric": metric,
-        "value": value,
-        "tpu_era_s": era_s,
-        "trial_spread_pct": spread,
-    }
-
-
-def _gate(tmp_path, base, cur, *extra):
-    import benchmarks.compare as compare
-
-    b = tmp_path / "base.json"
-    c = tmp_path / "cur.json"
-    b.write_text(json.dumps(base))
-    c.write_text(json.dumps(cur))
-    return compare.main([str(b), str(c), *extra])
-
-
-def test_compare_clean_run_passes(tmp_path):
-    assert _gate(tmp_path, _result(), _result(value=990.0, era_s=0.51)) == 0
-
-
-def test_compare_regression_fails(tmp_path):
-    # >=20% era-latency regression vs a 15.6%-spread baseline must gate
-    base = _result(spread=15.6)
-    bad = _result(value=800.0, era_s=0.62, spread=15.6)
-    assert _gate(tmp_path, base, bad) == 1
-
-
-def test_compare_noise_widens_gate(tmp_path):
-    # the same 20% delta passes when the runs themselves are that noisy
-    base = _result(spread=30.0)
-    cur = _result(value=800.0, era_s=0.6, spread=5.0)
-    assert _gate(tmp_path, base, cur) == 0
-
-
-def test_compare_direction_lower_is_better(tmp_path):
-    base = _result(metric="consensus_sim_era_latency_s", value=10.0)
-    worse = _result(metric="consensus_sim_era_latency_s", value=12.0)
-    better = _result(metric="consensus_sim_era_latency_s", value=8.0)
-    assert _gate(tmp_path, base, worse) == 1
-    assert _gate(tmp_path, base, better) == 0
-
-
-def test_compare_wrapper_and_schema_errors(tmp_path):
-    import benchmarks.compare as compare
-
-    # the driver's {cmd, rc, parsed} envelope is accepted
-    wrapped = {"cmd": "python bench.py", "rc": 0, "parsed": _result()}
-    b = tmp_path / "wrapped.json"
-    b.write_text(json.dumps(wrapped))
-    c = tmp_path / "cur.json"
-    c.write_text(json.dumps(_result()))
-    assert compare.main([str(b), str(c)]) == 0
-    # metric mismatch and garbage input are schema errors, not passes
-    d = tmp_path / "other.json"
-    d.write_text(json.dumps(_result(metric="different_metric")))
-    assert compare.main([str(b), str(d)]) == 2
-    e = tmp_path / "garbage.json"
-    e.write_text("not json at all")
-    assert compare.main([str(b), str(e)]) == 2
-
-
-def _mesh_result(util=0.95, era_s=6.0, devices=8, value=6.0):
-    return {
-        "metric": "consensus_sim_era_latency_s",
-        "value": value,
-        "trial_spread_pct": 5.0,
-        "mesh_devices": devices,
-        "mesh_pad_waste_fraction": 0.0,
-        "mesh_device_util_floor": util,
-        "era_phase_report_s": {
-            "1": {"wall_s": era_s, "idle_s": 0.0, "overlap_s": 0.0},
-            "2": {"wall_s": era_s, "idle_s": 0.0, "overlap_s": 0.0},
-        },
-    }
-
-
-def test_compare_mesh_self_gate(tmp_path):
-    """MULTICHIP gate contract: a mesh baseline passes against itself; a
-    device-utilization collapse or per-era wall regression gates (exit 1);
-    a mesh-width mismatch is a schema error (exit 2), never a silent pass."""
-    base = _mesh_result()
-    args = ("--min-threshold-pct", "60")
-    assert _gate(tmp_path, base, _mesh_result(), *args) == 0
-    assert _gate(tmp_path, base, _mesh_result(era_s=20.0, value=20.0), *args) == 1
-    assert _gate(tmp_path, base, _mesh_result(util=0.2), *args) == 1
-    assert _gate(tmp_path, base, _mesh_result(devices=4), *args) == 2
-
-
-def test_compare_checked_in_multichip_baseline():
-    """The checked-in bench-gate mesh baseline must pass against itself —
-    guards the Makefile bench-gate mesh leg from schema drift."""
-    import os
-
-    import benchmarks.compare as compare
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks",
-        "MULTICHIP_sim_gate.json",
-    )
-    assert compare.main([path, path, "--min-threshold-pct", "60"]) == 0
-
-
 def test_rpc_and_cli_era_report_surface():
     """la_getEraReport returns the merged report shape, and the trace CLI
     accepts --era-report (the devnet runbook path)."""
@@ -489,44 +380,3 @@ def test_rpc_and_cli_era_report_surface():
     assert "w:crypto_flush" in table.splitlines()[0]
     cp_table = tracing.critical_path_table(round_trip)
     assert "critical path" in cp_table
-
-
-def test_compare_checked_in_baseline_self_gate():
-    """The Makefile bench-gate wiring: a checked-in baseline vs itself
-    passes."""
-    import os
-
-    import benchmarks.compare as compare
-
-    base = os.path.join(
-        os.path.dirname(__file__), "..", "benchmarks", "BENCH_sim_gate.json"
-    )
-    assert compare.main([base, base]) == 0
-
-
-def test_compare_gates_tx_e2e_percentiles(tmp_path):
-    """The sampled tx e2e percentiles gate like any latency field: a p99
-    regression beyond the noise threshold fails even when the headline
-    throughput held steady."""
-    base = _result()
-    base.update(tx_e2e_p50_s=0.20, tx_e2e_p99_s=0.50)
-    bad = _result()
-    bad.update(tx_e2e_p50_s=0.21, tx_e2e_p99_s=1.00)
-    assert _gate(tmp_path, base, bad) == 1
-    ok = _result()
-    ok.update(tx_e2e_p50_s=0.20, tx_e2e_p99_s=0.51)
-    assert _gate(tmp_path, base, ok) == 0
-
-
-def test_compare_skips_absent_or_null_tx_percentiles(tmp_path):
-    """A run with tracing sampled out (tx_e2e_* null) or an old baseline
-    without the fields must not trip the gate on them."""
-    base = _result()
-    cur = _result()
-    cur.update(tx_e2e_p50_s=0.2, tx_e2e_p99_s=0.5)
-    assert _gate(tmp_path, base, cur) == 0
-    null_base = _result()
-    null_base.update(tx_e2e_p50_s=None, tx_e2e_p99_s=None)
-    worse_but_null = _result()
-    worse_but_null.update(tx_e2e_p50_s=None, tx_e2e_p99_s=None)
-    assert _gate(tmp_path, null_base, worse_but_null) == 0

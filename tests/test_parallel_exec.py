@@ -513,37 +513,6 @@ def test_pool_sharded_semantics_preserved():
 
 
 # ---------------------------------------------------------------------------
-# perf-regression gate: committed throughput is a gated field
-# ---------------------------------------------------------------------------
-
-
-def test_compare_gates_tx_per_s_commit_vs_r06():
-    """compare.py treats tx_per_s_commit as a higher-is-better gated
-    field: the checked-in r09 LSM row passes the gate against the r06
-    baseline row, and a degraded copy is flagged as a regression."""
-    import json
-    import os
-
-    import benchmarks.compare as compare
-
-    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
-    r06 = json.load(open(os.path.join(here, "results_r06.json")))["configs"][
-        "block_commit_10k_lsm (round-6 tentpole)"
-    ]
-    r09 = json.load(open(os.path.join(here, "results_r09.json")))["configs"][
-        "block_commit_10k_lsm (round-9 tentpole)"
-    ]
-    rc, report = compare.compare(r06, r06, 5.0)
-    assert rc == 0 and "tx_per_s_commit" in report  # field engages
-    rc, report = compare.compare(r06, r09, 5.0)
-    assert "tx_per_s_commit" in report
-    assert rc == 0  # round-9 committed throughput holds the r06 line
-    bad = dict(r09, tx_per_s_commit=r09["tx_per_s_commit"] / 2)
-    rc, report = compare.compare(r06, bad, 5.0)
-    assert rc == 1 and "REGRESSION" in report
-
-
-# ---------------------------------------------------------------------------
 # observability: the exec phase in the era report
 # ---------------------------------------------------------------------------
 

@@ -3,8 +3,8 @@
 CPU CI covers the field arithmetic, group law, marshal round-trips and the
 host-side validation/scalar plumbing; the full windowed-scan recover path
 (64 windows -> XLA-CPU compile explosion in emulation) is exercised on the
-chip, where it was validated against the oracle at 10k-signature scale
-(benchmarks/results_r03.json). The pool wires in through
+chip: chip_smoke.py holds a 2048-signature batch to the native library
+there. The pool wires in through
 ecdsa.recover_hash_batch's size-gated TPU routing.
 """
 import random

@@ -24,11 +24,8 @@ Marked `wan` (make test-wan); the fleet drills are additionally `slow`.
 from __future__ import annotations
 
 import asyncio
-import json
 import os
 import random
-import subprocess
-import sys
 import zlib
 
 import pytest
@@ -515,58 +512,3 @@ def test_rolling_upgrade_drill_matches_control():
     # chain content is independent of the upgrade happening at all
     assert len(drill) == N + 2
     assert drill == control
-
-
-# ---------------------------------------------------------------------------
-# bench gate: the checked-in WAN curve baseline
-# ---------------------------------------------------------------------------
-
-GATE = os.path.join(REPO_ROOT, "benchmarks", "BENCH_wan_gate.json")
-
-
-def test_wan_gate_baseline_self_compares_clean():
-    """Satellite 4: the checked-in era-latency-vs-RTT baseline is
-    schema-valid and gates cleanly against itself (rc 0)."""
-    rc = subprocess.call(
-        [
-            sys.executable,
-            os.path.join(REPO_ROOT, "benchmarks", "compare.py"),
-            GATE,
-            GATE,
-            "--min-threshold-pct",
-            "60",
-        ],
-        stdout=subprocess.DEVNULL,
-    )
-    assert rc == 0
-    # the baseline really is a curve: >= 3 points, RTT strictly rising,
-    # and the self-gate's sub-linearity verdict is recorded as holding
-    parsed = json.load(open(GATE))["parsed"]
-    curve = parsed["wan_curve"]
-    assert len(curve) >= 3
-    rtts = [p["rtt_ms"] for p in curve]
-    assert rtts == sorted(rtts) and rtts[0] < rtts[-1]
-    assert parsed["sub_linear"] is True
-
-
-def test_wan_gate_catches_latency_collapse(tmp_path):
-    """A 3x era-latency blowup at the same RTT must fail the gate."""
-    parsed = json.load(open(GATE))["parsed"]
-    bad = dict(parsed)
-    bad["value"] = round(parsed["value"] * 3, 4)
-    bad["era_latency_p99_s"] = bad["value"]
-    bad["trial_spread_pct"] = 0.0
-    cur = tmp_path / "wan_bad.json"
-    cur.write_text(json.dumps(bad))
-    rc = subprocess.call(
-        [
-            sys.executable,
-            os.path.join(REPO_ROOT, "benchmarks", "compare.py"),
-            GATE,
-            str(cur),
-            "--min-threshold-pct",
-            "60",
-        ],
-        stdout=subprocess.DEVNULL,
-    )
-    assert rc == 1

@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """Repo-invariant linter: static checks for the guarantees the tests assume.
 
-Four rule families over `lachain_tpu/` (AST-based, zero dependencies):
+Rule families D, L, P, E, M over `lachain_tpu/` (AST-based, zero
+dependencies) and rule B over the checkout:
 
 D. **Determinism** — the consensus modules (`consensus/`,
    `core/parallel_exec.py`, `storage/trie.py`) must replay bit-identically:
@@ -69,6 +70,16 @@ M. **Metric-name hygiene** — counters and histograms minted through
    dashboards: a scraper cannot tell a monotonic counter from a
    distribution, and rate() over a gauge-shaped name is silently wrong.
 
+B. **One benchmark** — `BENCHMARK.json` declares the benchmark: its
+   `command`, and the directories (`paths`) that hold it. Outside those
+   directories the tree holds no second one: no `bench*.py`, `compare.py`,
+   `*_gate.json` or `MULTICHIP_*.json` (the shapes of the CPU-recorded
+   scripts, gate and baselines that stood beside it until PR 29), and
+   README.md quotes the declared command, so a session that starts from
+   the README measures with the tool the driver runs. Directories the
+   root `.gitignore` lists are not the tree. A root without
+   `BENCHMARK.json` has no benchmark to be second to: the rule is silent.
+
 Escape hatch: a line ending in `# lint-allow: <rule-id> <reason>` silences
 that line for that rule. Allowed lines are counted and printed so silent
 growth of the whitelist shows up in review diffs.
@@ -79,6 +90,8 @@ Run as `python tools/check_invariants.py [repo-root]` (part of `make lint`).
 from __future__ import annotations
 
 import ast
+import fnmatch
+import json
 import os
 import sys
 from collections import defaultdict
@@ -840,6 +853,63 @@ def check_metric_names(
     return out
 
 
+# -- rule B: one benchmark ---------------------------------------------------
+
+BENCHMARK_FILE = "BENCHMARK.json"
+SECOND_BENCHMARK_PATTERNS = (
+    "bench*.py", "compare.py", "*_gate.json", "MULTICHIP_*.json",
+)
+
+
+def check_one_benchmark(root: str) -> List[Violation]:
+    declared = os.path.join(root, BENCHMARK_FILE)
+    if not os.path.isfile(declared):
+        return []
+    with open(declared, "r", encoding="utf-8") as fh:
+        bench = json.load(fh)
+    skip = {".git"} | {p.strip("/") for p in bench["paths"]}
+    ignore = os.path.join(root, ".gitignore")
+    if os.path.isfile(ignore):
+        with open(ignore, "r", encoding="utf-8") as fh:
+            skip |= {
+                line.strip().strip("/") for line in fh
+                if line.strip().endswith("/")
+            }
+    out: List[Violation] = []
+    for dirpath, dirs, files in os.walk(root):
+        rel_dir = os.path.relpath(dirpath, root).replace(os.sep, "/")
+        dirs[:] = sorted(
+            d for d in dirs
+            if d not in skip
+            and os.path.normpath(f"{rel_dir}/{d}").replace(os.sep, "/")
+            not in skip
+        )
+        for fn in sorted(files):
+            if any(
+                fnmatch.fnmatch(fn, pat)
+                for pat in SECOND_BENCHMARK_PATTERNS
+            ):
+                out.append(Violation(
+                    os.path.normpath(f"{rel_dir}/{fn}"), 1, "one-benchmark",
+                    f"a second benchmark beside {BENCHMARK_FILE}'s: "
+                    f"measure through {' '.join(bench['command'])} "
+                    f"(files under {', '.join(bench['paths'])}/)",
+                ))
+    command = " ".join(bench["command"])
+    readme = os.path.join(root, "README.md")
+    text = ""
+    if os.path.isfile(readme):
+        with open(readme, "r", encoding="utf-8") as fh:
+            text = fh.read()
+    if command not in text:
+        out.append(Violation(
+            "README.md", 1, "one-benchmark",
+            f"README.md does not quote {BENCHMARK_FILE}'s command "
+            f"({command})",
+        ))
+    return out
+
+
 # -- driver ------------------------------------------------------------------
 
 
@@ -916,6 +986,7 @@ def run(root: str) -> int:
 
     lock_checker.build_edges()
     violations += lock_checker.find_cycles()
+    violations += check_one_benchmark(root)
 
     for v in sorted(violations, key=lambda v: (v.path, v.line)):
         print(v)
