@@ -21,7 +21,6 @@
 // including adversarial reorderings) is the property the reference's
 // thread-based harness only approximates.
 #include <algorithm>
-#include <array>
 #include <chrono>
 #include <cstdint>
 #include <cstring>
@@ -726,10 +725,12 @@ struct TraceEvent {
 
 enum TraceKind : uint32_t {
   TK_ERA_ADVANCE = 1,  // a = new era
-  TK_CROSS = 2,        // a = XO_* op, dur = time inside the Python callback
+  // 2 is reserved (was TK_CROSS, a callback's interval: the Python span
+  // cross.<op> times it, native_rt.py _cross_begin); never reuse the number
   TK_POST = 3,         // a = PO_* op (coarse ops only; per-slot ops skipped)
   TK_STAGE = 4,        // a = TS_* stage code
-  TK_PHASE = 5,        // a = TP_* phase, dur = accumulated dispatch ns
+  // 5 is reserved (was TK_PHASE, per-era dispatch ns by family:
+  // rt_phase_totals hands out the one total); never reuse the number
   TK_WAIT = 6,         // a = WR_* resource, b = min live era; dur = the gap
                        // the dispatch loop sat starved (queue empty between
                        // two rt_run calls — host-side flush/IO time)
@@ -754,9 +755,10 @@ enum TraceStage : uint32_t {
 };
 
 // Dispatch-phase buckets: per-message deliver() time (minus any time spent
-// inside Python crossings) accumulated by protocol family, flushed as one
-// TK_PHASE record per (era, phase). This is what gives the era report its
-// rbc/ba split on native runs, where no per-protocol Python spans exist.
+// inside Python crossings) accumulated by protocol family into phase_total,
+// which rt_phase_totals hands to the host. This is what gives the era
+// report its rbc/ba split on native runs, where no per-protocol Python
+// spans exist.
 enum TracePhase : uint32_t {
   TP_RBC = 1,     // VAL/ECHO/READY (RS decode + Merkle checks live here)
   TP_BA = 2,      // BVAL/AUX/CONF + BA bookkeeping
@@ -838,12 +840,9 @@ struct Engine {
 
   // -- flight recorder ------------------------------------------------------
   TraceRing trace;
-  // per-era exclusive dispatch time by protocol family (TP_*); std::map so
-  // flush order is deterministic across identically-seeded runs
-  std::map<uint32_t, std::array<uint64_t, 8>> phase_acc;
-  // the same nanoseconds by family over the engine's life: phase_acc is
-  // erased at every era advance and its records pass through the ring, so
-  // this is what rt_phase_totals reads (nothing can evict it)
+  // exclusive dispatch nanoseconds by protocol family (TP_*) over the
+  // engine's life; the host reads it through rt_phase_totals and takes
+  // differences (native_rt.py _fold_dispatch)
   uint64_t phase_total[8] = {0};
   uint64_t cross_ns = 0;  // crossing time inside the current deliver()
   // queue-empty starvation tracking: set when run() exits with nothing to
@@ -872,27 +871,6 @@ struct Engine {
         return TP_OTHER;
     }
     return TP_OTHER;
-  }
-
-  // flush finished-era dispatch accumulators into the ring (an era is
-  // finished once every validator has advanced past it: stale-era messages
-  // are dropped on delivery, so its accumulators can no longer grow)
-  void trace_flush_phases() {
-    if (!trace.enabled || phase_acc.empty()) return;
-    int min_era = vals[0].era;
-    for (auto& v : vals) min_era = v.era < min_era ? v.era : min_era;
-    uint64_t now = trace_now_ns();
-    for (auto it = phase_acc.begin(); it != phase_acc.end();) {
-      if ((int)it->first >= min_era) {
-        ++it;
-        continue;
-      }
-      for (uint32_t ph = 1; ph < 8; ph++)
-        if (it->second[ph])
-          trace.push(now, it->second[ph], TK_PHASE, 0xFFFFFFFFu, ph,
-                     it->first);
-      it = phase_acc.erase(it);
-    }
   }
 
   Engine(int n_, int f_, int mode_, uint32_t ppm, uint64_t seed, int era0)
@@ -1159,17 +1137,13 @@ struct Engine {
       if (!muted.test(e.target)) {
         if (trace.enabled) {
           // exclusive dispatch time: crossings triggered by this message
-          // are timed separately (TK_CROSS) and subtracted here
+          // are summed in cross_ns (Engine::cross) and subtracted here
           uint32_t ph = phase_of(e.m);
-          uint32_t era = (uint32_t)e.m->era;
           uint64_t t0 = trace_now_ns();
           cross_ns = 0;
           deliver(e);
           uint64_t dt = trace_now_ns() - t0;
-          if (dt > cross_ns) {
-            phase_acc[era][ph] += dt - cross_ns;
-            phase_total[ph] += dt - cross_ns;
-          }
+          if (dt > cross_ns) phase_total[ph] += dt - cross_ns;
         } else {
           deliver(e);
         }
@@ -1188,7 +1162,6 @@ struct Engine {
                (uint32_t)new_era, (uint32_t)V.era);
     V.era = new_era;
     V.clear_protocols();
-    trace_flush_phases();
     std::vector<Entry> pending;
     pending.swap(V.postponed);
     V.postponed_per_sender.clear();
@@ -1672,12 +1645,9 @@ void Engine::cross(int vid, int op, int a, int b, const std::string& blob) {
   uint64_t t0 = trace_now_ns();
   cb_cross(vid, vals[vid].era, op, a, b,
            reinterpret_cast<const uint8_t*>(blob.data()), blob.size());
-  uint64_t dt = trace_now_ns() - t0;
   // nested crossings (a callback posting back can trigger another cross)
   // over-accumulate here; run() guards with dt > cross_ns before subtracting
-  cross_ns += dt;
-  trace.push(t0, dt, TK_CROSS, (uint32_t)vid, (uint32_t)op,
-             (uint32_t)vals[vid].era);
+  cross_ns += trace_now_ns() - t0;
 }
 
 NCoin* Engine::get_ncoin(Validator& V, int agreement, int epoch, bool create) {
@@ -2497,23 +2467,14 @@ uint64_t rt_trace_dropped(void* h) {
 // Two-call drain (pattern of rt_debug_state): size query with buf == NULL,
 // then the copying call, which CONSUMES the ring. Output is 32-byte
 // big-endian records (u64 ts_ns, u64 dur_ns, u32 kind, u32 tid, u32 a,
-// u32 b); the tail carries a snapshot of the still-accumulating per-era
-// dispatch-phase totals (TK_PHASE, cumulative — the merge layer keeps the
-// latest record per (era, phase)).
+// u32 b).
 size_t rt_trace_drain(void* h, uint8_t* buf, size_t cap) {
-  Engine* E = static_cast<Engine*>(h);
-  TraceRing& r = E->trace;
+  TraceRing& r = static_cast<Engine*>(h)->trace;
   std::string out;
-  out.reserve((r.count + 8 * E->phase_acc.size()) * 32);
+  out.reserve(r.count * 32);
   size_t start = (r.w + r.cap - r.count) % (r.cap ? r.cap : 1);
   for (size_t i = 0; i < r.count; i++)
     trace_put_event(out, r.buf[(start + i) % r.cap]);
-  uint64_t now = trace_now_ns();
-  for (auto& kv : E->phase_acc)
-    for (uint32_t ph = 1; ph < 8; ph++)
-      if (kv.second[ph])
-        trace_put_event(out, {now, kv.second[ph], TK_PHASE, 0xFFFFFFFFu, ph,
-                              kv.first});
   if (!buf || out.size() > cap) return out.size();
   std::memcpy(buf, out.data(), out.size());
   r.count = 0;  // consumed (w stays: the ring keeps filling from there)
@@ -2521,7 +2482,7 @@ size_t rt_trace_drain(void* h, uint8_t* buf, size_t cap) {
 }
 
 // Exclusive dispatch nanoseconds by protocol family (index = TP_*, 0 unused)
-// since the engine was built: what phase_acc sums, without the ring. Stands
+// since the engine was built; nothing of it passes through the ring. Stands
 // still while recording is off (capacity 0), as the clock reads do.
 void rt_phase_totals(void* h, uint64_t* out8) {
   std::memcpy(out8, static_cast<Engine*>(h)->phase_total, 8 * sizeof(uint64_t));

@@ -251,8 +251,10 @@ def load_rt():
 
 # consensus_rt.cpp trace record contract: 32-byte big-endian records
 _TRACE_RECORD = struct.Struct(">QQIIII")
-TK_ERA_ADVANCE, TK_CROSS, TK_POST, TK_STAGE, TK_PHASE, TK_WAIT = 1, 2, 3, 4, 5, 6
-# TP_* dispatch-phase buckets -> era-report phase keys (tracing._DISPATCH_PHASE)
+# (kinds 2 and 5 are reserved: a callback's interval is the span cross.<op>,
+# dispatch seconds by family are DISPATCH_METRIC; old buffers held both)
+TK_ERA_ADVANCE, TK_POST, TK_STAGE, TK_WAIT = 1, 3, 4, 6
+# TP_* dispatch-phase buckets -> DISPATCH_METRIC's family label
 TP_NAMES = {1: "rbc", 2: "ba", 3: "coin", 4: "tpke", 5: "commit", 6: "other"}
 # WR_* wait resources (TK_WAIT.a) -> era-report wait buckets (tracing.WAIT_RESOURCES)
 WR_NAMES = {1: "net", 2: "crypto_flush", 3: "device", 4: "fsync", 5: "sched"}
@@ -260,16 +262,13 @@ WR_NAMES = {1: "net", 2: "crypto_flush", 3: "device", 4: "fsync", 5: "sched"}
 _PO_TRACE_NAMES = {2: "coin_result", 3: "hb_acs_input", 5: "hb_acs_done",
                    12: "root_header"}
 _TS_NAMES = {1: "acs_result"}
-TRACE_PID_CONSENSUS = 2  # Chrome-export process lane (python host is pid 1)
 
 
 # clock-offset handshake shared with the LSM binding
 clock_offset = tracing.clock_offset
 
 
-def decode_consensus_trace(
-    raw: bytes, offset: float, source: str = "consensus"
-) -> List[dict]:
+def decode_consensus_trace(raw: bytes, offset: float) -> List[dict]:
     """Raw drain buffer -> merged-tracer event dicts (see
     tracing.register_native_source for the schema)."""
     evs: List[dict] = []
@@ -280,36 +279,10 @@ def decode_consensus_trace(
         common = dict(
             start=start,
             end=end,
-            pid=TRACE_PID_CONSENSUS,
+            pid=tracing.NATIVE_CONSENSUS_PID,
             pname="native-consensus",
         )
-        if kind == TK_CROSS:
-            op = XO_NAMES.get(a, str(a))
-            evs.append(
-                dict(
-                    common,
-                    name=f"cross:{op}",
-                    cat="native.cross",
-                    tid=tid,
-                    tname=f"validator-{tid}",
-                    args={"op": op, "era": b, "vid": tid},
-                )
-            )
-        elif kind == TK_PHASE:
-            phase = TP_NAMES.get(a, str(a))
-            evs.append(
-                dict(
-                    common,
-                    name=f"dispatch:{phase}",
-                    cat="native.phase",
-                    tid=0,
-                    tname="dispatch",
-                    # cumulative per-(era,phase) totals: latest wins
-                    replace_key=(source, b, a),
-                    args={"phase": phase, "era": b, "dur_ns": dur},
-                )
-            )
-        elif kind == TK_ERA_ADVANCE:
+        if kind == TK_ERA_ADVANCE:
             evs.append(
                 dict(
                     common,
@@ -1048,8 +1021,8 @@ class NativeSimulatedNetwork:
             self._trace_backlog.extend(self._drain_engine_trace(h))
         except Exception:  # pragma: no cover - tracing must never kill an era
             pass
-        self._fold_dispatch(h)
-        del self._phase_seen[h]  # a later engine may get the same address
+        # a later engine may get the same address
+        self._phase_seen.pop(h, None)
         self._native_handled_closed += int(self._lib.rt_native_handled(h))
         self._trace_dropped_closed += int(self._lib.rt_trace_dropped(h))
         self._lib.rt_free(h)
@@ -1057,27 +1030,28 @@ class NativeSimulatedNetwork:
     # -- flight recorder -------------------------------------------------------
     def trace_configure(self, capacity: int) -> None:
         """Resize the engine-side trace rings; 0 disables recording (and
-        the hot-path clock reads) entirely — the bench overhead check."""
+        the hot-path clock reads) entirely."""
         self._trace_capacity = max(int(capacity), 0)
         for h in self._live_engines():
             self._lib.rt_trace_configure(h, self._trace_capacity)
 
-    def _fold_dispatch(self, h: int) -> None:
-        """What engine `h` spent dispatching since the last call, added to
-        DISPATCH_METRIC by family. Read from the engine's running totals,
-        not from its ring: nothing evicts them, and they stand still while
-        recording is off."""
+    def _fold_dispatch(self, h: int) -> Dict[str, float]:
+        """What engine `h` spent dispatching since the last call, in
+        seconds by family: added to DISPATCH_METRIC and returned. Read from
+        the engine's running totals, not from its ring: nothing evicts
+        them, and they stand still while recording is off."""
         now = (ctypes.c_uint64 * 8)()
         self._lib.rt_phase_totals(h, now)
         seen = self._phase_seen.setdefault(h, [0] * 8)
+        moved: Dict[str, float] = {}
         for ph, family in TP_NAMES.items():
             if now[ph] > seen[ph]:
+                moved[family] = (now[ph] - seen[ph]) / 1e9
                 metrics.inc(
-                    DISPATCH_METRIC,
-                    (now[ph] - seen[ph]) / 1e9,
-                    labels={"family": family},
+                    DISPATCH_METRIC, moved[family], labels={"family": family}
                 )
                 seen[ph] = now[ph]
+        return moved
 
     def trace_dropped(self) -> int:
         total = self._trace_dropped_closed
@@ -1097,7 +1071,7 @@ class NativeSimulatedNetwork:
             got = self._lib.rt_trace_drain(h, buf, len(buf))
             if got <= len(buf):
                 return decode_consensus_trace(
-                    bytes(buf[:got]), self._trace_offset, self._trace_source
+                    bytes(buf[:got]), self._trace_offset
                 )
         return []
 
@@ -1379,17 +1353,22 @@ class NativeSimulatedNetwork:
     # -- execution (simulator.py::run contract) --------------------------------
     def _run_engine(self, h: int, chunk: int, era: int) -> int:
         """One rt_run call under the span `engine.pump`: the engine's own
-        dispatch and every callback it makes meanwhile (cross.* inside)."""
+        dispatch and every callback it makes meanwhile (cross.* inside).
+        Only rt_run moves the engine's dispatch totals, so they are folded
+        here, and the span carries what this call added by family: the
+        era's share of DISPATCH_METRIC, which tracing.era_report reads."""
         sid = tracing.begin("engine.pump", "engine", era=era)
         processed = self._lib.rt_run(h, chunk)
-        tracing.end(sid, processed=processed)
+        tracing.end(
+            sid, processed=processed, dispatch_s=self._fold_dispatch(h)
+        )
         return processed
 
     def post_request(self, validator: int, pid, value) -> None:
         self._sync_ownership()
         # proposal injection does the RBC encode (erasure coding) before
-        # the first dispatch chunk runs — outside the engine's phase
-        # accumulators, so tag it as propose-phase work here
+        # the first dispatch chunk runs — outside the engine's dispatch
+        # totals, so tag it as propose-phase work here
         with tracing.span(
             "consensus.propose", era=getattr(pid, "era", None)
         ):
@@ -1450,7 +1429,6 @@ class NativeSimulatedNetwork:
                     )
             return True
         finally:
-            self._fold_dispatch(self._h)
             metrics.set_gauge(
                 "consensus_native_handled_messages", self.native_handled()
             )
@@ -1567,7 +1545,6 @@ class NativeSimulatedNetwork:
                     f"era {era} {lane}: message cap {max_messages} "
                     "exceeded — livelock?"
                 )
-        self._fold_dispatch(h)
 
     def run_front(
         self, era: int, max_messages: int = 2_000_000, chunk: int = 16384

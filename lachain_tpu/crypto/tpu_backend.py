@@ -93,12 +93,16 @@ class TpuBackend:
         # fixed cost (marshal, upload, one download) that tiny batches
         # cannot amortize.
         #
-        # The default routes ALL era shapes to the host: the last recorded
-        # comparison (round 5, ROADMAP S2) had the host flush a full N=64
-        # era batch (4096 lanes) in ~40 ms against ~0.45 s for the device
-        # call. Nothing has been measured on the current toolchain; the
-        # threshold stays where it was until ROADMAP S2 re-measures it.
-        # chip_smoke.py and the benches pass min_device_lanes explicitly.
+        # The default routes ALL era shapes to the host. Both sides are
+        # on the ledger since PR 22 (era_batch_device_ms against
+        # era_batch_host_ms, one kept batch a traced run, on a v5e): 64
+        # slots x 64 shares (4096 lanes), device 170-176 ms against host
+        # 185-195 ms (hb64.*, ledger, PR 28); 7 slots x 8, host 9.6-10.3
+        # ms against device 11.8 ms (hb7.quiet, ledger, PR 28). So the
+        # crossover lies between 56 and 4096 lanes and the default is on
+        # the wrong side of it at N=64; moving it is a routing policy with
+        # a claim to prove, ROADMAP D1's. chip_smoke.py and the benchmark's
+        # hb64-sim configuration pass min_device_lanes explicitly.
         if min_device_lanes is None:
             min_device_lanes = int(
                 os.environ.get("LTPU_TPU_MIN_LANES", "1000000")

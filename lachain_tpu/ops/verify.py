@@ -13,13 +13,13 @@ i.e. three MSMs on device + 2 pairings on host. The MSMs are this module;
 pairings ride the native C++ backend (lachain_tpu.crypto.native_backend) —
 the host<->TPU split named in SURVEY.md §5 (the "sidecar" boundary).
 
-`tpke_era_step(u, y, rlc_bits, lagrange_bits)` is the jittable "forward step"
-the mesh slice shards (parallel/mesh.py); the served path runs the era
-pipelines below.
+`tpke_era_slots_step(u, y, rlc_bits, lagrange_bits)` is the jittable
+"forward step" the mesh slice shards (parallel/mesh.py); the served path
+runs the era pipelines below.
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Tuple
 
 import numpy as np
 
@@ -28,28 +28,6 @@ import jax.numpy as jnp
 
 from . import curve, msm
 from ..crypto import bls12381 as bls
-
-
-def tpke_era_step(u_pts, y_pts, rlc_bits, lagrange_bits):
-    """One era's worth of share verification + combination aggregates.
-
-    Args:
-      u_pts:        (n, 3, L) decryption shares U_i (Jacobian, Montgomery limbs)
-      y_pts:        (n, 3, L) verification keys Y_i for the same shares
-      rlc_bits:     (n, nbits) random-linear-combination coefficients
-      lagrange_bits:(n, nbits) Lagrange coefficients at 0 (zero rows for
-                    shares not selected into the combination subset)
-
-    Returns (u_agg, y_agg, combined): three G1 points (3, L). The host checks
-    e(u_agg, H) == e(y_agg, W) and uses `combined` as U^x for the XOR pad.
-    """
-    u_agg = curve.g1_msm(u_pts, rlc_bits)
-    y_agg = curve.g1_msm(y_pts, rlc_bits)
-    combined = curve.g1_msm(u_pts, lagrange_bits)
-    return u_agg, y_agg, combined
-
-
-tpke_era_step_jit = jax.jit(tpke_era_step)
 
 
 def tpke_era_slots_step(u_pts, y_pts, rlc_bits, lagrange_bits):
@@ -436,52 +414,3 @@ class TsHostEraPipeline(_HostEraPipelineBase):
     """Coin slots: shares are G2 signatures (see _HostEraPipelineBase)."""
 
     _share_msm = "g2_msm"
-
-
-class TpuTpkeVerifier:
-    """Host-side wrapper: marshals oracle-format shares to the device kernel
-    and finishes with 2 native pairings.
-
-    Drop-in accelerated path for TpkePublicKey.batch_verify_shares +
-    full_decrypt when the batch is large (the N=64 / 10k-tx regime of
-    BASELINE.json config #5).
-    """
-
-    def __init__(self, backend=None):
-        from ..crypto.provider import get_backend
-
-        self._backend = backend or get_backend()
-
-    def verify_and_combine(
-        self,
-        u_points: Sequence[tuple],
-        y_points: Sequence[tuple],
-        h_point: tuple,
-        w_point: tuple,
-        rlc: Sequence[int],
-        lagrange: Sequence[int],
-    ) -> Tuple[bool, tuple]:
-        """Returns (all_valid, combined_point)."""
-        n = len(u_points)
-        assert n and n == len(y_points) == len(rlc) == len(lagrange)
-        size = 1
-        while size < n:
-            size *= 2
-        u_all = list(u_points) + [bls.G1_INF] * (size - n)
-        y_all = list(y_points) + [bls.G1_INF] * (size - n)
-        rlc_all = list(rlc) + [0] * (size - n)
-        lag_all = list(lagrange) + [0] * (size - n)
-        u_dev = jnp.asarray(curve.g1_to_device(u_all))
-        y_dev = jnp.asarray(curve.g1_to_device(y_all))
-        rlc_bits = jnp.asarray(curve.scalars_to_bits(rlc_all, nbits=128))
-        lag_bits = jnp.asarray(curve.scalars_to_bits(lag_all, nbits=256))
-        u_agg_d, y_agg_d, comb_d = tpke_era_step_jit(
-            u_dev, y_dev, rlc_bits, lag_bits
-        )
-        u_agg = curve.g1_from_device(np.asarray(u_agg_d)[None])[0]
-        y_agg = curve.g1_from_device(np.asarray(y_agg_d)[None])[0]
-        combined = curve.g1_from_device(np.asarray(comb_d)[None])[0]
-        ok = self._backend.pairing_check(
-            [(u_agg, h_point), (bls.g1_neg(y_agg), w_point)]
-        )
-        return ok, combined

@@ -637,8 +637,10 @@ class RpcService:
 
     def la_getEraReport(self):
         """Per-era phase attribution (propose/RBC/BA/coin/TPKE-verify/
-        TPKE-decrypt/commit + idle), merged from the Python span ring and
-        the native engines' flight-recorder rings. Each era's idle column
+        TPKE-decrypt/commit + idle), from the span ring (a native
+        engine's callbacks are its `cross.<op>` spans, its dispatch
+        seconds ride on `engine.pump`) and the native engines' wait
+        records. Each era's idle column
         is decomposed into named wait buckets (waits_s: net/crypto_flush/
         device/fsync/sched, from wait spans and native wait records) plus
         an idle_unattributed remainder, and carries a critical_path block

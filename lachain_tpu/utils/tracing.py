@@ -43,14 +43,11 @@ _epoch = time.monotonic()
 # -- native flight-recorder merge state --------------------------------------
 # Sources (the native consensus engine, each native LSM store) register a
 # drain callback returning ready-made event dicts: {name, cat, start, end,
-# args, pid, tid, tname, [replace_key]}. `start`/`end` are time.monotonic()
-# seconds (the source applies its clock-offset handshake before handing
-# events over). Events carrying `replace_key` are cumulative snapshots
-# (per-era dispatch-phase totals): only the latest per key is kept.
+# args, pid, tid, tname}. `start`/`end` are time.monotonic() seconds (the
+# source applies its clock-offset handshake before handing events over).
 # name -> (drain, resize or None)
 _native_sources: "Dict[str, tuple]" = {}
 _native_done: deque = deque(maxlen=DEFAULT_CAPACITY)
-_native_acc: Dict[tuple, dict] = {}
 # ring evictions (silent truncation made visible: satellite of ISSUE 6)
 _py_dropped = 0
 
@@ -357,12 +354,6 @@ def drain_native() -> None:
             continue
         with _lock:
             for ev in evs:
-                key = ev.get("replace_key")
-                if key is not None:
-                    # cumulative snapshot (dispatch-phase totals):
-                    # latest per key wins, no ring growth
-                    _native_acc[key] = ev
-                    continue
                 if (
                     _native_done.maxlen is not None
                     and len(_native_done) == _native_done.maxlen
@@ -372,16 +363,18 @@ def drain_native() -> None:
 
 
 def native_snapshot() -> List[dict]:
-    """Drained native events (plus latest cumulative accumulators) as
-    plain dicts, oldest first. Triggers a drain."""
+    """Drained native events as plain dicts, oldest first. Triggers a
+    drain."""
     drain_native()
     with _lock:
-        out = list(_native_done) + list(_native_acc.values())
+        out = list(_native_done)
     out.sort(key=lambda d: (d.get("start", 0.0), d.get("tid", 0)))
     return [dict(d) for d in out]
 
 
 PY_PID = 1  # Python host process lane group in the Chrome export
+# the native consensus engine's lane group (LSM stores take 3 and up)
+NATIVE_CONSENSUS_PID = 2
 
 
 def _assign_lanes(spans: List[dict]) -> List[tuple]:
@@ -423,8 +416,9 @@ def to_chrome_trace(limit: Optional[int] = None) -> dict:
     group per category (nesting preserved; concurrent instances fan out
     to numbered sibling rows). Drained native-engine events render under
     their own pids with the engine's real thread roles (WAL writer,
-    flusher, compactor, per-validator dispatch) as named rows, so one
-    export shows the whole cross-language timeline."""
+    flusher, compactor, per-validator dispatch) as named rows, and the
+    consensus engine's callbacks (`cross.<op>` spans) on their validator's
+    row there, so one export shows the whole cross-language timeline."""
     events: List[dict] = []
     # (pid, tid) -> row label; pid -> process label
     thread_names: Dict[tuple, str] = {}
@@ -444,13 +438,21 @@ def to_chrome_trace(limit: Optional[int] = None) -> dict:
         args = dict(d["args"])
         if d["open"]:
             args["open"] = True
+        if d["name"].startswith("cross.") and "vid" in args:
+            # an engine->Python callback renders on its validator's row of
+            # the native engine's process, beside that engine's ring records
+            pid, tid = NATIVE_CONSENSUS_PID, int(args["vid"])
+            proc_names.setdefault(pid, "native-consensus")
+            thread_names[(pid, tid)] = f"validator-{tid}"
+        else:
+            pid, tid = PY_PID, py_tid(cat, lane)
         events.append(
             {
                 "name": d["name"],
                 "cat": d["cat"],
                 "ph": "X",
-                "pid": PY_PID,
-                "tid": py_tid(cat, lane),
+                "pid": pid,
+                "tid": tid,
                 "ts": round((d["start"] - _epoch) * 1e6, 1),
                 "dur": round(max((d["end"] - d["start"]) * 1e6, 0.0), 1),
                 "args": args,
@@ -458,7 +460,7 @@ def to_chrome_trace(limit: Optional[int] = None) -> dict:
         )
 
     for ev in native_snapshot():
-        pid = int(ev.get("pid", 2))
+        pid = int(ev.get("pid", NATIVE_CONSENSUS_PID))
         tid = int(ev.get("tid", 0))
         if ev.get("pname"):
             proc_names[pid] = ev["pname"]
@@ -574,7 +576,9 @@ _SPAN_PHASE = {
     "merkle.freeze": "merkle",
 }
 
-# Native crossing op name -> phase (see consensus/native_hosts.py XO_NAMES).
+# Engine->Python callback -> phase, keyed by the op of its span `cross.<op>`
+# (consensus/native_rt.py _cross_begin; ops: native_hosts.py XO_NAMES). The
+# span is the one timing of a callback.
 _CROSS_PHASE = {
     "coin_sign": "coin",
     "coin_combine": "coin",
@@ -590,8 +594,10 @@ _CROSS_PHASE = {
     "root_produce": "commit",
 }
 
-# Native dispatch-phase accumulator name -> phase (TK_PHASE records;
-# exclusive message-dispatch time measured inside the C++ engine).
+# Family of consensus_engine_dispatch_seconds_total -> phase: the engine's
+# exclusive message-dispatch seconds, which reach the report as the
+# `dispatch_s` argument of the `engine.pump` span that folded them into the
+# counter (native_rt.py _run_engine).
 _DISPATCH_PHASE = {
     "rbc": "rbc",
     "ba": "ba",
@@ -679,8 +685,8 @@ def _critical_path(intervals: List[tuple], lo: float, hi: float) -> dict:
     {"phase", "wait"}. Walk BACKWARDS from the era end (the commit): at
     each cursor pick the covering interval that reaches furthest back and
     emit one segment per hop; stretches nothing covers become
-    "gap"/"unattributed" segments (native dispatch accumulators have no
-    intervals, so engine dispatch time lands here, bounded by crossings
+    "gap"/"unattributed" segments (the engine's dispatch seconds have no
+    intervals, so engine dispatch time lands here, bounded by callbacks
     and wait records on either side). By construction the segments tile
     [lo, hi], so their lengths sum to the era wall."""
     eps = 1e-9
@@ -752,10 +758,12 @@ def era_report(
 ) -> dict:
     """Per-era phase attribution: where does era wall time go?
 
-    Combines three sources: Python protocol/crypto spans (interval sweep
-    with nesting priority), native crossing events (batched crypto ops,
-    from the drained consensus ring), and the engine's per-era exclusive
-    dispatch accumulators. Idle = wall − attributed, clamped at 0, then
+    Combines three sources, all of them spans: Python protocol/crypto
+    spans (interval sweep with nesting priority), the native engine's
+    callbacks (`cross.<op>`, swept with them), and the engine's exclusive
+    dispatch seconds by family (the `dispatch_s` of each `engine.pump`:
+    what that call added to consensus_engine_dispatch_seconds_total).
+    Idle = wall − attributed, clamped at 0, then
     DECOMPOSED into named wait buckets (waits_s, from wait.* spans and
     native wait records) plus an idle_unattributed remainder — the
     invariant is buckets + remainder == the old idle value. Each era also
@@ -778,12 +786,25 @@ def era_report(
             w[1] = max(w[1], d["end"])
 
     per_era_iv: Dict[int, List[tuple]] = {e: [] for e in windows}
+    dispatch: Dict[int, Dict[str, float]] = {}
     for d in spans:
-        phase = _SPAN_PHASE.get(d["name"])
         era = d["args"].get("era")
-        if phase is None or era is None or int(era) not in per_era_iv:
+        if era is None or int(era) not in per_era_iv:
             continue
-        per_era_iv[int(era)].append((phase, d["start"], d["end"]))
+        name = d["name"]
+        if name == "engine.pump":
+            acc = dispatch.setdefault(int(era), {})
+            for family, secs in (d["args"].get("dispatch_s") or {}).items():
+                phase = _DISPATCH_PHASE.get(family)
+                if phase is not None:
+                    acc[phase] = acc.get(phase, 0.0) + float(secs)
+            continue
+        if name.startswith("cross."):
+            phase = _CROSS_PHASE.get(name[len("cross."):])
+        else:
+            phase = _SPAN_PHASE.get(name)
+        if phase is not None:
+            per_era_iv[int(era)].append((phase, d["start"], d["end"]))
 
     # mesh device-busy windows (parallel/mesh.MeshEraPipeline spans the
     # kernel dispatch -> result-ready interval as "mesh.device"): these are
@@ -804,27 +825,10 @@ def era_report(
             res = d["args"].get("resource") or "net"
             wait_iv_all.append((res, d["start"], d["end"]))
 
-    dispatch: Dict[int, Dict[str, float]] = {}
     for ev in native:
         if ev.get("cat") == "native.wait":
             res = (ev.get("args") or {}).get("resource") or "sched"
             wait_iv_all.append((res, ev["start"], ev["end"]))
-            continue
-        era = (ev.get("args") or {}).get("era")
-        if era is None or int(era) not in windows:
-            continue
-        era = int(era)
-        if ev.get("cat") == "native.cross":
-            phase = _CROSS_PHASE.get((ev.get("args") or {}).get("op"))
-            if phase is not None:
-                per_era_iv[era].append((phase, ev["start"], ev["end"]))
-        elif ev.get("cat") == "native.phase":
-            phase = _DISPATCH_PHASE.get((ev.get("args") or {}).get("phase"))
-            if phase is not None:
-                acc = dispatch.setdefault(era, {})
-                acc[phase] = acc.get(phase, 0.0) + float(
-                    (ev.get("args") or {}).get("dur_ns", 0)
-                ) / 1e9
 
     eras = []
     for era in sorted(windows):
@@ -850,15 +854,15 @@ def era_report(
         if cur_e is not None:
             overlap += cur_e - cur_s
         phases = _sweep(per_era_iv[era], lo, hi)
-        # engine dispatch time is measured OUTSIDE the crossing callbacks
-        # (cross time subtracted natively), so it is exclusive of every
-        # interval above and adds linearly
+        # engine dispatch time is measured OUTSIDE the callbacks (their
+        # time subtracted natively), so it is exclusive of every interval
+        # above and adds linearly
         for phase, secs in dispatch.get(era, {}).items():
             phases[phase] += secs
         attributed = sum(phases.values())
         idle = max(wall - attributed, 0.0)
         # idle decomposition: exclusive wait coverage on the un-attributed
-        # stretches of the window. The dispatch accumulators above occupy
+        # stretches of the window. The dispatch seconds above occupy
         # unswept wall time, so raw wait coverage can exceed the idle
         # residual; scale the buckets down proportionally so
         # buckets + remainder always equal the old idle value exactly.
@@ -1032,7 +1036,6 @@ def set_capacity(n: int) -> None:
         _native_done = deque(_native_done, maxlen=n)
         if n == 0:
             _open.clear()
-            _native_acc.clear()
         sources = list(_native_sources.values())
     for _drain, resize in sources:
         if resize is not None:
@@ -1045,6 +1048,5 @@ def reset_for_tests() -> None:
         _done = deque(maxlen=DEFAULT_CAPACITY)
         _open.clear()
         _native_done = deque(maxlen=DEFAULT_CAPACITY)
-        _native_acc.clear()
         _native_sources.clear()
         _py_dropped = 0

@@ -4,8 +4,10 @@
 running totals, and the recorder's off state. N=4 native devnet on the CPU:
 counts and nesting only — a CPU run says nothing about time.
 """
+import ctypes
 import gc
 import os
+import struct
 import subprocess
 import sys
 import threading
@@ -88,6 +90,49 @@ def test_one_cross_span_for_every_counted_callback(rbc_batch):
     assert ("rbc_need" in counted) == rbc_batch
 
 
+# the callbacks an N=4 era with batched RBC makes through Engine::cross
+ERA_OPS = (
+    "root_input", "root_sign", "root_verify", "root_produce",
+    "hb_acs", "coin_sign", "coin_combine", "rbc_need",
+)
+
+
+def _ring_kinds(net) -> dict:
+    """Record kinds in the engines' rings, read raw (the drain consumes)."""
+    kinds = {}
+    lib = net.net._lib
+    for h in net.net._live_engines():
+        need = lib.rt_trace_drain(h, None, 0)
+        buf = (ctypes.c_uint8 * (need + 4096))()
+        got = lib.rt_trace_drain(h, buf, len(buf))
+        for i in range(0, got, 32):
+            kind = struct.unpack_from(">I", buf, i + 16)[0]
+            kinds[kind] = kinds.get(kind, 0) + 1
+    return kinds
+
+
+@pytest.fixture(scope="module")
+def two_eras():
+    tracing.reset_for_tests()
+    metrics.reset_all_for_tests()
+    net = _devnet(rbc_batch=True)
+    try:
+        net.run_eras(1, 2)
+        return _cross_counts(tracing.snapshot()), _crossings(), _ring_kinds(net)
+    finally:
+        net.close()
+
+
+@pytest.mark.parametrize("op", ERA_OPS)
+def test_each_crossing_is_timed_once(two_eras, op):
+    """A callback has one timing, the span cross.<op>: as many spans as
+    the counter counted, and no record of it (kind 2) or of per-era
+    dispatch seconds (kind 5) in the engine's ring."""
+    spans, counted, ring = two_eras
+    assert spans[op] == counted[op] > 0
+    assert ring and not {2, 5} & set(ring), ring
+
+
 def test_engine_spans_nest_in_their_era_and_in_a_named_parent():
     net = _devnet(rbc_batch=True)
     try:
@@ -126,7 +171,7 @@ def test_every_era_holds_one_advance_and_at_least_one_pump():
         assert sum(s["name"] == "engine.pump" for s in held) >= 1
 
 
-@pytest.mark.parametrize("engine_ring", [None, 64])
+@pytest.mark.parametrize("engine_ring", [None, 8])
 def test_dispatch_counter_reads_the_engines_totals_not_its_ring(engine_ring):
     """The counter grows each era, stays under the time the engine was
     pumped, and a ring too small for an era's records changes nothing."""
@@ -231,7 +276,7 @@ def test_set_capacity_resizes_the_engines_that_are_registered():
         net.run_era(3)
         assert sum(_dispatch().values()) > grown
         # what the rings held before a resize was drained, not lost
-        assert any(e["name"] == "cross:root_produce" for e in tracing.native_snapshot())
+        assert any(e["name"] == "post:root_header" for e in tracing.native_snapshot())
     finally:
         net.close()
         tracing.set_capacity(tracing.DEFAULT_CAPACITY)

@@ -64,16 +64,12 @@ def _run_native_hb(era_span: bool = True):
 
 
 def _signature(evs):
-    """Determinism signature: everything except wall-clock values. The
-    cumulative dispatch accumulators keep their phase/era identity but
-    drop their ns totals (those are timings)."""
-    out = []
-    for e in evs:
-        args = {
-            k: v for k, v in e["args"].items() if k not in ("dur_ns",)
-        }
-        out.append((e["name"], e["cat"], e["tid"], tuple(sorted(args.items()))))
-    return out
+    """Determinism signature: everything except wall-clock values (the
+    ring's records carry none in their args)."""
+    return [
+        (e["name"], e["cat"], e["tid"], tuple(sorted(e["args"].items())))
+        for e in evs
+    ]
 
 
 def test_native_drain_deterministic_across_identical_runs():
@@ -140,7 +136,7 @@ def test_era_report_phases_sum_to_wall_time():
     assert abs(total - ent["wall_s"]) <= 0.10 * ent["wall_s"]
     # TPKE share verification crosses into Python on every native run
     assert ent["phases_s"]["tpke_verify"] > 0
-    # and the engine's dispatch accumulators give the rbc/ba split
+    # and the engine's dispatch seconds give the rbc/ba split
     assert ent["phases_s"]["rbc"] > 0
 
 
@@ -380,3 +376,109 @@ def test_rpc_and_cli_era_report_surface():
     assert "w:crypto_flush" in table.splitlines()[0]
     cp_table = tracing.critical_path_table(round_trip)
     assert "critical path" in cp_table
+
+
+# -- one timing of each thing: callbacks from cross.<op>, dispatch from the
+# -- counter's own differences -------------------------------------------------
+
+
+def _phase_ops(phase):
+    return sorted(op for op, p in tracing._CROSS_PHASE.items() if p == phase)
+
+
+@pytest.mark.parametrize("phase", sorted(set(tracing._CROSS_PHASE.values())))
+def test_era_report_reads_cross_spans(phase):
+    """A callback's seconds in the report are its cross.<op> span's: each
+    op of the phase one second apart in a synthetic era, with no native
+    event at all."""
+    ops = _phase_ops(phase)
+    spans = [_syn_span("era", 0.0, 100.0, era=3)] + [
+        _syn_span(f"cross.{op}", 2.0 * i, 2.0 * i + 1.0, cat="engine",
+                  era=3, vid=i % 4)
+        for i, op in enumerate(ops)
+    ]
+    # other eras' callbacks and ops with no phase stay out
+    spans.append(_syn_span(f"cross.{ops[0]}", 50.0, 60.0, cat="engine", era=4, vid=0))
+    spans.append(_syn_span("cross.opaque_message", 70.0, 80.0, cat="engine", era=3, vid=0))
+    ent = tracing.era_report(spans=spans, native=[])["eras"][0]
+    assert ent["era"] == 3
+    want = {p: 0.0 for p in tracing.PHASES}
+    want[phase] = float(len(ops))
+    assert ent["phases_s"] == pytest.approx(want)
+    assert ent["idle_s"] == pytest.approx(100.0 - len(ops))
+    assert [s["name"] for s in ent["critical_path"]["segments"]
+            if s["kind"] == "phase"] == [phase] * len(ops)
+
+
+@pytest.fixture(scope="module")
+def two_native_eras():
+    """Spans and the dispatch counter of a two-era N=4 native devnet."""
+    from lachain_tpu.consensus.native_rt import DISPATCH_METRIC
+    from lachain_tpu.core.devnet import Devnet
+
+    tracing.reset_for_tests()
+    metrics.reset_all_for_tests()
+    net = Devnet(4, 1, seed=9, txs_per_block=20, engine="native", rbc_batch=True)
+    try:
+        net.run_eras(1, 2)
+    finally:
+        net.close()
+    counter = {
+        dict(labels)["family"]: v
+        for (_n, labels), v in metrics.counters_with_prefix(DISPATCH_METRIC).items()
+    }
+    return tracing.snapshot(), counter
+
+
+@pytest.mark.parametrize("family", sorted(tracing._DISPATCH_PHASE))
+def test_era_report_dispatch_is_the_counter(two_native_eras, family):
+    """An era's dispatch seconds are differences of the one total: over
+    both eras the pumps' `dispatch_s` sum to what the counter moved by,
+    and the report puts exactly those seconds on the family's phase."""
+    spans, counter = two_native_eras
+    pumps = [s for s in spans if s["name"] == "engine.pump"]
+    assert {s["args"]["era"] for s in pumps} == {1, 2}
+    folded = sum(s["args"]["dispatch_s"].get(family, 0.0) for s in pumps)
+    assert folded == pytest.approx(counter[family], rel=1e-9) and folded > 0
+    stripped = [
+        dict(s, args={k: v for k, v in s["args"].items() if k != "dispatch_s"})
+        for s in spans
+    ]
+    phase = tracing._DISPATCH_PHASE[family]
+    with_d = tracing.era_report(spans=spans, native=[])["eras"]
+    without = tracing.era_report(spans=stripped, native=[])["eras"]
+    assert [e["era"] for e in with_d] == [1, 2]
+    moved = sum(
+        a["phases_s"][phase] - b["phases_s"][phase]
+        for a, b in zip(with_d, without)
+    )
+    # each era's figure is rounded to a microsecond, twice
+    assert moved == pytest.approx(folded, abs=4e-6)
+
+
+def test_recorder_off_moves_nothing():
+    """At capacity 0 a native era leaves no span, no ring record and no
+    dispatch second: the engine reads no clock."""
+    from lachain_tpu.consensus.native_rt import DISPATCH_METRIC
+
+    tracing.set_capacity(0)
+    try:
+        from lachain_tpu.consensus import messages as M
+        from lachain_tpu.consensus.keys import trusted_key_gen
+        from lachain_tpu.consensus.native_rt import NativeSimulatedNetwork
+
+        pub, privs = trusted_key_gen(4, 1, rng=_Rng(7))
+        net = NativeSimulatedNetwork(pub, privs, era=0, seed=11)
+        pid = M.HoneyBadgerId(era=0)
+        for i in range(4):
+            net.post_request(i, pid, b"payload|%d|" % i + bytes(16))
+        assert net.run(
+            lambda: all(r.result_of(pid) is not None for r in net.routers)
+        )
+        assert net._lib.rt_trace_drain(net._h, None, 0) == 0
+        assert net.trace_dropped() == 0
+        net.close()
+        assert tracing.snapshot() == [] and tracing.native_snapshot() == []
+        assert not metrics.counters_with_prefix(DISPATCH_METRIC)
+    finally:
+        tracing.set_capacity(tracing.DEFAULT_CAPACITY)
