@@ -791,9 +791,10 @@ def era_report(
         era = d["args"].get("era")
         if era is None or int(era) not in per_era_iv:
             continue
+        era = int(era)
         name = d["name"]
         if name == "engine.pump":
-            acc = dispatch.setdefault(int(era), {})
+            acc = dispatch.setdefault(era, {})
             for family, secs in (d["args"].get("dispatch_s") or {}).items():
                 phase = _DISPATCH_PHASE.get(family)
                 if phase is not None:
@@ -804,7 +805,7 @@ def era_report(
         else:
             phase = _SPAN_PHASE.get(name)
         if phase is not None:
-            per_era_iv[int(era)].append((phase, d["start"], d["end"]))
+            per_era_iv[era].append((phase, d["start"], d["end"]))
 
     # mesh device-busy windows (parallel/mesh.MeshEraPipeline spans the
     # kernel dispatch -> result-ready interval as "mesh.device"): these are

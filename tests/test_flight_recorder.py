@@ -463,18 +463,8 @@ def test_recorder_off_moves_nothing():
 
     tracing.set_capacity(0)
     try:
-        from lachain_tpu.consensus import messages as M
-        from lachain_tpu.consensus.keys import trusted_key_gen
-        from lachain_tpu.consensus.native_rt import NativeSimulatedNetwork
-
-        pub, privs = trusted_key_gen(4, 1, rng=_Rng(7))
-        net = NativeSimulatedNetwork(pub, privs, era=0, seed=11)
-        pid = M.HoneyBadgerId(era=0)
-        for i in range(4):
-            net.post_request(i, pid, b"payload|%d|" % i + bytes(16))
-        assert net.run(
-            lambda: all(r.result_of(pid) is not None for r in net.routers)
-        )
+        net = _quiesce_net()  # a whole era, pumped until the queue is empty
+        assert net.delivered_count > 0
         assert net._lib.rt_trace_drain(net._h, None, 0) == 0
         assert net.trace_dropped() == 0
         net.close()

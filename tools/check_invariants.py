@@ -867,6 +867,8 @@ def check_one_benchmark(root: str) -> List[Violation]:
         return []
     with open(declared, "r", encoding="utf-8") as fh:
         bench = json.load(fh)
+    command = " ".join(bench["command"])
+    # directories that are not the tree, by name or by path from the root
     skip = {".git"} | {p.strip("/") for p in bench["paths"]}
     ignore = os.path.join(root, ".gitignore")
     if os.path.isfile(ignore):
@@ -875,14 +877,17 @@ def check_one_benchmark(root: str) -> List[Violation]:
                 line.strip().strip("/") for line in fh
                 if line.strip().endswith("/")
             }
+
+    def rel(dirpath: str, name: str) -> str:
+        return os.path.relpath(os.path.join(dirpath, name), root).replace(
+            os.sep, "/"
+        )
+
     out: List[Violation] = []
     for dirpath, dirs, files in os.walk(root):
-        rel_dir = os.path.relpath(dirpath, root).replace(os.sep, "/")
         dirs[:] = sorted(
             d for d in dirs
-            if d not in skip
-            and os.path.normpath(f"{rel_dir}/{d}").replace(os.sep, "/")
-            not in skip
+            if d not in skip and rel(dirpath, d) not in skip
         )
         for fn in sorted(files):
             if any(
@@ -890,12 +895,11 @@ def check_one_benchmark(root: str) -> List[Violation]:
                 for pat in SECOND_BENCHMARK_PATTERNS
             ):
                 out.append(Violation(
-                    os.path.normpath(f"{rel_dir}/{fn}"), 1, "one-benchmark",
+                    rel(dirpath, fn), 1, "one-benchmark",
                     f"a second benchmark beside {BENCHMARK_FILE}'s: "
-                    f"measure through {' '.join(bench['command'])} "
+                    f"measure through {command} "
                     f"(files under {', '.join(bench['paths'])}/)",
                 ))
-    command = " ".join(bench["command"])
     readme = os.path.join(root, "README.md")
     text = ""
     if os.path.isfile(readme):
