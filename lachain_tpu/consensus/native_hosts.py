@@ -583,13 +583,17 @@ class RbcHost:
     def _post_vals(self, slot: int, shards) -> None:
         from ..crypto import hashes
 
-        leaves = hashes.keccak256_batch(shards)
-        root = hashes.merkle_root(leaves)
+        # one tree a proposal, all N branches read from it: `hashes` is the
+        # keccak digests it took, the leaves' and the inner nodes'
+        with tracing.span(
+            "rbc.merkle", "engine", era=self.era, leaves=len(shards)
+        ) as sid:
+            tree = hashes.merkle_tree(hashes.keccak256_batch(shards))
+            tracing.annotate(sid, hashes=len(shards) + tree.hashes)
         blob = bytearray(self.era.to_bytes(4, "big"))
-        blob += root
+        blob += tree.root
         blob += self.n.to_bytes(4, "big")
-        for i in range(self.n):
-            branch = hashes.merkle_proof(leaves, i)
+        for i, branch in enumerate(tree.branches):
             blob += len(branch).to_bytes(4, "big")
             for h in branch:
                 blob += len(h).to_bytes(4, "big")

@@ -68,16 +68,14 @@ class ReliableBroadcast(Protocol):
         self._send_vals(rs.encode(value, self._k, self.n))
 
     def _send_vals(self, shards: List[bytes]) -> None:
-        leaves = [hashes.keccak256(s) for s in shards]
-        root = hashes.merkle_root(leaves)
+        tree = hashes.merkle_tree([hashes.keccak256(s) for s in shards])
         for i in range(self.n):
-            branch = tuple(hashes.merkle_proof(leaves, i))
             self.broadcaster.send_to(
                 i,
                 M.ValMessage(
                     rbc=self.id,
-                    root=root,
-                    branch=branch,
+                    root=tree.root,
+                    branch=tuple(tree.branches[i]),
                     shard=shards[i],
                     shard_index=i,
                 ),

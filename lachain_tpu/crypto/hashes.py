@@ -9,7 +9,7 @@ since hashlib only ships NIST SHA-3.
 from __future__ import annotations
 
 import hashlib
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 _KECCAK_ROUNDS = 24
 _RC = [
@@ -233,6 +233,41 @@ def merkle_proof(leaves: Sequence[bytes], index: int) -> List[bytes]:
         idx //= 2
         level = nxt
     return proof
+
+
+class MerkleTree(NamedTuple):
+    root: Optional[bytes]  # what merkle_root(leaves) gives
+    branches: List[List[bytes]]  # branches[i] == merkle_proof(leaves, i)
+    hashes: int  # keccak256 calls made: one an inner node, N - 1 in all
+
+
+def merkle_tree(leaves: Sequence[bytes]) -> MerkleTree:
+    """Root and every leaf's sibling path from ONE bottom-up pass: N - 1
+    hashes where merkle_proof in a loop over the leaves makes N * (N - 1).
+    RBC's VAL fan-out needs all N branches of a proposal's tree;
+    merkle_proof stays the tool for one branch and the reference this is
+    tested against (same shape, b"" where an odd node was promoted)."""
+    n = len(leaves)
+    if not n:
+        return MerkleTree(None, [], 0)
+    branches: List[List[bytes]] = [[] for _ in range(n)]
+    idxs = list(range(n))  # each leaf's ancestor's position in `level`
+    level = list(leaves)
+    hashed = 0
+    while len(level) > 1:
+        for branch, idx in zip(branches, idxs):
+            sib = idx ^ 1
+            branch.append(level[sib] if sib < len(level) else b"")
+        nxt = [
+            keccak256(level[i] + level[i + 1])
+            for i in range(0, len(level) - 1, 2)
+        ]
+        hashed += len(nxt)
+        if len(level) % 2:
+            nxt.append(level[-1])
+        idxs = [idx // 2 for idx in idxs]
+        level = nxt
+    return MerkleTree(level[0], branches, hashed)
 
 
 def merkle_verify(

@@ -128,7 +128,9 @@ class NetworkManager:
         if self._reregister_task is not None:
             self._reregister_task.cancel()
             self._reregister_task = None
-        for w in self._workers.values():
+        # a snapshot: the hub still delivers while a worker stops, and a
+        # peer's signed peers_request may rebind (pop and insert) meanwhile
+        for w in list(self._workers.values()):
             await w.stop()
         await self.hub.stop()
 

@@ -114,3 +114,38 @@ def test_keccak_batch_python_fallback():
 
 # slice marker: crypto/accelerator kernels ("make test-kernel")
 pytestmark = pytest.mark.kernel
+
+
+def _leaves(n):
+    return [hashes.keccak256(bytes([i % 256, i // 256, n % 256])) for i in range(n)]
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 7, 8, 16, 22, 64, 65, 255])
+def test_merkle_tree_is_merkle_proof_for_every_leaf(n):
+    """One bottom-up pass gives what merkle_root and N merkle_proof calls
+    give: same root, same branches (b"" at an odd promotion), all valid."""
+    leaves = _leaves(n)
+    tree = hashes.merkle_tree(leaves)
+    assert tree.root == hashes.merkle_root(leaves)
+    assert len(tree.branches) == n
+    for i in range(n):
+        assert tree.branches[i] == hashes.merkle_proof(leaves, i), (n, i)
+        assert hashes.merkle_verify(leaves[i], i, tree.branches[i], tree.root)
+
+
+def test_merkle_tree_hashes_once_a_node(monkeypatch):
+    """A 64-leaf tree with its 64 branches costs O(N) keccaks, not N^2."""
+    leaves = _leaves(64)
+    want = hashes.merkle_root(leaves)
+    calls = []
+    real = hashes.keccak256
+    monkeypatch.setattr(hashes, "keccak256", lambda d: calls.append(1) or real(d))
+    tree = hashes.merkle_tree(leaves)
+    assert tree.root == want
+    assert len(calls) == tree.hashes == 63  # the contract: at most 2N
+
+
+def test_merkle_tree_of_nothing():
+    tree = hashes.merkle_tree([])
+    assert tree.root is None and hashes.merkle_root([]) is None
+    assert tree.branches == [] and tree.hashes == 0
