@@ -35,6 +35,15 @@ bit-identical to the serial pass. Each tx re-executes at most once, so a
 forced-100%-conflict workload degrades to exactly one serial pass plus
 the (wasted) lane pass — graceful, never a livelock.
 
+What a block cost is on the record: spans `exec.plan`, `exec.lanes` (args
+`lanes`, `largest_lane`) and `exec.merge` (arg `stragglers`) inside the
+caller's `exec.block`, and counter `exec_lane_txs_largest_total` beside
+`exec_txs_validated_total` / `exec_txs_straggler_total`. A block whose
+footprints form ONE group — every call of one contract carries its address
+in `tx.to` — plans one lane: it runs on the caller's thread, recorded and
+then validated by the merge, the pipeline's cost with nothing to overlap
+(largest lane = the block). The planner sees addresses, not storage keys.
+
 On a single hardware thread the lanes buy no wall-clock (pure-Python
 execution under the GIL); the win there comes from the delta-checkpoint
 snapshot and the commit-path work this PR removes. On multi-core hosts
@@ -49,7 +58,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..storage.state import Snapshot, StateManager, StateRoots
-from ..utils import metrics
+from ..utils import metrics, tracing
 from .execution import TransactionExecuter
 from .types import SignedTransaction, TransactionReceipt, warm_sender_caches
 
@@ -250,8 +259,12 @@ def execute_block_parallel(
     canonical order, and the stats. The caller freezes — exactly where
     the serial path freezes — so the two paths share the commit seam."""
     chain_id = executer.chain_id
-    warm_sender_caches(ordered, chain_id)
-    lanes = [l for l in plan_lanes(ordered, chain_id, n_lanes, partition) if l]
+    with tracing.span("exec.plan", cat="exec", era=block_index):
+        warm_sender_caches(ordered, chain_id)
+        lanes = [
+            l for l in plan_lanes(ordered, chain_id, n_lanes, partition) if l
+        ]
+    largest = max((len(l) for l in lanes), default=0)
 
     def run_lane(lane: List[Tuple[int, SignedTransaction]]):
         snap = RecordingSnapshot(state.trie.fork(), base_roots)
@@ -263,13 +276,19 @@ def execute_block_parallel(
             out.append((gi, res.receipt, reads, delta))
         return out
 
-    if len(lanes) <= 1:
-        lane_results = [run_lane(lane) for lane in lanes]
-    else:
-        with ThreadPoolExecutor(
-            max_workers=len(lanes), thread_name_prefix="exec-lane"
-        ) as pool:
-            lane_results = list(pool.map(run_lane, lanes))
+    with tracing.span(
+        "exec.lanes", cat="exec", era=block_index,
+        lanes=len(lanes), largest_lane=largest,
+    ):
+        if len(lanes) <= 1:
+            # one footprint group: no pool, the lane runs on this thread,
+            # still recorded and still validated below (module docstring)
+            lane_results = [run_lane(lane) for lane in lanes]
+        else:
+            with ThreadPoolExecutor(
+                max_workers=len(lanes), thread_name_prefix="exec-lane"
+            ) as pool:
+                lane_results = list(pool.map(run_lane, lanes))
 
     by_index: Dict[int, tuple] = {}
     for lane_out in lane_results:
@@ -278,25 +297,27 @@ def execute_block_parallel(
 
     # canonical-order merge with read validation; stragglers re-execute
     # serially on the merged snapshot (<= one serial pass in total)
-    merged = state.new_snapshot(base_roots)
-    merged_writes = merged._writes
-    receipts: List[TransactionReceipt] = []
-    stragglers = 0
-    for i, stx in enumerate(ordered):
-        _, receipt, reads, delta = by_index[i]
-        ok = True
-        for (tree, key), seen in reads.items():
-            if merged.get(tree, key) != seen:
-                ok = False
-                break
-        if ok:
-            for tree, key, value in delta:
-                merged_writes[tree][key] = value
-            receipts.append(receipt)
-        else:
-            stragglers += 1
-            res = executer.execute(merged, stx, block_index, i)
-            receipts.append(res.receipt)
+    with tracing.span("exec.merge", cat="exec", era=block_index) as merge_span:
+        merged = state.new_snapshot(base_roots)
+        merged_writes = merged._writes
+        receipts: List[TransactionReceipt] = []
+        stragglers = 0
+        for i, stx in enumerate(ordered):
+            _, receipt, reads, delta = by_index[i]
+            ok = True
+            for (tree, key), seen in reads.items():
+                if merged.get(tree, key) != seen:
+                    ok = False
+                    break
+            if ok:
+                for tree, key, value in delta:
+                    merged_writes[tree][key] = value
+                receipts.append(receipt)
+            else:
+                stragglers += 1
+                res = executer.execute(merged, stx, block_index, i)
+                receipts.append(res.receipt)
+        tracing.annotate(merge_span, stragglers=stragglers)
 
     stats = ParallelStats(
         lanes=len(lanes),
@@ -309,5 +330,6 @@ def execute_block_parallel(
     metrics.set_gauge("exec_conflict_rate", stats.conflict_rate)
     metrics.inc("exec_txs_validated_total", stats.validated)
     metrics.inc("exec_txs_straggler_total", stats.stragglers)
+    metrics.inc("exec_lane_txs_largest_total", largest)
     metrics.inc("exec_blocks_parallel_total")
     return merged, receipts, stats

@@ -536,6 +536,9 @@ PHASES = (
     "tpke_verify",
     "tpke_decrypt",
     "exec",
+    "exec_plan",
+    "exec_lanes",
+    "exec_merge",
     "merkle",
     "commit",
 )
@@ -548,6 +551,12 @@ _PHASE_PRIORITY = {
     # root_produce commit crossing, and the refactored executor
     # (core/parallel_exec.py) is what the exec column exists to expose
     "merkle": 2,
+    # the lane pipeline's three steps (core/parallel_exec.py) nest inside
+    # exec.block and split it: `exec` keeps what they do not cover, which is
+    # all of it where a block ran on the serial executor
+    "exec_plan": 2.5,
+    "exec_lanes": 2.5,
+    "exec_merge": 2.5,
     "exec": 3,
     "propose": 4,
     "commit": 5,
@@ -573,6 +582,9 @@ _SPAN_PHASE = {
     "hb.era_decrypt": "tpke_decrypt",
     "hb.apply_era_results": "tpke_decrypt",
     "exec.block": "exec",
+    "exec.plan": "exec_plan",
+    "exec.lanes": "exec_lanes",
+    "exec.merge": "exec_merge",
     "merkle.freeze": "merkle",
 }
 

@@ -172,11 +172,34 @@ class Op:
     i64_add = b"\x7c"
     i64_sub = b"\x7d"
     i64_mul = b"\x7e"
+    i64_and = b"\x83"
+    i64_or = b"\x84"
+    i64_shl = b"\x86"
+    i64_shr_u = b"\x88"
     i64_eq = b"\x51"
+    i64_ne = b"\x52"
     i64_lt_u = b"\x54"
     i64_ge_u = b"\x5a"
     i32_wrap_i64 = b"\xa7"
     i64_extend_i32_u = b"\xad"
+    memory_copy = b"\xfc\x0a\x00\x00"  # (dst, src, n)
+
+
+def call_const(func_idx: int, *args: int) -> bytes:
+    """Call a function with constant i32 arguments — how a contract hands
+    fixed memory offsets (a storage key here, its value there) to an `env`
+    import or to one of its own functions."""
+    return b"".join(Op.i32_const(a) for a in args) + Op.call(func_idx)
+
+
+def selector_case(selector: bytes, body: Body, at: int = 0) -> bytes:
+    """One arm of a contract's dispatch on the 4-byte method selector it
+    copied to memory offset `at`: `if mem[at:at+4] == selector: body; return`."""
+    return _flatten([
+        Op.i32_const(at), Op.i32_load(),
+        Op.i32_const(int.from_bytes(selector, "little")), Op.i32_eq,
+        Op.if_(), _flatten(body), Op.return_, Op.end,
+    ])
 
 
 class ModuleBuilder:

@@ -17,6 +17,7 @@ from typing import Dict, Tuple
 
 from ..crypto import ecdsa
 from ..crypto.hashes import keccak256
+from ..utils import metrics
 from . import gas as G
 from .interpreter import WasmTrap
 
@@ -118,6 +119,7 @@ def build_env(vm, frame) -> HostTable:
 
     def load_storage(key_off: int, value_off: int) -> None:
         charge(G.LOAD_STORAGE_GAS)
+        metrics.inc("contract_storage_reads_total")
         key = read(key_off, WORD)
         raw = vm.snap.get("storage", skey(key))
         write(value_off, raw if raw and len(raw) == WORD else b"\x00" * WORD)
@@ -125,6 +127,7 @@ def build_env(vm, frame) -> HostTable:
     def save_storage(key_off: int, value_off: int) -> None:
         require_mutable()
         charge(G.SAVE_STORAGE_GAS)
+        metrics.inc("contract_storage_writes_total")
         key = read(key_off, WORD)
         vm.snap.put("storage", skey(key), read(value_off, WORD))
 

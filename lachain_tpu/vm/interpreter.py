@@ -207,6 +207,9 @@ class Instance:
         # round-2 schedule). Set by the VM from the block height; bulk/
         # memory/input gas is unaffected (those prices never changed).
         self.tgas_scale = 1
+        # functions of this instance that ran on the interpreter (call_index):
+        # vm.py folds it into vm_interpreted_calls_total
+        self.interpreted_calls = 0
         self.host = host or {}
         self._imported_funcs: List[Tuple[FuncType, HostFunc]] = []
         for im in module.imports:
@@ -311,6 +314,7 @@ class Instance:
             if compiled is not False:
                 res = compiled(self, *args)
                 return res if ftype.results else None
+            self.interpreted_calls += 1
             return self._exec(fn_def, ftype, list(args))
         finally:
             self._depth -= 1
@@ -390,6 +394,13 @@ class Instance:
         charge = self.gas.charge
         n_body = len(body)
         rate = getattr(fn, "_gas_rate", INTERP_INSTRUCTION_GAS)
+        # a translatable function is here only through LACHAIN_TPU_WASM=interp
+        # and is billed as the translated tier bills it, which charges the
+        # `end` of an `if` without `else` on the false path too. A function
+        # that only this tier can run keeps this tier's own schedule (that
+        # `end` is jumped over, unbilled): another would reprice committed
+        # blocks (core/hardforks.py)
+        bills_skipped_end = rate == INSTRUCTION_GAS
         if rate == INSTRUCTION_GAS and self.tgas_scale != 1:
             rate *= self.tgas_scale  # pre-fast_wasm_gas schedule
 
@@ -428,6 +439,8 @@ class Instance:
                             ctrl.append((end_of[pc], len(stack), arity))
                             pc = ep + 1
                         else:
+                            if bills_skipped_end:
+                                charge(rate)
                             pc = end_of[pc] + 1
                 elif op == 0x05:  # else: end of true arm
                     tgt, _, _ = ctrl[-1]

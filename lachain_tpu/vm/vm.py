@@ -10,11 +10,13 @@ host-import table (external.py).
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
 from ..crypto.hashes import keccak256
 from ..storage.state import Snapshot
+from ..utils import metrics
 from . import gas as G
 from .external import build_env
 from .interpreter import (
@@ -140,6 +142,9 @@ class VirtualMachine:
         self.frames: List[ExecutionFrame] = []
         self.events: List[Tuple[bytes, bytes]] = []
         self.gas: Optional[GasMeter] = None
+        # functions the interpreter ran in the current transaction, every
+        # frame's instance together (Instance.interpreted_calls)
+        self.interpreted_calls = 0
 
     @property
     def frame(self) -> ExecutionFrame:
@@ -173,6 +178,7 @@ class VirtualMachine:
         if top_level:
             self.gas = GasMeter(min(gas_limit, self.block_gas_limit))
             self.events = []
+            self.interpreted_calls = 0
         meter = self.gas
         assert meter is not None
         # a nested call's gas limit bounds the CHILD's spend only: the
@@ -181,6 +187,7 @@ class VirtualMachine:
         outer_limit = meter.limit
         if not top_level and gas_limit:
             meter.limit = min(outer_limit, meter.spent + gas_limit)
+        t_call = time.perf_counter() if top_level else 0.0
         frame = ExecutionFrame(
             contract=contract,
             storage_owner=storage_owner or contract,
@@ -237,7 +244,17 @@ class VirtualMachine:
         finally:
             self.frames.pop()
             meter.limit = outer_limit
+            if frame.instance is not None:
+                self.interpreted_calls += frame.instance.interpreted_calls
         gas_used = meter.spent - start_gas
+        if top_level:
+            # one reading a transaction, nested frames inside it: what the
+            # VM costs a block (PERF.md section 3, layer `vm`)
+            metrics.inc("vm_calls_total")
+            if self.interpreted_calls:
+                metrics.inc("vm_interpreted_calls_total")
+            metrics.inc("vm_call_seconds_total", time.perf_counter() - t_call)
+            metrics.inc("vm_gas_used_total", gas_used)
         if status != 1:
             self.snap.restore(cp)
             del self.events[n_events:]

@@ -531,3 +531,25 @@ def test_era_report_has_exec_phase_row():
     ent = next(e for e in report["eras"] if e["era"] == 7)
     assert ent["phases_s"]["exec"] > 0
     assert "exec" in tracing.era_report_table(report).splitlines()[0]
+    # a serial block has no lane pipeline: the row stays whole
+    split = ("exec_plan", "exec_lanes", "exec_merge")
+    assert all(p in report["phases"] for p in split)
+    assert all(ent["phases_s"][p] == 0 for p in split)
+    # a block through the lanes splits it into plan / lanes / merge
+    bm4 = BlockManager(state._kv, state, executer, lanes=4)
+    many = [
+        _tx(priv, _ACCOUNTS[2 + i % 3][1], 1, i)
+        for i in range(MIN_PARALLEL_TXS)
+    ]
+    bm_mod._EMULATE_MEMO.clear()
+    with tracing.span("era", era=8):
+        bm4.emulate(many, 8)
+    report = tracing.era_report()
+    ent = next(e for e in report["eras"] if e["era"] == 8)
+    assert all(ent["phases_s"][p] > 0 for p in split)
+    header = tracing.era_report_table(report).splitlines()[0]
+    assert all(p in header for p in split)
+    lanes = [s for s in tracing.snapshot() if s["name"] == "exec.lanes"][-1]
+    merge = [s for s in tracing.snapshot() if s["name"] == "exec.merge"][-1]
+    assert lanes["args"]["largest_lane"] == MIN_PARALLEL_TXS  # one sender
+    assert lanes["args"]["lanes"] == 1 and merge["args"]["stragglers"] == 0

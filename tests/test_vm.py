@@ -14,7 +14,14 @@ from lachain_tpu.storage.kv import MemoryKV
 from lachain_tpu.storage.state import StateManager
 from lachain_tpu.utils.serialization import write_bytes
 from lachain_tpu.vm import abi
-from lachain_tpu.vm.builder import I32, I64, ModuleBuilder, Op
+from lachain_tpu.vm.builder import (
+    I32,
+    I64,
+    ModuleBuilder,
+    Op,
+    call_const,
+    selector_case,
+)
 from lachain_tpu.vm.interpreter import GasMeter, Instance, OutOfGas, WasmTrap
 from lachain_tpu.vm.vm import VirtualMachine, deploy_code, get_code
 from lachain_tpu.vm.wasm import decode_module
@@ -277,29 +284,17 @@ def counter_contract() -> bytes:
     save_st = b.add_import("env", "save_storage", [I32, I32], [])
     set_ret = b.add_import("env", "set_return", [I32, I32], [])
     b.add_memory(1)
-    sel_inc = int.from_bytes(SEL_INC, "little")
-    sel_get = int.from_bytes(SEL_GET, "little")
     body = [
-        # mem[0:4] = calldata[0:4]
-        Op.i32_const(0), Op.i32_const(4), Op.i32_const(0), Op.call(copy_call),
-        # load storage[key@64] into 96
-        Op.i32_const(64), Op.i32_const(96), Op.call(load_st),
-        # if selector == inc(): value += 1, save
-        Op.i32_const(0), Op.i32_load(), Op.i32_const(sel_inc), Op.i32_eq,
-        Op.if_(),
-        Op.i32_const(96),
-        Op.i32_const(96), Op.i64_load(), Op.i64_const(1), Op.i64_add,
-        Op.i64_store(),
-        Op.i32_const(64), Op.i32_const(96), Op.call(save_st),
-        Op.i32_const(96), Op.i32_const(8), Op.call(set_ret),
-        Op.return_,
-        Op.end,
-        # if selector == get(): return value
-        Op.i32_const(0), Op.i32_load(), Op.i32_const(sel_get), Op.i32_eq,
-        Op.if_(),
-        Op.i32_const(96), Op.i32_const(8), Op.call(set_ret),
-        Op.return_,
-        Op.end,
+        call_const(copy_call, 0, 4, 0),  # mem[0:4] = calldata[0:4]
+        call_const(load_st, 64, 96),  # storage[key@64] into 96
+        selector_case(SEL_INC, [  # value += 1, save, return it
+            Op.i32_const(96),
+            Op.i32_const(96), Op.i64_load(), Op.i64_const(1), Op.i64_add,
+            Op.i64_store(),
+            call_const(save_st, 64, 96),
+            call_const(set_ret, 96, 8),
+        ]),
+        selector_case(SEL_GET, [call_const(set_ret, 96, 8)]),
         Op.unreachable,
     ]
     b.add_function([], [], [], body, export="start")
@@ -750,6 +745,123 @@ def test_translator_interpreter_differential():
 
     res = run_both(b3, "f", [(1,), (0,)])
     assert res == [("ok", 1), ("ok", 2)]
+
+
+@pytest.mark.parametrize("cond", [0, 1])
+@pytest.mark.parametrize("arm_returns", [False, True])
+def test_tiers_bill_an_if_without_else_alike(cond, arm_returns, monkeypatch):
+    """A translatable function is billed alike by the engine that runs it:
+    the translated tier charges the `end` of an `if` without `else` on the
+    false path too, and the interpreter, which jumps over that `end`, used to
+    leave it out when LACHAIN_TPU_WASM=interp made it run such a function
+    (200 gas a skipped arm, on every selector dispatch)."""
+
+    def gas(tier):
+        if tier == "interp":
+            monkeypatch.setenv("LACHAIN_TPU_WASM", "interp")
+        else:
+            monkeypatch.delenv("LACHAIN_TPU_WASM", raising=False)
+        b = ModuleBuilder()
+        arm = [Op.i32_const(1), Op.return_] if arm_returns else [Op.nop]
+        body = [Op.local_get(0), Op.if_(), *arm, Op.end, Op.i32_const(2)]
+        b.add_function([I32], [I32], [], body, export="f")
+        inst = instantiate(b, gas=GasMeter(10**9))
+        return inst.invoke("f", [cond]), inst.gas.spent
+
+    assert gas("translated") == gas("interp")
+
+
+def _interpreter_only(b: ModuleBuilder, export=None) -> int:
+    """(x) -> 2, through an `if` without `else` on x whose arm assigns to an
+    immutable global: decodable, a trap only if executed, so the translator
+    leaves the function to the interpreter."""
+    b.add_global(I32, False, [Op.i32_const(0)])
+    body = [
+        Op.local_get(0), Op.if_(), Op.i32_const(1), Op.global_set(0), Op.end,
+        Op.i32_const(2),
+    ]
+    return b.add_function([I32], [I32], [], body, export=export)
+
+
+@pytest.mark.parametrize("override", [None, "interp"])
+def test_interpreter_only_function_keeps_its_gas(override, monkeypatch):
+    """A function that only the interpreter can run is billed as it always
+    was: local.get, if, i32.const and the function's end at 2000 each, the
+    skipped arm's `end` not among them. Chains hold blocks billed so; the
+    number is the one the tree before PR 31 gives."""
+    if override:
+        monkeypatch.setenv("LACHAIN_TPU_WASM", override)
+    b = ModuleBuilder()
+    _interpreter_only(b, export="f")
+    inst = instantiate(b, gas=GasMeter(10**9))
+    assert (inst.invoke("f", [0]), inst.gas.spent) == (2, 8000)
+    assert inst.interpreted_calls == 1
+    with pytest.raises(WasmTrap):
+        inst.invoke("f", [1])
+
+
+def _vm_counters():
+    from lachain_tpu.utils import metrics
+
+    return [
+        metrics.counter_value("vm_calls_total"),
+        metrics.counter_value("vm_interpreted_calls_total"),
+    ]
+
+
+def test_an_inner_function_on_the_interpreter_counts_the_call_as_interpreted():
+    """`start` translates and calls a function that does not: the
+    transaction is one call and one interpreted call, direct or through
+    another contract's frame."""
+    b = ModuleBuilder()
+    inner = _interpreter_only(b)
+    b.add_function(
+        [], [], [], [Op.i32_const(0), Op.call(inner), Op.drop], export="start"
+    )
+    snap, executer, priv, addr = make_chain()
+    deployed = []
+    for nonce, code in enumerate([b.build(), proxy_contract(), counter_contract()]):
+        res = _run_tx(
+            snap, executer, priv, addr, nonce,
+            to=system_contracts.DEPLOY_ADDRESS,
+            invocation=system_contracts.SEL_DEPLOY + write_bytes(code),
+        )
+        assert res.ok
+        deployed.append(res.receipt.return_data)
+    mixed, proxy, counter = deployed
+    before = _vm_counters()
+    assert _run_tx(snap, executer, priv, addr, 3, to=mixed, invocation=b"\x01").ok
+    assert _vm_counters() == [before[0] + 1, before[1] + 1]
+    _run_tx(snap, executer, priv, addr, 4, to=proxy, invocation=mixed)
+    assert _vm_counters() == [before[0] + 2, before[1] + 2]
+    assert _run_tx(snap, executer, priv, addr, 5, to=counter, invocation=SEL_INC).ok
+    assert _vm_counters() == [before[0] + 3, before[1] + 2]
+
+
+@pytest.mark.parametrize("what", ["past the table", "an import alone", "an import"])
+def test_start_exported_at_no_function_is_a_receipt_not_a_crash(what):
+    """deploy_code asks only that `start` is an exported function, and the
+    decoder checks no export index: a module may name an index past its
+    functions, or one of its imports. Calling it is a failed receipt (a
+    host function called as `start` just returns), never an exception out
+    of executer.execute, with the VM's counters read on the way."""
+    b = ModuleBuilder()
+    b.add_import("env", "get_call_size", [], [I32])
+    if what != "an import alone":
+        b.add_function([], [], [], [Op.nop])
+    b.exports.append(("start", 0, 9 if what == "past the table" else 0))
+    snap, executer, priv, addr = make_chain()
+    res = _run_tx(
+        snap, executer, priv, addr, 0,
+        to=system_contracts.DEPLOY_ADDRESS,
+        invocation=system_contracts.SEL_DEPLOY + write_bytes(b.build()),
+    )
+    assert res.ok
+    before = _vm_counters()
+    called = _run_tx(snap, executer, priv, addr, 1, to=res.receipt.return_data, invocation=b"\x01")
+    assert called.ok == (what != "past the table")
+    assert _vm_counters() == [before[0] + 1, before[1]]
+    assert execution.get_nonce(snap, addr) == 2
 
 
 def test_translator_speedup_over_interpreter():
