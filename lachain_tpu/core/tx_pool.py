@@ -23,6 +23,12 @@ sanitize then cost what they touch: one state nonce read per pooled sender
 (`txpool_state_nonce_reads_total` counts them), not one per pooled
 transaction, and a hash names its shard without a probe of all of them.
 
+The crash-restore repository is written ONCE per eviction call: a call
+forgets its transactions in memory under the shard locks, then hands their
+keys to the store as one `write_batch` with no shard lock held
+(`_delete_rows`), so a block's eviction waits for one WAL fsync, not one a
+transaction. `txpool_evict_writes_total` counts those writes.
+
 Lock ordering: shard lock -> `_nonce_lock` (state-trie nonce reads; the
 trie's LRU cache is not thread-safe). No path acquires two shard locks
 at once, so there is no cross-shard ordering to get wrong. `peek` copies
@@ -170,6 +176,7 @@ class TransactionPool:
             if nonce < self._account_nonce(sender):
                 return False  # already used
             entry = (nonce, (-stx.tx.gas_price, h.translate(_INVERT)), h, stx)
+            replaced: List[bytes] = []
             chain = shard.chains.get(sender)
             if chain is None:
                 shard.chains[sender] = [entry]
@@ -180,7 +187,7 @@ class TransactionPool:
                     old = chain[i]
                     if stx.tx.gas_price <= old[3].tx.gas_price:
                         return False
-                    self._forget(shard, old[2])
+                    replaced.append(self._forget(shard, old[2]))
                     chain[i] = entry
                 else:
                     chain.insert(i, entry)
@@ -190,7 +197,13 @@ class TransactionPool:
             # crash-restore repository — a kill here loses the tx from the
             # restart (best-effort by design; gossip re-fills)
             crash_point("pool.save.mid")
-            self._kv.put(prefixed(EntryPrefix.POOL_TX, h), stx.encode())
+            key = prefixed(EntryPrefix.POOL_TX, h)
+            if replaced:
+                # one atomic write: the repository never holds both rows of
+                # the nonce, and never neither
+                self._kv.write_batch([(key, stx.encode())], replaced)
+            else:
+                self._kv.put(key, stx.encode())
         # tx lifecycle stamp OUTSIDE the shard lock (admission succeeded;
         # sampled-only, first stamp wins across gossip re-admissions)
         txtrace.stamp(h, "pool")
@@ -300,27 +313,48 @@ class TransactionPool:
     # -- lifecycle --------------------------------------------------------------
     def remove_included(self, tx_hashes) -> None:
         tx_hashes = list(tx_hashes)
-        with tracing.span("pool.remove_included", "pool", n=len(tx_hashes)):
-            for h in tx_hashes:
-                self._evict(h)
+        with tracing.span("pool.remove_included", "pool", n=len(tx_hashes)) as sid:
+            keys = [self._evict(h) for h in tx_hashes]
+            tracing.annotate(sid, writes=self._delete_rows(keys))
 
     def sanitize(self) -> int:
         """Drop txs whose nonce is now stale (reference sanitize-on-persist,
         TransactionPool.cs:79-90). Returns number evicted."""
-        n = 0
+        keys: List[bytes] = []
         with tracing.span("pool.sanitize", "pool") as sid:
             for shard in self._shards:
                 with shard.lock:
                     for sender, chain in list(shard.chains.items()):
                         stale = bisect_left(chain, (self._account_nonce(sender),))
                         for entry in chain[:stale]:
-                            self._forget(shard, entry[2])
+                            keys.append(self._forget(shard, entry[2]))
                         del chain[:stale]
                         if not chain:
                             del shard.chains[sender]
-                        n += stale
-            tracing.annotate(sid, evicted=n)
-        return n
+            tracing.annotate(
+                sid, evicted=len(keys), writes=self._delete_rows(keys)
+            )
+        return len(keys)
+
+    def _delete_rows(self, keys: List[bytes]) -> int:
+        """The repository half of an eviction: every key of the call in ONE
+        atomic write, issued with no shard lock held, so admission into a
+        shard never queues behind the store's fsync. Returns the writes
+        issued (0 or 1).
+
+        Memory has forgotten these transactions already, as it did per hash
+        when each had its own delete. A crash before the write leaves their
+        rows behind, all or none, and `restore()` drops each on re-admission
+        (`add` refuses a nonce below the account's). A concurrent `add` of
+        one of them cannot put back a row that this write then deletes from
+        under a pooled transaction: `remove_included` runs after the block's
+        commit and `sanitize` picks what is stale, so each has a nonce below
+        its account's and `add` refuses it before its `put`."""
+        if not keys:
+            return 0
+        self._kv.write_batch([], keys)
+        metrics.inc("txpool_evict_writes_total")
+        return 1
 
     def restore(self) -> int:
         """Reload persisted pool txs (reference Restore, TransactionPool.cs:98)."""
@@ -339,33 +373,30 @@ class TransactionPool:
                 self._kv.delete(key)
         return count
 
-    def _evict(self, h: bytes) -> None:
+    def _evict(self, h: bytes) -> bytes:
+        """Forgets `h` if pooled; either way returns its repository key: a
+        hash not pooled (or evicted since the lookup) may still have a row."""
         sender = self._sender_of.get(h)
         if sender is not None:
             shard = self._shard(sender)
             with shard.lock:
-                if h in shard.txs:
-                    self._evict_in_shard(shard, h)
-                    return
-        # not pooled (or evicted since the lookup): the repository may
-        # still hold it
-        self._kv.delete(prefixed(EntryPrefix.POOL_TX, h))
+                stx = shard.txs.get(h)
+                if stx is not None:
+                    key = self._forget(shard, h)
+                    chain = shard.chains[sender]
+                    del chain[bisect_left(chain, (stx.tx.nonce,))]
+                    if not chain:
+                        del shard.chains[sender]
+                    return key
+        return prefixed(EntryPrefix.POOL_TX, h)
 
-    def _forget(self, shard: _PoolShard, h: bytes) -> None:
+    def _forget(self, shard: _PoolShard, h: bytes) -> bytes:
         """Caller holds shard.lock and `h` is pooled there. Drops everything
-        of `h` but its chain entry, which the caller replaces or deletes."""
+        of `h` in memory but its chain entry, which the caller replaces or
+        deletes, and returns its repository key, which the caller deletes."""
         del shard.txs[h]
         del self._sender_of[h]
-        self._kv.delete(prefixed(EntryPrefix.POOL_TX, h))
-
-    def _evict_in_shard(self, shard: _PoolShard, h: bytes) -> None:
-        """Caller holds shard.lock and `h` is pooled there."""
-        sender, nonce = self._sender_of[h], shard.txs[h].tx.nonce
-        self._forget(shard, h)
-        chain = shard.chains[sender]
-        del chain[bisect_left(chain, (nonce,))]
-        if not chain:
-            del shard.chains[sender]
+        return prefixed(EntryPrefix.POOL_TX, h)
 
     def tx_hashes(self) -> set:
         """Snapshot of pooled tx hashes (pending-tx filters)."""
@@ -378,10 +409,16 @@ class TransactionPool:
     def clear(self) -> None:
         """Drop every pooled tx, memory AND persisted entries (reference
         clearInMemoryPool + repository delete, TransactionPool.cs)."""
+        keys: List[bytes] = []
         for shard in self._shards:
             with shard.lock:
-                for h in list(shard.txs):
-                    self._evict_in_shard(shard, h)
+                keys.extend(self._forget(shard, h) for h in list(shard.txs))
+                shard.chains.clear()
+        # one write for the call, outside the locks like an eviction's. A tx
+        # cleared here and admitted again before the write loses its row, not
+        # its place in the pool: the repository is best-effort (see `add`)
+        if keys:
+            self._kv.write_batch([], keys)
 
     def persisted_hashes(self) -> List[bytes]:
         """Hashes of txs currently saved in the crash-restore repository."""

@@ -7,13 +7,17 @@ sequences and asks for identical lists in identical order, identical return
 values and an identical crash-restore repository after every step. Block
 contents, and so block hashes, follow from `peek`'s order.
 
-Below it: the nonce-read counter is bounded by senders, `restore()`, the
-nonce memo against a commit that no pool call announces, and where the
-pool's spans sit in a traced N=4 devnet era. Counts and order only: a CPU
-run says nothing about time.
+Below it: the nonce-read counter is bounded by senders, `restore()`, what an
+eviction writes to the repository (one batch a call, its crash window, its
+race with admission), the nonce memo against a commit that no pool call
+announces, and where the pool's spans sit in a traced N=4 devnet era. Counts
+and order only: a CPU run says nothing about time.
 """
 import heapq
 import random
+import sys
+import threading
+import time
 
 import pytest
 
@@ -32,6 +36,7 @@ from lachain_tpu.utils import metrics, tracing
 
 CHAIN = 41
 READS = "txpool_state_nonce_reads_total"
+EVICT_WRITES = "txpool_evict_writes_total"
 
 
 class Rng:
@@ -51,6 +56,23 @@ def _stx(sender: bytes, nonce: int, gas_price: int, salt: int = 0):
     stx = SignedTransaction(tx, sender + bytes(45))
     object.__setattr__(stx, "_sender_cache", (CHAIN, sender))
     return stx
+
+
+def _keys(n: int, seed: int):
+    """(private key, address) of `n` accounts whose transactions carry real
+    signatures: what restore() needs, which recovers every sender again."""
+    keys = []
+    for i in range(n):
+        priv = ecdsa.generate_private_key(Rng(seed + i))
+        keys.append((priv, ecdsa.address_from_public_key(ecdsa.public_key_bytes(priv))))
+    return keys
+
+
+def _signed(priv, nonce, gas_price, value=1):
+    tx = Transaction(
+        to=b"\x09" * 20, value=value, nonce=nonce, gas_price=gas_price, gas_limit=21000
+    )
+    return sign_transaction(tx, priv, CHAIN)
 
 
 # ---------------------------------------------------------------------------
@@ -401,19 +423,13 @@ def test_two_pools_share_nothing():
 def test_restore_rebuilds_the_same_pool():
     """Real signatures: restore() decodes from the repository and recovers
     every sender again."""
-    accounts = []
-    for i in range(5):
-        priv = ecdsa.generate_private_key(Rng(4000 + i))
-        accounts.append((priv, ecdsa.address_from_public_key(ecdsa.public_key_bytes(priv))))
+    accounts = _keys(5, 4000)
     state = {accounts[0][1]: 2}
     kv = MemoryKV()
     pool = TransactionPool(kv, CHAIN, lambda a: state.get(a, 0))
 
     def signed(k, nonce, gas_price, value=1):
-        tx = Transaction(
-            to=b"\x09" * 20, value=value, nonce=nonce, gas_price=gas_price, gas_limit=21000
-        )
-        return sign_transaction(tx, accounts[k][0], CHAIN)
+        return _signed(accounts[k][0], nonce, gas_price, value)
 
     offered = [
         signed(0, 2, 3), signed(0, 3, 1), signed(0, 5, 6),  # a gap at 4
@@ -442,6 +458,269 @@ def test_restore_rebuilds_the_same_pool():
         ] == [t.hash() for t in pool.peek(3, rng=random.Random(seed), window_txs=100)]
     for _priv, addr in accounts:
         assert again.next_nonce(addr) == pool.next_nonce(addr)
+
+
+# ---------------------------------------------------------------------------
+# what an eviction writes to the repository
+# ---------------------------------------------------------------------------
+
+
+class _SpyKV(MemoryKV):
+    """A MemoryKV that lists the writes it is asked for and can be told to
+    fail the next batch, as a store whose process dies before it acts."""
+
+    def __init__(self):
+        super().__init__()
+        self.calls = []
+        self.fail_next_batch = False
+
+    def put(self, key, value):
+        self.calls.append(("put", key))
+        super().put(key, value)
+
+    def delete(self, key):
+        self.calls.append(("delete", key))
+        super().delete(key)
+
+    def write_batch(self, puts, deletes=()):
+        self.calls.append(("write_batch", [k for k, _ in puts], list(deletes)))
+        if self.fail_next_batch:
+            self.fail_next_batch = False
+            raise IOError("the store is gone")
+        super().write_batch(puts, deletes)
+
+
+def _row(h: bytes) -> bytes:
+    return prefixed(EntryPrefix.POOL_TX, h)
+
+
+@pytest.mark.parametrize("k,strangers", [(1, 0), (5, 2), (300, 40), (0, 3)])
+def test_an_eviction_is_one_write_whatever_it_evicts(k, strangers):
+    """`k` pooled hashes and `strangers` the pool never held: one batch
+    with a delete for each, no single delete, and the writes counted."""
+    rnd = random.Random(k)
+    kv = _SpyKV()
+    pool = TransactionPool(kv, CHAIN, lambda a: 0)
+    senders = [rnd.randbytes(20) for _ in range(24)]
+    pooled = [_stx(senders[i % 24], i // 24, 1 + i % 5) for i in range(k + 30)]
+    assert all(pool.add(stx) for stx in pooled)
+    included = [stx.hash() for stx in rnd.sample(pooled, k)]
+    included += [rnd.randbytes(32) for _ in range(strangers)]
+    rnd.shuffle(included)
+    kv.calls.clear()
+    before = metrics.counter_value(EVICT_WRITES)
+    pool.remove_included(iter(included))
+    assert [c[0] for c in kv.calls] == ["write_batch"]
+    assert kv.calls[0][1] == [] and kv.calls[0][2] == [_row(h) for h in included]
+    assert metrics.counter_value(EVICT_WRITES) - before == 1
+    assert len(pool) == 30 and not pool.tx_hashes() & set(included)
+    assert sorted(pool.persisted_hashes()) == sorted(pool.tx_hashes())
+
+
+def _three_deep_pool():
+    """40 senders with nonces 0..2 pooled, over a state the test moves."""
+    state = {}
+    kv = _SpyKV()
+    pool = TransactionPool(kv, CHAIN, lambda a: state.get(a, 0))
+    senders = [bytes([i]) * 20 for i in range(40)]
+    assert all(pool.add(_stx(s, nonce, 2)) for s in senders for nonce in range(3))
+    kv.calls.clear()
+    return state, kv, pool, senders
+
+
+def test_an_eviction_with_nothing_to_evict_writes_nothing():
+    _state, kv, pool, _senders = _three_deep_pool()
+    before = metrics.counter_value(EVICT_WRITES)
+    pool.remove_included([])
+    assert pool.sanitize() == 0
+    assert kv.calls == [] and metrics.counter_value(EVICT_WRITES) == before
+
+
+def test_a_block_is_one_write_whoever_finds_its_transactions():
+    state, kv, pool, senders = _three_deep_pool()
+    before = metrics.counter_value(EVICT_WRITES)
+    # the producer's order: a block of the first two nonces of every other
+    # sender; remove_included writes once and leaves sanitize nothing
+    block = [_stx(s, nonce, 2).hash() for s in senders[::2] for nonce in range(2)]
+    for s in senders[::2]:
+        state[s] = 2
+    pool.remove_included(block)
+    assert pool.sanitize() == 0
+    assert kv.calls == [("write_batch", [], [_row(h) for h in block])]
+    # the synchronizer's order: nonces move, nobody names the hashes; sanitize
+    # finds them itself, over all shards, and writes once
+    for s in senders[1::2]:
+        state[s] = 1
+    kv.calls.clear()
+    assert pool.sanitize() == 20
+    assert [c[0] for c in kv.calls] == ["write_batch"] and kv.calls[0][1] == []
+    assert sorted(kv.calls[0][2]) == sorted(_row(_stx(s, 0, 2).hash()) for s in senders[1::2])
+    assert metrics.counter_value(EVICT_WRITES) - before == 2
+    assert sorted(pool.persisted_hashes()) == sorted(pool.tx_hashes()) and len(pool) == 60
+
+
+def test_clear_is_one_write_and_leaves_a_pool_that_works():
+    _state, kv, pool, senders = _three_deep_pool()
+    before = metrics.counter_value(EVICT_WRITES)
+    pool.clear()
+    assert [c[0] for c in kv.calls] == ["write_batch"] and len(kv.calls[0][2]) == 120
+    assert len(pool) == 0 and pool.persisted_hashes() == [] and pool.peek(10) == []
+    assert pool.add(_stx(senders[0], 0, 2)) and pool.next_nonce(senders[0]) == 1
+    kv.calls.clear()
+    pool.clear()
+    pool.clear()  # an empty pool: nothing to write
+    assert [c[0] for c in kv.calls] == ["write_batch"]
+    assert metrics.counter_value(EVICT_WRITES) == before, "the operator's verb is no eviction"
+
+
+def test_a_fee_replacement_leaves_the_nonce_one_row():
+    kv = _SpyKV()
+    pool = TransactionPool(kv, CHAIN, lambda a: 0)
+    sender = b"\x44" * 20
+    cheap, rich = _stx(sender, 0, 2), _stx(sender, 0, 5, salt=1)
+    assert pool.add(cheap) and pool.add(_stx(sender, 1, 1))
+    kv.calls.clear()
+    assert not pool.add(_stx(sender, 0, 2, salt=2)), "no richer: refused"
+    assert kv.calls == []
+    assert pool.add(rich)
+    # the new row and the old row's delete are one atomic write
+    assert kv.calls == [("write_batch", [_row(rich.hash())], [_row(cheap.hash())])]
+    assert pool.get(cheap.hash()) is None and pool.get(rich.hash()) is rich
+    assert sorted(pool.persisted_hashes()) == sorted(pool.tx_hashes())
+    assert [t.hash() for t in pool.peek(10)][0] == rich.hash() and len(pool) == 2
+
+
+@pytest.fixture(params=["memory", "lsm"])
+def reopenable_kv(request, tmp_path):
+    """open() gives the store; on the LSM engine each call after the first
+    closes it and opens its directory again, as a restarted node does."""
+    if request.param == "memory":
+        kv = MemoryKV()
+        yield lambda: kv
+        return
+    from lachain_tpu.storage.lsm import LsmKV
+
+    held = []
+
+    def open_():
+        if held:
+            held.pop().close()
+        held.append(LsmKV(str(tmp_path / "db")))
+        return held[-1]
+
+    yield open_
+    if held:
+        held.pop().close()
+
+
+def test_restore_after_evictions_rebuilds_the_same_pool(reopenable_kv):
+    keys = _keys(4, 4100)
+    state = {}
+    read = lambda a: state.get(a, 0)  # noqa: E731
+    pool = TransactionPool(reopenable_kv(), CHAIN, read)
+    offered = [
+        _signed(priv, nonce, 1 + (nonce + i) % 4)
+        for i, (priv, _a) in enumerate(keys)
+        for nonce in range(4)
+    ]
+    assert all(pool.add(stx) for stx in offered)
+    # a block of every sender's nonce 0, and sender 3's nonce 1 besides;
+    # sender 2's chain moved further than the block says (a synced block)
+    block = [stx for stx in offered if stx.tx.nonce == 0] + [offered[13]]
+    for _priv, addr in keys:
+        state[addr] = 1
+    state[keys[3][1]] = 2
+    state[keys[2][1]] = 3
+    pool.remove_included([stx.hash() for stx in block] + [b"\x5a" * 32])
+    assert pool.sanitize() == 2
+    assert len(pool) == 16 - 5 - 2
+    assert sorted(pool.persisted_hashes()) == sorted(pool.tx_hashes())
+
+    again = TransactionPool(reopenable_kv(), CHAIN, read)
+    assert again.restore() == len(pool)
+    assert again.tx_hashes() == pool.tx_hashes()
+    assert sorted(again.persisted_hashes()) == sorted(again.tx_hashes())
+    assert [t.hash() for t in again.peek(100)] == [t.hash() for t in pool.peek(100)]
+    for _priv, addr in keys:
+        assert again.next_nonce(addr) == pool.next_nonce(addr) == 4
+
+
+def test_a_crash_between_forgetting_and_the_write_leaves_no_included_row():
+    """Memory forgets first, the repository second. The store dies in
+    between: every row of the block is still there, and the restart's
+    restore() re-admits none of them and deletes each."""
+    keys = _keys(3, 4200)
+    state = {}
+    read = lambda a: state.get(a, 0)  # noqa: E731
+    kv = _SpyKV()
+    pool = TransactionPool(kv, CHAIN, read)
+    offered = [_signed(priv, nonce, 3) for priv, _a in keys for nonce in range(3)]
+    assert all(pool.add(stx) for stx in offered)
+    block = [stx for stx in offered if stx.tx.nonce < 2]
+    for _priv, addr in keys:
+        state[addr] = 2  # execute_block has committed
+    kv.fail_next_batch = True
+    with pytest.raises(IOError):
+        pool.remove_included([stx.hash() for stx in block])
+    left = {stx.hash() for stx in offered if stx.tx.nonce == 2}
+    assert pool.tx_hashes() == left, "memory had forgotten the block already"
+    assert set(pool.persisted_hashes()) == {stx.hash() for stx in offered}
+
+    again = TransactionPool(kv, CHAIN, read)
+    assert again.restore() == 3
+    assert again.tx_hashes() == left and set(again.persisted_hashes()) == left
+    assert all(again.get(stx.hash()) is None for stx in block)
+
+
+def test_admission_races_eviction_and_the_repository_keeps_up():
+    """More admitting threads than cores offer every tx over and over, the
+    evicted ones too, while blocks commit and are evicted. A row deleted
+    from under a pooled tx, or left behind an evicted one, shows at the end."""
+    senders = [bytes([7 * i]) * 20 for i in range(32)]
+    txs = {s: [_stx(s, nonce, 1 + nonce % 3) for nonce in range(12)] for s in senders}
+    everything = [stx for chain in txs.values() for stx in chain]
+    state = {}
+    pool = TransactionPool(MemoryKV(), CHAIN, lambda a: state.get(a, 0))
+    stop = threading.Event()
+    failures = []
+
+    def admit(seed):
+        rnd = random.Random(seed)
+        try:
+            while not stop.is_set():
+                pool.add(rnd.choice(everything))
+        except Exception as e:  # noqa: BLE001 - asserted on below
+            failures.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=admit, args=(i,)) for i in range(16)]
+    try:
+        for t in threads:
+            t.start()
+        rnd = random.Random(99)
+        deadline = time.monotonic() + 20
+        for height in range(1, 11):
+            while len(pool) < 32 * (12 - height) and time.monotonic() < deadline:
+                time.sleep(0.001)
+            # a block: the next nonce of most senders; the rest move with no
+            # hash named, and are sanitize's to find
+            block = [txs[s][height - 1].hash() for s in senders if rnd.random() < 0.8]
+            for s in senders:
+                state[s] = height
+            pool.remove_included(block)
+            pool.sanitize()
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=30)
+        sys.setswitchinterval(interval)
+    assert not failures and not any(t.is_alive() for t in threads)
+    want = {stx.hash() for stx in everything if stx.tx.nonce >= 10}
+    for stx in everything:
+        pool.add(stx)  # whatever the threads had not offered again yet
+    assert pool.tx_hashes() == want
+    assert set(pool.persisted_hashes()) == want
 
 
 # ---------------------------------------------------------------------------
@@ -508,14 +787,25 @@ def _inside(s, outer) -> bool:
 def test_pool_spans_sit_where_the_metrics_expect_them():
     tracing.reset_for_tests()
     metrics.reset_all_for_tests()
-    accounts = []
-    for i in range(6):
-        priv = ecdsa.generate_private_key(Rng(5000 + i))
-        accounts.append((priv, ecdsa.address_from_public_key(ecdsa.public_key_bytes(priv))))
+    accounts = _keys(6, 5000)
     net = Devnet(
         4, 1, seed=9, txs_per_block=100, engine="native", rbc_batch=True,
         initial_balances={addr: 10**18 for _p, addr in accounts},
     )
+    # when each pool's repository takes a batch of deletes, on the tracer's clock
+    row_writes = []
+
+    def timed(write_batch):
+        def spy(puts, deletes=()):
+            t0 = time.monotonic()
+            write_batch(puts, deletes)
+            if any(k.startswith(_row(b"")) for k in deletes):
+                row_writes.append({"start": t0, "end": time.monotonic(), "n": len(deletes)})
+
+        return spy
+
+    for node in net.nodes:
+        node.pool._kv.write_batch = timed(node.pool._kv.write_batch)
     try:
         sent = 0
         for era in (1, 2):
@@ -536,6 +826,7 @@ def test_pool_spans_sit_where_the_metrics_expect_them():
         net.close()
         spans = tracing.snapshot()
         tracing.reset_for_tests()
+        evict_writes = metrics.counter_value(EVICT_WRITES)
     by_name = {}
     for s in spans:
         by_name.setdefault(s["name"], []).append(s)
@@ -555,6 +846,13 @@ def test_pool_spans_sit_where_the_metrics_expect_them():
     assert all("size" in s["args"] for s in by_name["pool.peek"])
     assert sum(s["args"]["n"] for s in by_name["pool.remove_included"]) == 4 * sent
     assert all(s["args"]["evicted"] == 0 for s in by_name["pool.sanitize"])
+    # a block's eviction is one write, inside the span the metric reads
+    assert all(s["args"]["writes"] == 1 for s in by_name["pool.remove_included"])
+    assert all(s["args"]["writes"] == 0 for s in by_name["pool.sanitize"])
+    assert evict_writes == len(row_writes) == 2 * 4
+    for w in row_writes:
+        inside = [s for s in by_name["pool.remove_included"] if _inside(w, s)]
+        assert len(inside) == 1 and inside[0]["args"]["n"] == w["n"], w
     submits = by_name["devnet.submit_tx"]
     assert len(submits) == sent
     assert not any(_inside(s, era) for s in submits for era in eras)
