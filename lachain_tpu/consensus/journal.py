@@ -29,6 +29,11 @@ halves, and ``tools/check_invariants.py`` rule P holds both:
     once a frame, not once a record, while the WAL writer group-commits
     the records between.
 
+The node's hook runs the transaction pool's barrier beside this one
+(``core/node.Node._frame_barrier``; ``core/tx_pool.py``): an admitted
+transaction's crash-restore row is submitted to the same WAL and is durable
+before the frame that gossips or proposes it, by the same wait.
+
 A crash between ``record`` and the barrier leaves the record either absent
 (not journaled, and no frame carried it) or present (recovery re-arms it
 and the re-run re-sends it byte-identically): the states a crash between

@@ -139,6 +139,9 @@ class TcpFleet:
         node's pool holds all of them — the pacing that makes proposals
         (hence committed block content) identical across runs."""
         entry = self.live()[0]
+        # in-process pacing, not an acknowledgement: what is waited for
+        # below arrives through the entry node's frames, which leave after
+        # the pool's barrier
         for stx in txs:
             if not entry.submit_tx(stx):
                 raise RuntimeError(f"tx rejected by pool: {stx.hash().hex()}")

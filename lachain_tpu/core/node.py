@@ -144,10 +144,10 @@ class Node:
             port,
             flush_interval=flush_interval,
             advertise_host=advertise_host,
-            # the network only queues a consensus payload, so the journal's
-            # fsync wait moves to where it writes to a socket: once a
-            # frame, not once a record
-            barrier=self.journal.frame_barrier(),
+            # the network only queues, so the fsync waits move to where it
+            # writes to a socket, once a frame: not once a journal record,
+            # and not once an admitted transaction
+            barrier=self._frame_barrier(),
         )
         self._relay_spec = relay
         self.network.on_consensus = self._on_consensus
@@ -633,6 +633,22 @@ class Node:
             logger.info("rejoin: requested replay for eras %s", self._rejoin_eras)
             self._rejoin_eras = []
 
+    def _frame_barrier(self):
+        """What the network runs in front of every write to a socket
+        (network/worker.durable_before_wire): whatever this node submitted
+        to its store and a frame may carry or follow — the journal's
+        records and the pool's admitted rows — is durable when it returns.
+        Both ride the one WAL, so the second wait finds its ticket covered
+        and returns at once. Either one raising holds the frame back."""
+        journal_barrier = self.journal.frame_barrier()
+        pool_barrier = self.pool.frame_barrier()
+
+        def barrier() -> None:
+            journal_barrier()
+            pool_barrier()
+
+        return barrier
+
     # -- tx ingress + gossip -----------------------------------------------
 
     def submit_tx(self, stx: SignedTransaction) -> bool:
@@ -641,6 +657,10 @@ class Node:
         from ..utils import txtrace
 
         txtrace.stamp(stx.hash(), "submit")
+        # admitted to memory, its crash-restore row submitted: True is for
+        # this process alone. Whoever passes it on waits for the pool's
+        # barrier first — the network in front of the gossip's frame
+        # (_frame_barrier), the RPC service in front of its answer
         ok = self.pool.add(stx)
         if ok:
             self.network.broadcast(wire.sync_pool_reply([stx]))
@@ -945,6 +965,8 @@ class Node:
             invocation=invocation,
         )
         stx = sign_transaction(tx, self.private_keys.ecdsa_priv, self.chain_id)
+        # nobody is answered here: the node's own lifecycle is the caller,
+        # and the transaction reaches others only through frames
         self.submit_tx(stx)
 
     def _install_rotated_keys(self, first_era, keyring, participants) -> None:

@@ -29,6 +29,33 @@ keys to the store as one `write_batch` with no shard lock held
 (`_delete_rows`), so a block's eviction waits for one WAL fsync, not one a
 transaction. `txpool_evict_writes_total` counts those writes.
 
+An ADMISSION's row is durable before anything acknowledges the admission,
+and who waits for its fsync depends on who owns the pool:
+
+  * a pool that stands alone (the devnet, the crash workload, a test) has
+    nobody after `add` to wait, so `add` writes the row with the store's
+    synchronous `put` (a fee replacement: one `write_batch` of the new
+    row and the old one's delete) and the row is durable when `add`
+    returns;
+  * an owner that acknowledges admissions at a boundary of its own
+    (`core/node.Node`: a frame to a peer, an RPC answer) takes
+    `frame_barrier()`. Where the store's WAL really overlaps
+    (`kv.supports_async_batches`: LsmKV) `add` then only SUBMITS the same
+    puts and deletes (`write_batch_async`, still under the shard's lock,
+    so a hash's put is ahead of any later delete of it in the WAL) and
+    keeps the newest ticket; `barrier()` waits for it. The node runs the
+    barrier in front of every write to a socket, beside the consensus
+    journal's (`network/worker.durable_before_wire`), and the RPC service
+    in front of the answer to a submission, off the event loop. The
+    node's thread waits for one fsync a frame, not one a transaction,
+    while the WAL writer group-commits the rows between. On a store
+    without an overlapping WAL the calls stay the synchronous ones.
+    `tools/check_invariants.py` rule P holds the submit to both waits.
+
+`txpool_admit_rows_total`, `txpool_admit_waits_total` and
+`txpool_admit_store_seconds_total` count the submitted rows, the waits that
+had a ticket and the seconds of both; a wait is a span `pool.barrier`.
+
 Lock ordering: shard lock -> `_nonce_lock` (state-trie nonce reads; the
 trie's LRU cache is not thread-safe). No path acquires two shard locks
 at once, so there is no cross-shard ordering to get wrong. `peek` copies
@@ -117,6 +144,17 @@ class TransactionPool:
         # nonces read at `_nonce_version` (see StateNonces); under _nonce_lock
         self._nonce_version: object = None
         self._nonce_memo: Dict[bytes, int] = {}
+        # True once an owner took frame_barrier() on a store whose WAL
+        # overlaps: add() submits its row and barrier() waits for it
+        self._submit_rows = False
+        # under _ticket_lock: the newest write_batch_async ticket not yet
+        # waited for (tickets are WAL sequences: the newest covers every
+        # earlier one), the rows submitted so far, and how many of them a
+        # barrier has waited for
+        self._ticket_lock = threading.Lock()
+        self._ticket: Optional[int] = None
+        self._rows_submitted = 0
+        self._rows_durable = 0
 
     def _shard(self, sender: bytes) -> _PoolShard:
         return self._shards[sender[0] % _N_SHARDS]
@@ -193,12 +231,16 @@ class TransactionPool:
                     chain.insert(i, entry)
             shard.txs[h] = stx
             self._sender_of[h] = sender
-            # the pool's crash window: admitted to memory, not yet in the
-            # crash-restore repository — a kill here loses the tx from the
-            # restart (best-effort by design; gossip re-fills)
+            # the pool's crash window: admitted to memory, its row not yet
+            # durable in the crash-restore repository — a kill here, or
+            # after a submit and before the barrier, loses the tx from the
+            # restart (best-effort by design; gossip re-fills). Nothing has
+            # acknowledged it: submitted, durable before anything does
             crash_point("pool.save.mid")
             key = prefixed(EntryPrefix.POOL_TX, h)
-            if replaced:
+            if self._submit_rows:
+                self._submit_row(key, stx.encode(), replaced)
+            elif replaced:
                 # one atomic write: the repository never holds both rows of
                 # the nonce, and never neither
                 self._kv.write_batch([(key, stx.encode())], replaced)
@@ -208,6 +250,66 @@ class TransactionPool:
         # sampled-only, first stamp wins across gossip re-admissions)
         txtrace.stamp(h, "pool")
         return True
+
+    def _submit_row(self, key: bytes, row: bytes, replaced: List[bytes]) -> None:
+        """The repository half of an admission whose owner waits at its own
+        boundary: the same puts and deletes as one atomic batch, submitted
+        to the store's WAL writer and not waited for. Caller holds the
+        shard's lock, which orders this put before any later delete of the
+        key in the WAL (`_delete_rows` relies on it)."""
+        t0 = time.perf_counter()
+        ticket = self._kv.write_batch_async([(key, row)], replaced)
+        with self._ticket_lock:
+            if self._ticket is None or ticket > self._ticket:
+                self._ticket = ticket
+            self._rows_submitted += 1
+        metrics.inc("txpool_admit_rows_total")
+        metrics.inc(
+            "txpool_admit_store_seconds_total", time.perf_counter() - t0
+        )
+
+    def barrier(self) -> None:
+        """Return once the row of every admission so far is durable. With
+        no ticket pending it returns at once, without a call into the KV.
+        Safe from any thread: the node's loop in front of a frame, an
+        executor thread in front of an RPC answer."""
+        if self._ticket is None:
+            return
+        with self._ticket_lock:
+            ticket, submitted = self._ticket, self._rows_submitted
+            rows = submitted - self._rows_durable
+        if ticket is None:
+            return
+        t0 = time.perf_counter()
+        with tracing.span("pool.barrier", "pool", rows=rows):
+            # forgotten only once durable: a barrier that raises (a failed
+            # WAL) leaves the ticket, so no later frame or answer passes on
+            # an empty one
+            self._kv.write_barrier(ticket)
+        with self._ticket_lock:
+            if self._ticket == ticket:
+                self._ticket = None
+            self._rows_durable = max(self._rows_durable, submitted)
+        metrics.inc("txpool_admit_waits_total")
+        metrics.inc(
+            "txpool_admit_store_seconds_total", time.perf_counter() - t0
+        )
+
+    def rows_pending(self) -> bool:
+        """Whether barrier() would wait: an admission submitted its row and
+        nothing has waited for it since."""
+        return self._ticket is not None
+
+    def frame_barrier(self) -> Callable[[], None]:
+        """Hand the wait to an owner that acknowledges admissions at a
+        boundary of its own: it runs the returned hook before every frame
+        and every answer leaves, and add() stops waiting itself — where the
+        store has a WAL to submit to; elsewhere add() keeps its synchronous
+        calls and the hook never finds a ticket."""
+        self._submit_rows = bool(
+            getattr(self._kv, "supports_async_batches", False)
+        )
+        return self.barrier
 
     # -- proposal --------------------------------------------------------------
     def next_nonce(self, sender: bytes) -> int:

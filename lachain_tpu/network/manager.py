@@ -444,7 +444,7 @@ class NetworkManager:
 
         async def deliver():
             # no worker stands between send_to and this socket, so the
-            # journal's barrier is taken here
+            # node's barrier (journal and pool) is taken here
             ok = durable_before_wire(self._barrier) and (
                 await self.hub.send_on_conn(conn_id, data)
             )

@@ -33,8 +33,11 @@ logger = logging.getLogger(__name__)
 def durable_before_wire(barrier: Optional[Callable[[], None]]) -> bool:
     """Persist-before-transmit, the frame's half (consensus/journal.py):
     the step in front of every write to a socket. `barrier` returns once
-    whatever the node journaled is durable; None means the owner journals
-    nothing, or waits inside its own record. False when the barrier raised
+    whatever the node submitted to its store's WAL is durable: the
+    journal's records of the payloads a frame carries, and the pool's rows
+    of the transactions it gossips or proposes (core/tx_pool.py). None
+    means the owner submits nothing, or waits inside its own record and
+    add. False when the barrier raised
     (a WAL that cannot fsync): the frame must not leave, and the caller
     treats it as a send that failed — keeps the messages and tries again."""
     if barrier is None:
@@ -42,7 +45,7 @@ def durable_before_wire(barrier: Optional[Callable[[], None]]) -> bool:
     try:
         barrier()
     except Exception:
-        logger.exception("journal barrier failed: frame held back")
+        logger.exception("durability barrier failed: frame held back")
         metrics.inc("network_barrier_failures_total")
         return False
     return True

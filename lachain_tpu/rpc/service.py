@@ -11,6 +11,7 @@ not RLP — the chain defines its own encoding (SURVEY.md §7 hard-part #2).
 """
 from __future__ import annotations
 
+import asyncio
 import binascii
 from typing import Any, Dict, List, Optional
 
@@ -183,14 +184,44 @@ class RpcService:
             "logs": self._logs_for_tx(rec.tx_hash),
         }
 
-    def eth_sendRawTransaction(self, raw):
+    def _after_pool_barrier(self, answer):
+        """The answer to a submission: it leaves after the pool's barrier
+        (core/tx_pool.py), so a client never holds the hash of a
+        transaction whose row a crash would lose. On a served node the
+        admission only submitted its row to the WAL; the wait for the
+        fsync is taken here, OFF the event loop (rpc/http.py awaits a
+        handler that returns a coroutine), so the node's one thread keeps
+        running consensus meanwhile and concurrent clients share a wait.
+        With no row pending (a store without an overlapping WAL, or a
+        frame's barrier came first) the answer is returned as it is; a
+        caller outside any loop waits in place."""
+        pool = self.node.pool
+        if not pool.rows_pending():
+            return answer
         try:
-            stx = SignedTransaction.decode(_bytes(raw))
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            pool.barrier()
+            return answer
+
+        async def durable_answer():
+            await loop.run_in_executor(None, pool.barrier)
+            return answer
+
+        return durable_answer()
+
+    @staticmethod
+    def _decode_raw(raw) -> SignedTransaction:
+        try:
+            return SignedTransaction.decode(_bytes(raw))
         except Exception:
             raise JsonRpcError(-32602, "undecodable transaction")
+
+    def eth_sendRawTransaction(self, raw):
+        stx = self._decode_raw(raw)
         if not self.node.submit_tx(stx):
             raise JsonRpcError(-32000, "transaction rejected by pool")
-        return _h(stx.hash())
+        return self._after_pool_barrier(_h(stx.hash()))
 
     def eth_getBalance(self, address, tag="latest"):
         return _hex(
@@ -798,13 +829,10 @@ class RpcService:
         stx = self._build_tx(tx)
         if not self.node.submit_tx(stx):
             raise JsonRpcError(-32000, "transaction rejected by pool")
-        return _h(stx.hash())
+        return self._after_pool_barrier(_h(stx.hash()))
 
     def eth_verifyRawTransaction(self, raw):
-        try:
-            stx = SignedTransaction.decode(_bytes(raw))
-        except Exception:
-            raise JsonRpcError(-32602, "undecodable transaction")
+        stx = self._decode_raw(raw)
         sender = stx.sender(self.node.chain_id)
         if sender is None:
             return {"valid": False, "reason": "bad signature"}
@@ -860,10 +888,14 @@ class RpcService:
         results = []
         for raw in raws:
             try:
-                results.append(self.eth_sendRawTransaction(raw))
+                stx = self._decode_raw(raw)
+                if not self.node.submit_tx(stx):
+                    raise JsonRpcError(-32000, "transaction rejected by pool")
+                results.append(_h(stx.hash()))
             except JsonRpcError as exc:
                 results.append({"error": exc.message})
-        return results
+        # one wait covers the batch: the newest row's ticket covers them all
+        return self._after_pool_barrier(results)
 
     def la_sendRawTransactionBatchParallel(self, raws):
         # ingest already batches ECDSA recovery across the whole batch
@@ -1433,7 +1465,7 @@ class RpcService:
         )
         if not self.node.submit_tx(stx):
             raise JsonRpcError(-32000, "transaction rejected by pool")
-        return {"transactionHash": _h(stx.hash())}
+        return self._after_pool_barrier({"transactionHash": _h(stx.hash())})
 
     # -- registry ------------------------------------------------------------
 

@@ -264,6 +264,91 @@ def test_barrier_before_frame_is_clean(tmp_path, capsys):
     assert rc == 0, out
 
 
+_POOL_SUBMITS = """
+    class TransactionPool:
+        def add(self, stx):
+            self._ticket = self._kv.write_batch_async([(stx.key, stx.row)])
+
+        def frame_barrier(self):
+            return self.barrier
+"""
+_POOL_WAITS = """
+    class TransactionPool:
+        def add(self, stx):
+            self._kv.put(stx.key, stx.row)
+"""
+_NODE_TAKES_BOTH = """
+    class Node:
+        def _frame_barrier(self):
+            journal_barrier = self.journal.frame_barrier()
+            pool_barrier = self.pool.frame_barrier()
+            return lambda: (journal_barrier(), pool_barrier())
+"""
+_NODE_TAKES_THE_JOURNALS = """
+    class Node:
+        def _frame_barrier(self):
+            return self.journal.frame_barrier()
+"""
+_RPC_ANSWERS_AFTER = """
+    class RpcService:
+        def eth_sendRawTransaction(self, raw):
+            stx = decode(raw)
+            if not self.node.submit_tx(stx):
+                raise Rejected()
+            return self._after_pool_barrier(stx.hash())
+"""
+_RPC_ANSWERS_AT_ONCE = """
+    class RpcService:
+        def eth_sendRawTransaction(self, raw):
+            stx = decode(raw)
+            self._after_pool_barrier(None)
+            if not self.node.submit_tx(stx):
+                raise Rejected()
+            return stx.hash()
+
+        def eth_sendTransaction(self, tx):
+            self.node.submit_tx(self._build_tx(tx))
+"""
+
+
+@pytest.mark.parametrize(
+    "pool,node,rpc,flagged",
+    [
+        (_POOL_SUBMITS, _NODE_TAKES_BOTH, _RPC_ANSWERS_AFTER, []),
+        # a pool that waits inside add acknowledges nothing early: no pair
+        (_POOL_WAITS, _NODE_TAKES_THE_JOURNALS, _RPC_ANSWERS_AT_ONCE, []),
+        (
+            _POOL_SUBMITS, _NODE_TAKES_THE_JOURNALS, _RPC_ANSWERS_AFTER,
+            ["never takes pool.frame_barrier()"],
+        ),
+        (
+            _POOL_SUBMITS, _NODE_TAKES_BOTH, _RPC_ANSWERS_AT_ONCE,
+            [
+                "submit_tx(...) in eth_sendRawTransaction() is not followed",
+                "submit_tx(...) in eth_sendTransaction() is not followed",
+            ],
+        ),
+    ],
+    ids=["both-waits", "pool-waits-itself", "no-frame-wait", "no-answer-wait"],
+)
+def test_a_submitted_pool_row_needs_the_frame_and_the_answer(
+    tmp_path, capsys, pool, node, rpc, flagged
+):
+    # rule P (3): once the pool submits a row without waiting, the node has
+    # to take the pool's barrier for its frames and every RPC submission
+    # has to answer through the barrier; a barrier BEFORE the submit, as
+    # one after a transport, protects nothing
+    rc, out, _ = run_lint(tmp_path, {
+        "core/tx_pool.py": pool,
+        "core/node.py": node,
+        "rpc/service.py": rpc,
+    }, capsys)
+    assert rc == (1 if flagged else 0), out
+    assert out.count("[persist-before-transmit]") == len(flagged)
+    for text in flagged:
+        assert text in out
+
+
 # -- rule L: lock order ------------------------------------------------------
 
 

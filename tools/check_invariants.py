@@ -51,6 +51,15 @@ P. **Persist-before-transmit** — a consensus payload is durable in the
    boundary), and then half (1) is the whole rule, as it was.
    Dominance is approximated as "the dominating call appears on an
    earlier line of the same function body".
+   (3) the pool's admitted rows ride the same pair. Once
+   `core/tx_pool.py` submits a row without waiting (`write_batch_async`),
+   the wait has to stand at both places where an admission is
+   acknowledged: `core/node.py` must take `<...>.pool.frame_barrier()` (what
+   it hands the network beside the journal's, so half (2) covers a frame
+   that carries or follows the transaction), and under `rpc/` every
+   function that calls `submit_tx(...)` must call `_after_pool_barrier(...)`
+   on a later line (the answer to the client). A submit with neither is a
+   later flush, not a group commit.
 
 E. **Evidence durability** — the Byzantine-evidence counters
    (`consensus_equivocations_total`, `consensus_invalid_shares_total`) may
@@ -142,6 +151,15 @@ JOURNAL_CALLEES = ("_durable_send", "_native_send", "record")
 FRAME_PACKAGE = "network/"
 FRAME_TRANSPORT_CALLEES = ("_transport", "send_raw", "send_on_conn")
 FRAME_BARRIER_CALLEES = ("durable_before_wire",)
+# rule P, the pool's rows: the submit that needs both waits, where the node
+# takes the pool's barrier for its frames, and the step after an RPC submit
+POOL_MODULE = "core/tx_pool.py"
+POOL_SUBMIT_CALLEE = "write_batch_async"
+POOL_OWNER_MODULE = "core/node.py"
+POOL_BARRIER_TAKER = "frame_barrier"
+POOL_ANSWER_PACKAGE = "rpc/"
+POOL_INGRESS_CALLEE = "submit_tx"
+POOL_ANSWER_CALLEE = "_after_pool_barrier"
 # functions allowed to transport without journaling, and why. Keyed by
 # function name within lachain_tpu/consensus/.
 TRANSMIT_WHITELIST = {
@@ -698,6 +716,74 @@ def check_persist_before_transmit(
     return out
 
 
+def _calls(node: ast.AST):
+    """(call node, callee's last name) for every call under `node`."""
+    for sub in ast.walk(node):
+        if isinstance(sub, ast.Call):
+            if isinstance(sub.func, ast.Attribute):
+                yield sub, sub.func.attr
+            elif isinstance(sub.func, ast.Name):
+                yield sub, sub.func.id
+
+
+def check_pool_rows_before_acknowledgement(
+    parsed: List[Tuple[str, str, ast.Module, List[str]]],
+) -> List[Violation]:
+    """Rule P (3): a `write_batch_async` in the pool needs the pool's
+    barrier in front of the node's frames and of the RPC answers."""
+    by_rel = {rel: (relpath, tree, lines) for relpath, rel, tree, lines in parsed}
+    if POOL_MODULE not in by_rel:
+        return []
+    relpath, tree, src_lines = by_rel[POOL_MODULE]
+    submits = [
+        call.lineno
+        for call, name in _calls(tree)
+        if name == POOL_SUBMIT_CALLEE
+        and not _line_allowed(src_lines, call.lineno, "persist-before-transmit")
+    ]
+    if not submits:
+        return []
+    out: List[Violation] = []
+    owner = by_rel.get(POOL_OWNER_MODULE)
+    taken = owner is not None and any(
+        name == POOL_BARRIER_TAKER
+        and (_dotted(call.func.value) or "").split(".")[-1] == "pool"
+        for call, name in _calls(owner[1])
+        if isinstance(call.func, ast.Attribute)
+    )
+    if not taken:
+        out.append(Violation(
+            relpath, submits[0], "persist-before-transmit",
+            f"the pool submits a row ({POOL_SUBMIT_CALLEE}) but "
+            f"{PACKAGE}/{POOL_OWNER_MODULE} never takes "
+            f"pool.{POOL_BARRIER_TAKER}(): no frame waits for it "
+            "(durable_before_wire)",
+        ))
+    for rel, (rpc_path, rpc_tree, rpc_lines) in sorted(by_rel.items()):
+        if not rel.startswith(POOL_ANSWER_PACKAGE):
+            continue
+        for fn in ast.walk(rpc_tree):
+            if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                continue
+            calls = list(_calls(fn))
+            answers = [
+                c.lineno for c, name in calls if name == POOL_ANSWER_CALLEE
+            ]
+            for call, name in calls:
+                if name != POOL_INGRESS_CALLEE or _line_allowed(
+                    rpc_lines, call.lineno, "persist-before-transmit"
+                ):
+                    continue
+                if not answers or max(answers) <= call.lineno:
+                    out.append(Violation(
+                        rpc_path, call.lineno, "persist-before-transmit",
+                        f"{POOL_INGRESS_CALLEE}(...) in {fn.name}() is not "
+                        f"followed by {POOL_ANSWER_CALLEE}(...): the answer "
+                        "can leave before the admitted row is durable",
+                    ))
+    return out
+
+
 # -- rule E: evidence durability ---------------------------------------------
 
 EVIDENCE_MODULE = "consensus/evidence.py"
@@ -988,6 +1074,7 @@ def run(root: str) -> int:
             violations += check_metric_names(relpath, tree, src_lines)
         lock_checker.analyze(relpath, tree, src_lines)
 
+    violations += check_pool_rows_before_acknowledgement(parsed)
     lock_checker.build_edges()
     violations += lock_checker.find_cycles()
     violations += check_one_benchmark(root)
