@@ -40,6 +40,8 @@ def coin_schedule(epoch: int):
 
 
 class BinaryAgreement(Protocol):
+    family = "ba"
+
     def __init__(self, pid: M.BinaryAgreementId, broadcaster: Broadcaster):
         super().__init__(pid, broadcaster)
         self._epoch = 0

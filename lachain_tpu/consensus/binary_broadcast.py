@@ -17,6 +17,8 @@ from .protocol import Broadcaster, Protocol
 
 
 class BinaryBroadcast(Protocol):
+    family = "ba"
+
     def __init__(self, pid: M.BinaryBroadcastId, broadcaster: Broadcaster):
         super().__init__(pid, broadcaster)
         self._bval_recv: Dict[bool, Set[int]] = {False: set(), True: set()}

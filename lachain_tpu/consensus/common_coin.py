@@ -19,6 +19,8 @@ from .protocol import Broadcaster, Protocol
 
 
 class CommonCoin(Protocol):
+    family = "coin"
+
     def __init__(
         self,
         pid: M.CoinId,

@@ -17,6 +17,8 @@ from .protocol import Broadcaster, Protocol
 
 
 class CommonSubset(Protocol):
+    family = "tpke"
+
     def __init__(self, pid: M.CommonSubsetId, broadcaster: Broadcaster):
         super().__init__(pid, broadcaster)
         self._rbc_results: Dict[int, bytes] = {}

@@ -29,6 +29,8 @@ from .protocol import Broadcaster, Protocol
 
 
 class HoneyBadger(Protocol):
+    family = "tpke"
+
     def __init__(
         self,
         pid: M.HoneyBadgerId,

@@ -132,7 +132,9 @@ class ConsensusJournal:
         """Append one send BEFORE it reaches the transport: durable on
         return, or — once frame_barrier() was taken — by the barrier in
         front of the frame that carries it."""
-        with tracing.span("journal.record", cat="journal", era=era):
+        with tracing.account("journal"), tracing.span(
+            "journal.record", cat="journal", era=era
+        ):
             seq = self._next_seq.get(era, 0)
             key = _PREFIX + write_u64(era) + write_u64(seq)
             value = write_i64(-1 if target is None else target) + write_bytes(
@@ -150,7 +152,9 @@ class ConsensusJournal:
         ticket pending it returns at once, without a call into the KV."""
         if self._ticket is None:
             return
-        with tracing.span("journal.barrier", cat="journal", era=self._ticket_era):
+        with tracing.account("journal"), tracing.span(
+            "journal.barrier", cat="journal", era=self._ticket_era
+        ):
             self._wait()
 
     def frame_barrier(self) -> Callable[[], None]:

@@ -76,7 +76,7 @@ OWN_ROOT = 4
 CROSSINGS_METRIC = "consensus_callback_crossings_total"
 # the engine's exclusive per-message dispatch time by protocol family
 # (TP_NAMES): callbacks into Python subtracted, no interval to put a span on
-DISPATCH_METRIC = "consensus_engine_dispatch_seconds_total"
+DISPATCH_METRIC = tracing.DISPATCH_METRIC  # one name, two engines write it
 
 _OPAQUE_CB = ctypes.CFUNCTYPE(
     None,

@@ -19,6 +19,7 @@ from __future__ import annotations
 import logging
 from typing import Any, Optional
 
+from ..utils import tracing
 from . import messages as M
 
 logger = logging.getLogger("lachain.consensus")
@@ -59,6 +60,13 @@ class Broadcaster:
 class Protocol:
     """Base class for consensus protocol instances."""
 
+    # Which label of consensus_engine_dispatch_seconds_total{family} a
+    # message handled by this class is charged to (tracing.DISPATCH_FAMILIES,
+    # the native engine's five). A class that names none lands in the loop's
+    # part `other`; every protocol of this package names one
+    # (tests/test_loop_ledger.py).
+    family = "other"
+
     def __init__(self, pid, broadcaster: Broadcaster):
         self.id = pid
         self.broadcaster = broadcaster
@@ -72,8 +80,6 @@ class Protocol:
         # costs more than most handlers at N=64 scale; retaining the raw
         # envelope would pin its payload for the protocol's lifetime)
         import time as _time
-
-        from ..utils import tracing
 
         self.started_at = _time.monotonic()
         self.last_activity = self.started_at
@@ -113,36 +119,41 @@ class Protocol:
             if isinstance(envelope, M.External)
             else None,
         )
-        try:
-            if isinstance(envelope, M.External):
-                self.handle_external(envelope.sender, envelope.payload)
-            elif isinstance(envelope, M.Request):
-                self._parent = envelope.from_id
-                if self._result_emitted:
-                    # completed before the parent asked (instance was created
-                    # by external traffic): replay the result to the parent
-                    self.broadcaster.internal_response(
-                        M.Result(
-                            from_id=self.id,
-                            to_id=self._parent,
-                            value=self.result,
+        # exclusive seconds by family: a protocol that answers its parent
+        # re-enters receive() beneath this scope, and the child's time is
+        # then the child's family's alone
+        with tracing.account(self.family):
+            try:
+                if isinstance(envelope, M.External):
+                    self.handle_external(envelope.sender, envelope.payload)
+                elif isinstance(envelope, M.Request):
+                    self._parent = envelope.from_id
+                    if self._result_emitted:
+                        # completed before the parent asked (instance was
+                        # created by external traffic): replay the result
+                        # to the parent
+                        self.broadcaster.internal_response(
+                            M.Result(
+                                from_id=self.id,
+                                to_id=self._parent,
+                                value=self.result,
+                            )
                         )
-                    )
+                    else:
+                        self.handle_input(envelope.input)
+                elif isinstance(envelope, M.Result):
+                    self.handle_child_result(envelope.from_id, envelope.value)
                 else:
-                    self.handle_input(envelope.input)
-            elif isinstance(envelope, M.Result):
-                self.handle_child_result(envelope.from_id, envelope.value)
-            else:
-                raise TypeError(f"bad envelope {type(envelope)}")
-        except Exception:
-            logger.exception("protocol %s terminated by exception", self.id)
-            self.terminated = True
-            self.close_span(outcome="exception")
+                    raise TypeError(f"bad envelope {type(envelope)}")
+            except Exception:
+                logger.exception("protocol %s terminated by exception", self.id)
+                self.terminated = True
+                self.close_span(outcome="exception")
 
     def close_span(self, outcome: str = "done") -> None:
         """Close this instance's lifetime span (idempotent) and record its
         duration in the per-protocol-type histogram."""
-        from ..utils import metrics, tracing
+        from ..utils import metrics
 
         tracing.end(self._span_id, outcome=outcome)
         if outcome == "done":

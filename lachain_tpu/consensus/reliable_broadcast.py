@@ -23,6 +23,8 @@ from .protocol import Broadcaster, Protocol
 
 
 class ReliableBroadcast(Protocol):
+    family = "rbc"
+
     def __init__(self, pid: M.ReliableBroadcastId, broadcaster: Broadcaster):
         super().__init__(pid, broadcaster)
         self._echo: Dict[bytes, Dict[int, Tuple[bytes, Tuple[bytes, ...]]]] = {}

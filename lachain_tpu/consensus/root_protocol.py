@@ -19,6 +19,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Set
 
 from ..crypto import ecdsa
+from ..utils import tracing
 from . import messages as M
 from .protocol import Broadcaster, Protocol
 
@@ -26,6 +27,8 @@ NONCE_AGREEMENT = -1  # dedicated coin slot for the block nonce
 
 
 class RootProtocol(Protocol):
+    family = "commit"
+
     def __init__(
         self,
         pid: M.RootProtocolId,
@@ -96,9 +99,12 @@ class RootProtocol(Protocol):
         txtrace.stamp_many(
             (stx.hash() for stx in txs), "decide", era=self.id.era
         )
-        self._header = self._producer.create_header(
-            self.id.era, txs, self._nonce
-        )
+        # part `exec`: ordering, execution and merkleization (span
+        # exec.block), so that family `commit` is this protocol without them
+        with tracing.account("exec"):
+            self._header = self._producer.create_header(
+                self.id.era, txs, self._nonce
+            )
         sig = ecdsa.sign_hash(self._priv, self._header.hash())
         self.broadcaster.broadcast(
             M.SignedHeaderMessage(
@@ -151,6 +157,9 @@ class RootProtocol(Protocol):
         multisig = MultiSig(
             signatures=tuple(sorted(self._signatures.items()))
         )
-        block = self._producer.produce_block(self._header, self._txs, multisig)
+        with tracing.account("exec"):  # execute_block, commit, pool eviction
+            block = self._producer.produce_block(
+                self._header, self._txs, multisig
+            )
         self._produced = True
         self.emit_result(block)

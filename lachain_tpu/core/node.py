@@ -27,6 +27,7 @@ from ..network.hub import PeerAddress
 from ..network.manager import NetworkManager
 from ..storage.kv import EntryPrefix, KVStore, MemoryKV, prefixed
 from ..storage.state import StateManager
+from ..utils import tracing
 from .block_manager import BlockManager
 from .block_producer import BlockProducer
 from .execution import TransactionExecuter
@@ -381,8 +382,6 @@ class Node:
                     continue
                 stalled = now - proto.last_activity
                 if stalled > stall_after:
-                    from ..utils import tracing
-
                     stage = proto.record_stall()
                     logger.warning(
                         "protocol %s stalled for %.0fs (alive %.0fs, "
@@ -442,8 +441,6 @@ class Node:
             # era complete on our side; quiet engine state is expected
             self._native_watch = (native_state, now, 0)
             return 0
-        from ..utils import tracing
-
         strikes += 1
         logger.warning(
             "native engine stalled for %.0fs in era %d (strike %d, "
@@ -532,8 +529,6 @@ class Node:
         idle_alerting = False
         if self.idle_alert_fraction is not None:
             try:
-                from ..utils import tracing
-
                 eras = tracing.era_report()["eras"][-3:]
                 walls = sum(e["wall_s"] for e in eras)
                 if walls > 0:
@@ -661,9 +656,12 @@ class Node:
         # this process alone. Whoever passes it on waits for the pool's
         # barrier first — the network in front of the gossip's frame
         # (_frame_barrier), the RPC service in front of its answer
-        ok = self.pool.add(stx)
+        with tracing.account("pool_admit"):
+            ok = self.pool.add(stx)
         if ok:
-            self.network.broadcast(wire.sync_pool_reply([stx]))
+            # encode once, enqueue on every peer's worker
+            with tracing.account("gossip_out"):
+                self.network.broadcast(wire.sync_pool_reply([stx]))
         return ok
 
     def _on_pool_txs(self, sender: bytes, txs: List[SignedTransaction]) -> None:
@@ -671,16 +669,17 @@ class Node:
         # ONLY for txs that pass the pool's cheap dedup/gas checks first,
         # deduped within the batch itself — a batch repeating one tx (or a
         # re-gossiped batch) must cost hash lookups, not ECDSA recoveries
-        seen = set()
-        fresh = []
-        for stx in txs:
-            h = stx.hash()
-            if h not in seen and self.pool.precheck(stx):
-                seen.add(h)
-                fresh.append(stx)
-        warm_sender_caches(fresh, self.chain_id)
-        for stx in fresh:
-            self.pool.add(stx)
+        with tracing.account("pool_admit"):
+            seen = set()
+            fresh = []
+            for stx in txs:
+                h = stx.hash()
+                if h not in seen and self.pool.precheck(stx):
+                    seen.add(h)
+                    fresh.append(stx)
+            warm_sender_caches(fresh, self.chain_id)
+            for stx in fresh:
+                self.pool.add(stx)
 
     def _on_ping_request(self, sender: bytes, height: int) -> None:
         self.network.send_to(
@@ -879,12 +878,12 @@ class Node:
         total; timeout=None (the autonomous loop) waits indefinitely —
         sync supersession is the recovery path there.
         """
-        from ..utils import tracing
-
         router = self._ensure_router(era)
         self._era_done.clear()
         pid = M.RootProtocolId(era=era)
         sid = tracing.begin("era", era=era)
+        # the loop thread's ledger over this era: the span ends with it
+        ledger = tracing.ledger_begin()
         outcome = "aborted"
         try:
             router.internal_request(
@@ -942,6 +941,9 @@ class Node:
                 # WAN context on the era span: the fleet merger's
                 # era-latency-vs-RTT curve reads these two together
                 rtt_max_ms=round(self.network.rtt.max_srtt() * 1000.0, 1),
+                # what this thread did with the era, by part and by
+                # consensus family: together they sum to the span
+                **tracing.ledger_end(ledger),
             )
 
     async def run_eras(self, first: int, count: int) -> List[Block]:
@@ -983,8 +985,6 @@ class Node:
         )
 
     def _on_block_persisted(self, block: Block) -> None:
-        from ..utils import tracing
-
         tracing.instant(
             "block_persisted", cat="block", height=block.header.index
         )

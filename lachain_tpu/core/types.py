@@ -13,6 +13,7 @@ from typing import Optional, Sequence, Tuple
 
 from ..crypto import ecdsa
 from ..crypto.hashes import keccak256, merkle_root
+from ..utils import tracing
 from ..utils.serialization import (
     Reader,
     write_bytes,
@@ -125,8 +126,11 @@ class SignedTransaction:
         if addr is _MISS:
             addr = None
         elif addr is None:
-            pub = ecdsa.recover_hash(h, self.signature)
-            addr = None if pub is None else ecdsa.address_from_public_key(pub)
+            with tracing.account("ecdsa_recover"):  # both caches missed
+                pub = ecdsa.recover_hash(h, self.signature)
+                addr = (
+                    None if pub is None else ecdsa.address_from_public_key(pub)
+                )
             if len(_SENDER_MEMO) > 65536:
                 _SENDER_MEMO.clear()
             _SENDER_MEMO[key] = addr if addr is not None else _MISS
@@ -150,13 +154,14 @@ def warm_sender_caches(stxs, chain_id: int) -> None:
     ]
     if not pending:
         return
-    pubs = ecdsa.recover_hash_batch(
-        [stx.tx.signing_hash(chain_id) for stx in pending],
-        [stx.signature for stx in pending],
-    )
-    for stx, pub in zip(pending, pubs):
-        addr = None if pub is None else ecdsa.address_from_public_key(pub)
-        object.__setattr__(stx, "_sender_cache", (chain_id, addr))
+    with tracing.account("ecdsa_recover"):
+        pubs = ecdsa.recover_hash_batch(
+            [stx.tx.signing_hash(chain_id) for stx in pending],
+            [stx.signature for stx in pending],
+        )
+        for stx, pub in zip(pending, pubs):
+            addr = None if pub is None else ecdsa.address_from_public_key(pub)
+            object.__setattr__(stx, "_sender_cache", (chain_id, addr))
 
 
 def sign_transaction(

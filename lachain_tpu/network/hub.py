@@ -51,6 +51,15 @@ def _accepts_conn_id(cb: Callable) -> bool:
     return n_positional >= 2
 
 
+def _write_frame(writer: asyncio.StreamWriter, data: bytes) -> None:
+    """Hand one framed batch to the wire. The loop's part `frame_write` is
+    this synchronous write (the socket's send, or a copy into the
+    transport's buffer), never the drain() the caller awaits after it."""
+    with tracing.account("frame_write"):
+        writer.write(len(data).to_bytes(4, "big") + data)
+    metrics.inc("network_frames_total", labels={"dir": "out"})
+
+
 @dataclass(frozen=True)
 class PeerAddress:
     public_key: bytes  # 33-byte compressed ECDSA key (identity)
@@ -119,10 +128,10 @@ class Hub:
         """Shared frame loop for both directions (batches are
         connection-agnostic; identity lives in the batch signature)."""
         while True:
-            # the inter-frame gap IS this node's network receive wait:
-            # tag it so the era report's idle decomposition can claim it
-            with tracing.wait("net", conn=conn_id):
-                header = await reader.readexactly(4)
+            # no span here: each connection's reader waits while the thread
+            # works for the others. What the NODE waits for the network is
+            # its loop parked in select(), span era.net_idle
+            header = await reader.readexactly(4)
             n = int.from_bytes(header, "big")
             if n > MAX_FRAME:
                 raise ValueError("oversized frame")
@@ -206,7 +215,7 @@ class Hub:
         if writer is None:
             return False
         try:
-            writer.write(len(data).to_bytes(4, "big") + data)
+            _write_frame(writer, data)
             await writer.drain()
             return True
         except (ConnectionError, OSError):
@@ -259,7 +268,7 @@ class Hub:
                     except OSError:
                         return False
                 try:
-                    writer.write(len(data).to_bytes(4, "big") + data)
+                    _write_frame(writer, data)
                     await writer.drain()
                     return True
                 except (ConnectionError, OSError):

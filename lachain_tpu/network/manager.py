@@ -14,7 +14,7 @@ import logging
 import zlib
 from typing import Callable, Dict, List, Optional, Sequence
 
-from ..utils import metrics
+from ..utils import metrics, tracing
 from . import wire
 from .hub import Hub, PeerAddress
 from .rtt import RttTracker
@@ -588,14 +588,23 @@ class NetworkManager:
     # -- receiving ---------------------------------------------------------
 
     def _on_raw_batch(self, data: bytes, conn_id: Optional[int] = None) -> None:
+        # the loop's part `frame_in`: an inbound frame less its verification
+        # and whatever its handlers claim (a consensus family, pool_admit)
+        with tracing.account("frame_in"):
+            self._on_frame(data, conn_id)
+
+    def _on_frame(self, data: bytes, conn_id: Optional[int]) -> None:
         try:
             batch = MessageBatch.decode(data)
         except ValueError:
             logger.warning("undecodable batch dropped")
             return
-        if not batch.verify():
+        with tracing.account("frame_verify"):
+            verified = batch.verify()
+        if not verified:
             logger.warning("batch with bad signature dropped")
             return
+        metrics.inc("network_frames_total", labels={"dir": "in"})
         try:
             msgs = batch.messages()
         except (ValueError, zlib.error):
@@ -636,8 +645,6 @@ class NetworkManager:
         tid_hex = tid.hex()
         if tid_hex not in ids:
             ids.add(tid_hex)
-            from ..utils import tracing
-
             tracing.instant(
                 "wire.trace_ctx",
                 cat="net",

@@ -20,6 +20,7 @@ from ..consensus import messages as M
 from ..core.types import Block, SignedTransaction
 from ..crypto import ecdsa
 from ..crypto.hashes import keccak256
+from ..utils import tracing
 from ..utils.serialization import (
     Reader,
     write_bytes,
@@ -567,7 +568,8 @@ class MessageFactory:
                     + write_i64(era)
                     + era_trace_id(self.public_key, era)
                 )
-        sig = ecdsa.sign_hash(self._priv, keccak256(content))
+        with tracing.account("frame_sign"):  # one keccak, one ECDSA signature
+            sig = ecdsa.sign_hash(self._priv, keccak256(content))
         return MessageBatch(
             sender=self.public_key, signature=sig, content=content
         )

@@ -14,7 +14,7 @@ import zlib
 from collections import deque
 from typing import Callable, List, Optional
 
-from ..utils import metrics
+from ..utils import metrics, tracing
 from .hub import Hub, PeerAddress
 from .wire import MessageFactory, NetworkMessage, PRIORITY
 
@@ -85,6 +85,9 @@ class ClientWorker:
         self._task: Optional[asyncio.Task] = None
         self._stopped = False
         self._queued_bytes = 0
+        # when the oldest message not yet drained was enqueued (None: the
+        # queues were empty since): what a frame waited for its flush
+        self._waiting_since: Optional[float] = None
         self._backoff = flush_interval
         # WAN hint (manager/rtt): redial pacing should start near the
         # link's actual RTT — on a 300 ms link a flush-interval-paced
@@ -121,6 +124,8 @@ class ClientWorker:
         self._wakeup.set()
 
     def enqueue(self, msg: NetworkMessage) -> None:
+        if self._waiting_since is None:
+            self._waiting_since = metrics.monotonic()
         self._queues[PRIORITY[msg.kind]].append(msg)
         self._queued_bytes += len(msg.body) + 6
         # shed the least-important traffic (numerically largest priority,
@@ -202,7 +207,17 @@ class ClientWorker:
         journal, hand it to the wire (tools/check_invariants.py rule P
         holds the order). A barrier that fails is a send that failed: the
         caller requeues the batch and backs off."""
-        data = self._factory.batch(msgs).encode()
+        since, self._waiting_since = self._waiting_since, None
+        if since is not None:
+            # a wait, not thread time: the flush interval in front of a hop
+            # (a batch requeued after a failed send is not counted again)
+            metrics.inc(
+                "network_flush_wait_seconds_total", metrics.monotonic() - since
+            )
+        # part `frame_out`: encode, compress, trailer; the signature inside
+        # is `frame_sign`'s, and the awaits below stay outside any scope
+        with tracing.account("frame_out"):
+            data = self._factory.batch(msgs).encode()
         if not durable_before_wire(self._barrier):
             return False
         if self._transport is None:

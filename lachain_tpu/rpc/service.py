@@ -676,7 +676,10 @@ class RpcService:
         device/fsync/sched, from wait spans and native wait records) plus
         an idle_unattributed remainder, and carries a critical_path block
         — the longest blocking chain from era start to commit. The input
-        for deciding what to overlap when pipelining eras."""
+        for deciding what to overlap when pipelining eras. A served node's
+        eras also carry `loop_s` and `dispatch_s`: what its one thread did
+        with the era, by part and by consensus family (the `era` span's
+        ledger, utils/tracing.py ledger_end), summing to the span."""
         from ..utils import tracing
 
         return tracing.era_report()

@@ -19,7 +19,7 @@ import itertools
 import os
 import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
 from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Dict, List, Optional
 
@@ -205,6 +205,171 @@ def _wait(resource: str, args: dict):
             pass
 
 
+# -- the loop thread's time, exclusive by part --------------------------------
+#
+# Spans answer WHEN; for what runs a thousand times an era (a frame, a
+# consensus message, an admission) a span each costs more than it is worth.
+# The ledger answers HOW MUCH, by part, while an era runs on the thread
+# (ledger_begin .. ledger_end): account(part) charges the calling thread's
+# wall time to `part` and pauses the part open beneath it, the select()
+# wrapper of loop_idle() charges a parked loop to `idle`, and busy time no
+# scope claimed goes to `other`. So idle + other + the named parts IS the
+# thread's wall time over its eras, by construction. The sums reach the
+# metrics registry by difference (_fold), as LOOP_METRIC{part} and, for the
+# parts that are a consensus family, DISPATCH_METRIC{family}: the counter the
+# native engine writes (consensus/native_rt.py _fold_dispatch).
+LOOP_METRIC = "node_loop_seconds_total"
+DISPATCH_METRIC = "consensus_engine_dispatch_seconds_total"
+SCOPES_METRIC = "node_loop_scopes_total"
+DISPATCH_FAMILIES = ("rbc", "ba", "coin", "tpke", "commit")
+_FOLD_EVERY = 0.1  # seconds between folds at a park; an era's end always folds
+
+
+class _Ledger:
+    """One thread's sums. `mark` is when the part on top of `stack` was
+    last charged: every boundary charges `now - mark` to the top and moves
+    the mark, so no second is charged twice or to nobody. `sums` is `held`
+    while an era runs on the thread and `spilt` otherwise: a scope costs the
+    same either way, and what it times outside every era is nobody's."""
+
+    __slots__ = (
+        "sums", "held", "spilt", "stack", "mark", "eras", "scopes", "folded",
+        "folded_scopes", "folded_at",
+    )
+
+    def __init__(self):
+        self.held: Dict[str, float] = defaultdict(float)
+        self.spilt: Dict[str, float] = defaultdict(float)
+        self.sums = self.spilt
+        self.stack = ["other"]  # the base: busy time no scope claims
+        self.mark = time.monotonic()
+        self.eras = 0  # ledger_begin() calls not yet ended
+        self.scopes = 0
+        self.folded: Dict[str, float] = {}
+        self.folded_scopes = 0
+        self.folded_at = self.mark
+
+
+class _Ledgers(threading.local):
+    # a thread's first touch builds its own ledger: a scope on an RPC
+    # executor or the signer's reader never enters the loop thread's
+    def __init__(self):
+        self.own = _Ledger()
+
+
+_ledgers = _Ledgers()
+
+
+class _Account:
+    """The scope account() hands out; one shared object a part, the state
+    lives in the calling thread's ledger."""
+
+    __slots__ = ("part",)
+
+    def __init__(self, part: str):
+        self.part = part
+
+    def __enter__(self):
+        led = _ledgers.own
+        now = time.monotonic()
+        stack = led.stack
+        led.sums[stack[-1]] += now - led.mark
+        led.mark = now
+        stack.append(self.part)
+
+    def __exit__(self, *exc):
+        led = _ledgers.own
+        now = time.monotonic()
+        led.stack.pop()
+        led.sums[self.part] += now - led.mark
+        led.mark = now
+        led.scopes += 1
+
+
+_accounts: Dict[str, _Account] = {}
+
+
+def account(part: str):
+    """Scope that charges the calling thread's wall time to `part`, less
+    whatever nested scopes claim: a nested scope's seconds are never also
+    its parent's. Two clock reads, a push and a pop; no lock, no span, no
+    metrics call. Wrap SYNCHRONOUS stretches only: a scope held across an
+    `await` would bill every other callback the loop runs meanwhile to its
+    part (tests/test_loop_ledger.py reads the source for one). With the
+    recorder off (capacity 0) it is the null context and nothing moves."""
+    if not _done.maxlen:
+        return _OFF
+    scope = _accounts.get(part)
+    if scope is None:
+        scope = _accounts[part] = _Account(part)
+    return scope
+
+
+def _cut(led: _Ledger) -> float:
+    now = time.monotonic()
+    led.sums[led.stack[-1]] += now - led.mark
+    led.mark = now
+    return now
+
+
+def _fold(led: _Ledger, now: float) -> None:
+    """Add what the ledger gained since the last fold to the registry."""
+    from . import metrics
+
+    led.folded_at = now
+    folded = led.folded
+    for part, secs in led.held.items():
+        moved = secs - folded.get(part, 0.0)
+        if moved <= 0.0:
+            continue
+        folded[part] = secs
+        if part in DISPATCH_FAMILIES:
+            metrics.inc(DISPATCH_METRIC, moved, labels={"family": part})
+        else:
+            metrics.inc(LOOP_METRIC, moved, labels={"part": part})
+    if led.scopes > led.folded_scopes:
+        metrics.inc(SCOPES_METRIC, led.scopes - led.folded_scopes)
+        led.folded_scopes = led.scopes
+
+
+def ledger_begin() -> Optional[Dict[str, float]]:
+    """An era starts on the calling thread: from here to ledger_end() the
+    thread's time is held, by part. Returns the ledger as it stands, for
+    ledger_end(); None while the recorder is off. Eras of a fleet on one
+    loop overlap: the ledger holds until the last one ends."""
+    if not _done.maxlen:
+        return None
+    led = _ledgers.own
+    _cut(led)
+    led.eras += 1
+    led.sums = led.held
+    return dict(led.held)
+
+
+def ledger_end(began: Optional[Dict[str, float]]) -> Dict[str, Dict[str, float]]:
+    """The era ended: what the thread's ledger gained since ledger_begin(),
+    as arguments for the era's span — `loop_s` by part and `dispatch_s` by
+    consensus family, which together sum to the time between the two calls
+    — and everything folded into the registry."""
+    if began is None:
+        return {}
+    led = _ledgers.own
+    now = _cut(led)
+    led.eras = max(led.eras - 1, 0)
+    if not led.eras:
+        led.sums = led.spilt
+        led.spilt.clear()
+    _fold(led, now)
+    loop_s: Dict[str, float] = {}
+    dispatch_s: Dict[str, float] = {}
+    for part, secs in led.held.items():
+        moved = secs - began.get(part, 0.0)
+        if moved > 0.0:
+            into = dispatch_s if part in DISPATCH_FAMILIES else loop_s
+            into[part] = round(moved, 6)
+    return {"loop_s": loop_s, "dispatch_s": dispatch_s}
+
+
 # selector -> how many loop_idle() scopes are open on its loop
 _idle_scopes: Dict[Any, int] = {}
 
@@ -215,7 +380,9 @@ def loop_idle(name: str, cat: str = "wait", **args):
     asyncio loop is one span `name`: the stretches in which the loop's
     thread has nothing ready — no frame read, no handler, no timer due.
     One thread parks in one select at a time, so the spans never overlap
-    and their lengths add (what the per-connection wait.net cannot give).
+    and their lengths add (what a span a connection's read cannot give).
+    The same stretches are the ledger's part `idle` while an era runs on
+    the thread (ledger_begin), and each park is where the ledger folds.
     Scopes nest (a fleet of nodes on one loop): the outermost one's name
     and args are recorded. Loops without a selector (not asyncio's own)
     record nothing."""
@@ -227,14 +394,20 @@ def loop_idle(name: str, cat: str = "wait", **args):
         return
     if selector not in _idle_scopes:
         inner = selector.select
+        led = _ledgers.own  # the loop's thread installs and runs the wrapper
 
         def select(timeout=None):
             if timeout is not None and timeout <= 0:
                 return inner(timeout)  # a poll: work is ready
             sid = begin(name, cat, **args)
+            park = _cut(led)
+            if park - led.folded_at > _FOLD_EVERY:
+                _fold(led, park)
             try:
                 return inner(timeout)
             finally:
+                led.mark = wake = time.monotonic()
+                led.sums["idle"] += wake - park
                 end(sid)
 
         selector.select = select  # shadows the method on this instance
@@ -571,6 +744,10 @@ _PHASE_PRIORITY = {
 # Python span name -> phase. Parent/orchestrator spans (era, HoneyBadger,
 # CommonSubset, RootProtocol) are deliberately absent: their time is the
 # sum of their children plus idle, so attributing them would double count.
+# The protocol names are LIFETIME spans: for the Python engine the rbc / ba /
+# coin columns they fill are coverage (a lifetime is mostly waiting for
+# peers), and the work is the era's `dispatch_s` — exclusive seconds by
+# family, the meaning these columns have under the native engine.
 _SPAN_PHASE = {
     "consensus.propose": "propose",
     "ReliableBroadcast": "rbc",
@@ -775,6 +952,12 @@ def era_report(
     callbacks (`cross.<op>`, swept with them), and the engine's exclusive
     dispatch seconds by family (the `dispatch_s` of each `engine.pump`:
     what that call added to consensus_engine_dispatch_seconds_total).
+    A served node's `era` span carries the loop thread's ledger over the
+    era (Node.run_era, ledger_end): it is passed through as `loop_s` by
+    part and `dispatch_s` by family, which together sum to the span. They
+    ADD to the report: under the Python engine the rbc / ba / coin phase
+    columns are swept from protocol lifetimes and so are coverage, the
+    work is in `dispatch_s`.
     Idle = wall − attributed, clamped at 0, then
     DECOMPOSED into named wait buckets (waits_s, from wait.* spans and
     native wait records) plus an idle_unattributed remainder — the
@@ -790,12 +973,19 @@ def era_report(
 
     # era window = union over every node's "era" span for that era number
     windows: Dict[int, List[float]] = {}
+    # era -> (length, args) of its longest `era` span that carries a ledger:
+    # nodes of a fleet on one loop share the thread, so each of their spans
+    # holds the thread's split over its own stretch and they must not add
+    ledgers: Dict[int, tuple] = {}
     for d in spans:
         if d["name"] == "era" and d["args"].get("era") is not None:
             era = int(d["args"]["era"])
             w = windows.setdefault(era, [d["start"], d["end"]])
             w[0] = min(w[0], d["start"])
             w[1] = max(w[1], d["end"])
+            length = d["end"] - d["start"]
+            if "loop_s" in d["args"] and length > ledgers.get(era, (-1.0,))[0]:
+                ledgers[era] = (length, d["args"])
 
     per_era_iv: Dict[int, List[tuple]] = {e: [] for e in windows}
     dispatch: Dict[int, Dict[str, float]] = {}
@@ -834,9 +1024,15 @@ def era_report(
     # split (same rule as mesh.device above)
     wait_iv_all: List[tuple] = []
     for d in spans:
-        if d["cat"] == "wait" and d["end"] is not None:
+        if d["end"] is None:
+            continue
+        if d["cat"] == "wait":
             res = d["args"].get("resource") or "net"
             wait_iv_all.append((res, d["start"], d["end"]))
+        elif d["name"] == "era.net_idle":
+            # the loop parked in select() while the era waits (loop_idle):
+            # the node's one measure of waiting for the network
+            wait_iv_all.append(("net", d["start"], d["end"]))
 
     for ev in native:
         if ev.get("cat") == "native.wait":
@@ -953,6 +1149,9 @@ def era_report(
                 },
             }
         )
+        if era in ledgers:
+            for key in ("loop_s", "dispatch_s"):
+                eras[-1][key] = dict(ledgers[era][1].get(key) or {})
     # Byzantine pressure per era (evidence.py per-process registry): how
     # many NEW equivocation / invalid-share records this process minted
     # while the era ran — `trace --era-report` surfaces attack visibility
@@ -1004,8 +1203,23 @@ def era_report_table(report: Optional[dict] = None) -> str:
         "  ".join(cell.rjust(widths[i]) for i, cell in enumerate(row))
         for row in rows
     ]
-    lines.insert(1, "  ".join("-" * w for w in widths))
-    return "\n".join(lines)
+    lines[1:1] = ["  ".join("-" * w for w in widths)]
+    # second row of an era whose span carried the loop's ledger: the thread's
+    # seconds by part, then the engine's by family, largest first
+    out = lines[:2]
+    for line, ent in zip(lines[2:], report["eras"]):
+        out.append(line)
+        split = "  ".join(
+            f"{key}: " + " ".join(
+                f"{part}={secs:.3f}"
+                for part, secs in sorted(ent[key].items(), key=lambda kv: -kv[1])
+            )
+            for key in ("loop_s", "dispatch_s")
+            if ent.get(key)
+        )
+        if split:
+            out.append(" " * (widths[0] + 2) + split)
+    return "\n".join(out)
 
 
 def critical_path_table(report: Optional[dict] = None) -> str:
@@ -1063,3 +1277,4 @@ def reset_for_tests() -> None:
         _native_done = deque(maxlen=DEFAULT_CAPACITY)
         _native_sources.clear()
         _py_dropped = 0
+    _ledgers.own = _Ledger()
