@@ -5,6 +5,17 @@ Parity with the reference's proto layer
 tx-hashing rules (src/Lachain.Crypto/TransactionUtils.cs:1-107). Our wire
 format is the framework's fixed-width codec; hashes are keccak256 over the
 canonical encoding (chain-id mixed into the signing hash, EIP-155-style).
+
+Senders. A transaction's sender is recovered from its signature, and one
+routine does it for every caller, `_resolve_senders`: an answer is kept on
+the object (`_sender_cache`, per chain id) and process-wide
+(`_SENDER_MEMO`, by signing hash and signature, failures included), a miss
+of both goes to the native library in one call that returns addresses, and
+both are filled. `SignedTransaction.sender` (a list of one) and
+`warm_sender_caches` (a batch) are that routine, so whichever way a node
+learns a transaction (RPC, gossip, block sync) it recovers the signature
+once, also when the block that holds it is decoded anew from the agreed
+proposals and ordered in `create_header`.
 """
 from __future__ import annotations
 
@@ -13,7 +24,7 @@ from typing import Optional, Sequence, Tuple
 
 from ..crypto import ecdsa
 from ..crypto.hashes import keccak256, merkle_root
-from ..utils import tracing
+from ..utils import metrics, tracing
 from ..utils.serialization import (
     Reader,
     write_bytes,
@@ -67,10 +78,12 @@ class Transaction:
         return keccak256(self.encode() + write_u64(chain_id))
 
 
+# The process-wide half of the sender caches (_resolve_senders below):
 # (signing_hash, signature) -> recovered address; _MISS marks a signature
 # that failed recovery so invalid txs don't retry the recover either
 _MISS = object()
 _SENDER_MEMO: dict = {}
+_SENDER_MEMO_MAX = 65536  # entries; past it the memo is cleared whole
 
 
 @dataclass(frozen=True)
@@ -110,58 +123,89 @@ class SignedTransaction:
         return cached
 
     def sender(self, chain_id: int) -> Optional[bytes]:
-        """Recovered 20-byte sender address, or None if invalid. Cached
-        per-object AND process-wide: ordering, execution and the pool all
-        ask repeatedly, and in-process multi-validator harnesses decode
-        the same wire tx into per-validator objects — without the shared
-        memo each validator pays the ECDSA recovery again (reference
-        caches recoveries in TransactionManager's verify cache,
-        TransactionManager.cs:141-171)."""
+        """Recovered 20-byte sender address, or None if invalid. Ordering,
+        execution and the pool all ask repeatedly: an object that holds
+        its answer for this chain id returns it here, before any counter,
+        scope or lock; one that does not goes through _resolve_senders as
+        a list of one."""
         cached = self.__dict__.get("_sender_cache")
         if cached is not None and cached[0] == chain_id:
             return cached[1]
-        h = self.tx.signing_hash(chain_id)
-        key = (h, self.signature)
+        _resolve_senders((self,), chain_id)
+        return self.__dict__["_sender_cache"][1]
+
+
+def _resolve_senders(stxs, chain_id: int) -> None:
+    """THE sender resolver: `SignedTransaction.sender` and
+    `warm_sender_caches` are this routine and nothing else, so a signature
+    is recovered once a process however its transaction arrives. For each
+    transaction, in order:
+
+    1. the object's own `_sender_cache` (chain id, address): set here and
+       only here; an object that holds this chain id is skipped;
+    2. the process-wide `_SENDER_MEMO`, by (signing hash, signature), a
+       failed recovery included (`_MISS`). A block's transactions are
+       decoded anew from the agreed proposals, and an in-process devnet
+       decodes one wire transaction into an object a validator: new
+       objects, the same key. The memo is what makes a transaction a node
+       admitted by gossip (`warm_sender_caches`) cost no second recovery
+       when `create_header` orders its block (the reference keeps its
+       recoveries in TransactionManager's verify cache,
+       TransactionManager.cs:141-171);
+    3. one call for every key both missed, each key once:
+       `ecdsa.recover_address_batch`, which returns addresses, so no
+       recovered key is decompressed in Python for its keccak. The
+       `ecdsa_recover` part of the loop thread's ledger is this step
+       alone;
+    4. the memo (cleared whole once it holds more than
+       `_SENDER_MEMO_MAX` entries) and every waiting object's cache are
+       filled.
+
+    Counters, one `inc` a call at most each, none for a call step 1
+    answered whole: `txpool_sender_memo_hits_total` (objects step 2
+    answered) and `txpool_sender_recoveries_total` (keys handed to step
+    3). Recoveries over transactions committed is 1 in a healthy node."""
+    pending: dict = {}  # memo key -> the objects that wait for it
+    hits = 0
+    for stx in stxs:
+        cached = stx.__dict__.get("_sender_cache")
+        if cached is not None and cached[0] == chain_id:
+            continue
+        key = (stx.tx.signing_hash(chain_id), stx.signature)
         addr = _SENDER_MEMO.get(key)
-        if addr is _MISS:
-            addr = None
-        elif addr is None:
-            with tracing.account("ecdsa_recover"):  # both caches missed
-                pub = ecdsa.recover_hash(h, self.signature)
-                addr = (
-                    None if pub is None else ecdsa.address_from_public_key(pub)
-                )
-            if len(_SENDER_MEMO) > 65536:
-                _SENDER_MEMO.clear()
-            _SENDER_MEMO[key] = addr if addr is not None else _MISS
-        object.__setattr__(self, "_sender_cache", (chain_id, addr))
-        return addr
+        if addr is None:
+            pending.setdefault(key, []).append(stx)
+            continue
+        hits += 1
+        object.__setattr__(
+            stx, "_sender_cache", (chain_id, None if addr is _MISS else addr)
+        )
+    if hits:
+        metrics.inc("txpool_sender_memo_hits_total", hits)
+    if not pending:
+        return
+    keys = list(pending)
+    with tracing.account("ecdsa_recover"):  # both caches missed
+        addrs = ecdsa.recover_address_batch(
+            [h for h, _ in keys], [sig for _, sig in keys]
+        )
+    metrics.inc("txpool_sender_recoveries_total", len(keys))
+    if len(_SENDER_MEMO) > _SENDER_MEMO_MAX:
+        _SENDER_MEMO.clear()
+    for key, addr in zip(keys, addrs):
+        _SENDER_MEMO[key] = _MISS if addr is None else addr
+        for stx in pending[key]:
+            object.__setattr__(stx, "_sender_cache", (chain_id, addr))
 
 
 def warm_sender_caches(stxs, chain_id: int) -> None:
-    """Batch-recover senders for many transactions at once through the
-    native threaded entry (ecdsa.recover_hash_batch) and populate each
-    tx's sender cache — the pool/sync bulk-ingest fast path (role of the
-    reference's background TransactionVerifier,
-    Blockchain/Operations/TransactionVerifier.cs:23-72). Safe to call with
-    any mix: already-cached txs are skipped, invalid signatures cache a
-    None sender exactly like the scalar path."""
-    pending = [
-        stx
-        for stx in stxs
-        if (c := stx.__dict__.get("_sender_cache")) is None
-        or c[0] != chain_id
-    ]
-    if not pending:
-        return
-    with tracing.account("ecdsa_recover"):
-        pubs = ecdsa.recover_hash_batch(
-            [stx.tx.signing_hash(chain_id) for stx in pending],
-            [stx.signature for stx in pending],
-        )
-        for stx, pub in zip(pending, pubs):
-            addr = None if pub is None else ecdsa.address_from_public_key(pub)
-            object.__setattr__(stx, "_sender_cache", (chain_id, addr))
+    """Resolve many transactions' senders at once, so that the misses
+    share one threaded native call — the pool/sync bulk-ingest path (role
+    of the reference's background TransactionVerifier,
+    Blockchain/Operations/TransactionVerifier.cs:23-72): gossip admission
+    (`Node._on_pool_txs`), block sync, `la_sendRawTransactionBatch`, the
+    lane planner. Safe to call with any mix: see `_resolve_senders`."""
+    _resolve_senders(stxs, chain_id)
 
 
 def sign_transaction(

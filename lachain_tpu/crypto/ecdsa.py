@@ -16,6 +16,7 @@ selection) before exposing validator keys to co-located adversaries.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import hmac
 from typing import List, Optional, Sequence, Tuple
@@ -348,6 +349,38 @@ def _tpu_recover(hashes, sigs):
     return out
 
 
+@functools.cache
+def _batch_threads() -> int:
+    """Threads a batch entry offers the library: the host's cores, 16 at
+    most. Read once a process: os.cpu_count() reads a file, 36-80 us a
+    call on the chip's host, where a batch of one recovery costs 230."""
+    return min(_os_mod.cpu_count() or 1, 16)
+
+
+def _native_batch(entry, width, hashes, sigs, regular, nthreads):
+    """One threaded library call (lt_ec_recover_batch's signature) over the
+    items at `regular`, each a 32-byte hash and a 65-byte signature: for
+    each, in that order, its `width` bytes of answer or None."""
+    import ctypes as _ct
+
+    m = len(regular)
+    outs = _ct.create_string_buffer(width * m)
+    oks = _ct.create_string_buffer(m)
+    entry(
+        b"".join(hashes[i] for i in regular),
+        b"".join(sigs[i] for i in regular),
+        m,
+        nthreads or _batch_threads(),
+        outs,
+        oks,
+    )
+    raw, ok = outs.raw, oks.raw
+    return [
+        raw[width * pos : width * (pos + 1)] if ok[pos] == 1 else None
+        for pos in range(m)
+    ]
+
+
 @metrics.timed("crypto_ec_recover_batch")
 def recover_hash_batch(
     hashes: Sequence[bytes],
@@ -361,8 +394,6 @@ def recover_hash_batch(
     multi-core hosts; on this 1-core CI box the win is the amortized
     fixed-base G table + windowed multiplies (~2x vs round 2). Entries
     with non-standard lengths fall back to the scalar path."""
-    import os as _os
-
     n = len(hashes)
     if n != len(sigs):
         raise ValueError("hashes/sigs length mismatch")
@@ -389,22 +420,70 @@ def recover_hash_batch(
                 if i not in regular_set:
                     out[i] = recover_hash(hashes[i], sigs[i])
             return out
-    import ctypes as _ct
-
-    hb = b"".join(hashes[i] for i in regular)
-    sb = b"".join(sigs[i] for i in regular)
-    m = len(regular)
-    outs = _ct.create_string_buffer(33 * m)
-    oks = _ct.create_string_buffer(m)
-    nt = nthreads or min(_os.cpu_count() or 1, 16)
-    lib.lt_ec_recover_batch(hb, sb, m, nt, outs, oks)
-    for pos, i in enumerate(regular):
-        if oks.raw[pos] == 1:
-            out[i] = outs.raw[33 * pos : 33 * pos + 33]
+    for i, pub in zip(
+        regular,
+        _native_batch(
+            lib.lt_ec_recover_batch, 33, hashes, sigs, regular, nthreads
+        ),
+    ):
+        out[i] = pub
     regular_set = set(regular)
     for i in range(n):
         if i not in regular_set:
             out[i] = recover_hash(hashes[i], sigs[i])
+    return out
+
+
+def _address_of(pub: Optional[bytes]) -> Optional[bytes]:
+    return None if pub is None else address_from_public_key(pub)
+
+
+@metrics.timed("crypto_ec_recover_address_batch")
+def recover_address_batch(
+    hashes: Sequence[bytes],
+    sigs: Sequence[bytes],
+    nthreads: Optional[int] = None,
+) -> List[Optional[bytes]]:
+    """The signers' 20-byte addresses (None where a signature is invalid)
+    in ONE native call, lt_ec_recover_address_batch: the library hashes
+    the affine point its recovery already holds, so no key is compressed
+    only to be decompressed again (a Python modular square root, ~140 us)
+    for its keccak. What core/types.py resolves senders through; a batch
+    of one is the scalar path. Every route gives
+    address_from_public_key(_recover_hash_py(h, s)) or None, and the ones
+    without the native entry derive it exactly so, as before the entry
+    existed: no library (the oracle), an item of irregular length, and
+    recover_hash_batch's chip route at _TPU_RECOVER_MIN regular items,
+    which returns keys."""
+    n = len(hashes)
+    if n != len(sigs):
+        raise ValueError("hashes/sigs length mismatch")
+    lib = _native_lib()
+    regular = [
+        i
+        for i in range(n)
+        if len(hashes[i]) == 32 and len(sigs[i]) == 65
+    ]
+    if lib is None or not regular:
+        return [_address_of(recover_hash(h, s)) for h, s in zip(hashes, sigs)]
+    if len(regular) >= _TPU_RECOVER_MIN:
+        from .provider import device_platform
+
+        if device_platform() == "tpu":
+            return [_address_of(p) for p in recover_hash_batch(hashes, sigs)]
+    out: List[Optional[bytes]] = [None] * n
+    if len(regular) < n:
+        regular_set = set(regular)
+        for i in range(n):
+            if i not in regular_set:
+                out[i] = _address_of(recover_hash(hashes[i], sigs[i]))
+    for i, addr in zip(
+        regular,
+        _native_batch(
+            lib.lt_ec_recover_address_batch, 20, hashes, sigs, regular, nthreads
+        ),
+    ):
+        out[i] = addr
     return out
 
 

@@ -160,6 +160,73 @@ def test_recover_hash_batch_matches_scalar():
     assert got[4] is None
 
 
+def test_recover_address_batch_matches_the_oracle():
+    """The address entry (lt_ec_recover_address_batch) beside the key
+    entry: every item is the oracle's key's address, threaded and not,
+    with an invalid signature, one of malformed length and an empty
+    batch."""
+    import random
+
+    from lachain_tpu.crypto import ecdsa
+
+    rng = random.Random(12)
+    privs = [ecdsa.generate_private_key() for _ in range(40)]
+    hashes = [rng.randbytes(32) for _ in privs]
+    sigs = [ecdsa.sign_hash(p, h) for p, h in zip(privs, hashes)]
+    bad = bytearray(sigs[2])
+    bad[5] ^= 0xFF
+    sigs[2] = bytes(bad)
+    sigs[4] = sigs[4][:40]
+    sigs[7] = bytes(32) + sigs[7][32:]  # r = 0
+    sigs[9] = sigs[9][:64] + b"\x04"  # v out of range
+    want = []
+    for h, s in zip(hashes, sigs):
+        pub = ecdsa._recover_hash_py(h, s)
+        want.append(None if pub is None else ecdsa.address_from_public_key(pub))
+    assert [w is None for w in want[:10]].count(True) >= 3
+    assert want[0] == ecdsa.address_from_public_key(
+        ecdsa.public_key_bytes(privs[0])
+    )
+    for nthreads in (None, 1, 3):
+        assert ecdsa.recover_address_batch(hashes, sigs, nthreads) == want
+    # the key entry's answers, hashed, are the same senders
+    pubs = ecdsa.recover_hash_batch(hashes, sigs)
+    assert [
+        None if p is None else ecdsa.address_from_public_key(p) for p in pubs
+    ] == want
+    assert ecdsa.recover_address_batch([], []) == []
+    with pytest.raises(ValueError):
+        ecdsa.recover_address_batch(hashes, sigs[:-1])
+
+
+def test_recover_address_batch_on_the_chip_route(monkeypatch):
+    """At _TPU_RECOVER_MIN regular items on a chip the recovery is
+    recover_hash_batch's device route, which returns keys: the addresses
+    are derived from them, and irregular items still take the scalar
+    path."""
+    from lachain_tpu.crypto import ecdsa, provider
+
+    privs = [ecdsa.generate_private_key() for _ in range(5)]
+    hashes = [bytes([i]) * 32 for i in range(5)]
+    sigs = [ecdsa.sign_hash(p, h) for p, h in zip(privs, hashes)]
+    sigs[3] = sigs[3][:64]
+    want = ecdsa.recover_address_batch(hashes, sigs)
+    assert want[3] is None and None not in want[:3]
+    handed = []
+
+    def device(hs, ss):
+        handed.append(len(hs))
+        return [ecdsa.recover_hash(h, s) for h, s in zip(hs, ss)]
+
+    monkeypatch.setattr(ecdsa, "_TPU_RECOVER_MIN", 4)
+    monkeypatch.setattr(provider, "device_platform", lambda: "tpu")
+    monkeypatch.setattr(ecdsa, "_tpu_recover", device)
+    assert ecdsa.recover_address_batch(hashes, sigs) == want
+    assert handed == [4]
+    assert ecdsa.recover_address_batch(hashes[:3], sigs[:3]) == want[:3]
+    assert handed == [4]  # under the threshold: the native entry
+
+
 def test_warm_sender_caches():
     from lachain_tpu.core.types import (
         Transaction,
