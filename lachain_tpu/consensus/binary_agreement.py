@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Dict, FrozenSet, Optional
 
+from ..utils import metrics
 from . import messages as M
 from .protocol import Broadcaster, Protocol
 
@@ -117,6 +118,11 @@ class BinaryAgreement(Protocol):
             if b == coin and self._decided is None:
                 self._decided = b
                 self._decide_epoch = self._epoch
+                # rounds (a binary broadcast and a coin each) this instance
+                # took to decide: one where the votes agree with round 0's
+                # fixed coin (0: a slot nobody accepts), two for a slot
+                # everybody accepts, more where the votes were split
+                metrics.inc("consensus_ba_rounds_total", (self._epoch + 1) // 2)
                 self.emit_result(b)
         else:
             self._est = coin

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Dict
 
+from ..utils import metrics
 from . import messages as M
 from .protocol import Broadcaster, Protocol
 
@@ -72,4 +73,9 @@ class CommonSubset(Protocol):
         if any(j not in self._rbc_results for j in accepted):
             return  # BA said yes but the RBC value hasn't arrived yet
         self._done = True
+        if len(accepted) < self.n:
+            # slots decided 0: a proposer that was late, or gone
+            metrics.inc(
+                "consensus_acs_slots_rejected_total", self.n - len(accepted)
+            )
         self.emit_result({j: self._rbc_results[j] for j in sorted(accepted)})

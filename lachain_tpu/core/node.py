@@ -15,6 +15,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import time
+from contextlib import nullcontext
 from typing import Dict, List, Optional
 
 from ..consensus import messages as M
@@ -106,6 +107,16 @@ class Node:
         # parallel-merkleization knob (config execution.merkleWorkers):
         # rides the shared trie handle so every freeze/commit sees it
         self.state.trie.merkle_workers = merkle_workers
+        # a store that holds a chain already: this process is a restart,
+        # and times its way back into the committee (core/recovery.py).
+        # None on a fresh store, so a node that never went away pays nothing
+        from .recovery import RecoveryClock
+
+        self.recovery: Optional[RecoveryClock] = (
+            RecoveryClock(self.block_manager.current_height())
+            if self.block_manager.block_by_height(0) is not None
+            else None
+        )
         self.block_manager.build_genesis(
             dict(initial_balances or {}),
             chain_id,
@@ -117,7 +128,9 @@ class Node:
         # crash-restore: repopulate from the persisted pool repository (the
         # repository existed but was never replayed on open — a restart
         # silently lost every pending tx)
-        restored = self.pool.restore()
+        with self._recover_span("pool") as sid:
+            restored = self.pool.restore()
+            tracing.annotate(sid, restored=restored)
         if restored:
             logger.info("restored %d pooled txs from disk", restored)
         # durable consensus send journal (consensus/journal.py): recovery
@@ -199,6 +212,7 @@ class Node:
             public_keys,
             keys_provider=self.validator_manager.keys_for_era,
         )
+        self.synchronizer.recovery = self.recovery
         # validator index <-> transport identity
         self._pub_by_index: Dict[int, bytes] = {
             i: pk for i, pk in enumerate(public_keys.ecdsa_pub_keys)
@@ -300,8 +314,24 @@ class Node:
         if self.index >= 0:
             self._ensure_router(first_era)
             self._recover_journal()
+        if self.recovery is not None:
+            # listening: the peers' workers may dial this port again, and
+            # the clock is told of the first verified frame from each
+            peers = [
+                pk for pk in self.public_keys.ecdsa_pub_keys
+                if pk != self.network.public_key
+            ]
+            self.recovery.listening(len(peers))
+            self.network.watch_first_frames(peers, self.recovery.peer_seen)
         if start_synchronizer:
             self.start_services()
+
+    def _recover_span(self, step: str):
+        """`node.recover.<step>`: a span around one step of opening a
+        store that holds a chain; nothing on a fresh store."""
+        if self.recovery is None:
+            return nullcontext(0)
+        return tracing.span(f"node.recover.{step}", cat="recover")
 
     def _recover_journal(self) -> None:
         """Crash-recovery replay (journal.py docstring): prune entries for
@@ -311,14 +341,16 @@ class Node:
         transmitted here — no peer workers exist yet."""
         assert self.router is not None
         height = self.block_manager.current_height()
-        self.journal.prune_below(height + 1)
-        eras = set()
-        n = 0
-        for era, _seq, target, data in self.journal.entries():
-            self.router.rearm_sent(era, target, data)
-            eras.add(era)
-            n += 1
-        self._rejoin_eras = sorted(eras)
+        with self._recover_span("journal") as sid:
+            self.journal.prune_below(height + 1)
+            eras = set()
+            n = 0
+            for era, _seq, target, data in self.journal.entries():
+                self.router.rearm_sent(era, target, data)
+                eras.add(era)
+                n += 1
+            self._rejoin_eras = sorted(eras)
+            tracing.annotate(sid, rearmed=n, eras=len(eras))
         if n:
             logger.info(
                 "journal recovery: re-armed %d sends across eras %s",
@@ -945,6 +977,10 @@ class Node:
                 # consensus family: together they sum to the span
                 **tracing.ledger_end(ledger),
             )
+            if self.recovery is not None:
+                # a restarted node is back when it finishes an era as a
+                # member, not when a synced block supersedes one
+                self.recovery.era_finished(era, outcome)
 
     async def run_eras(self, first: int, count: int) -> List[Block]:
         return [await self.run_era(first + i) for i in range(count)]

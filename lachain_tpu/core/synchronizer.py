@@ -17,6 +17,7 @@ from ..consensus.keys import PublicConsensusKeys
 from ..crypto import ecdsa
 from ..network import wire
 from ..network.manager import NetworkManager
+from ..utils import metrics, tracing
 from .block_manager import BlockManager
 from .tx_pool import TransactionPool
 from .types import Block, SignedTransaction
@@ -91,6 +92,10 @@ class BlockSynchronizer:
         # replier, spins an unthrottled request/empty-reply hot loop).
         self.peer_cooldown = 4 * self.request_timeout
         self._benched: Dict[bytes, float] = {}
+        # a restarted node's clock (core/recovery.RecoveryClock), told of
+        # the first request and of every block applied; None on a node
+        # that never went away
+        self.recovery = None
         # wire handlers (the serving side lives here too)
         network.on_ping_reply = self._on_ping_reply
         network.on_sync_blocks_request = self._on_blocks_request
@@ -187,6 +192,9 @@ class BlockSynchronizer:
         self._request_peer = pub
         self._request_start = mine + 1
         self._request_time = asyncio.get_event_loop().time()
+        metrics.inc("sync_requests_total")
+        if self.recovery is not None:
+            self.recovery.sync_requested()
         self.network.send_to(pub, wire.sync_blocks_request(mine + 1, count))
 
     # -- serving -----------------------------------------------------------
@@ -211,6 +219,8 @@ class BlockSynchronizer:
             out.append((block, txs))
         # always reply, even with no blocks — the requester uses the reply to
         # clear its inflight flag; silence would otherwise wedge its sync
+        if out:
+            metrics.inc("sync_blocks_served_total", len(out))
         self.network.send_to(sender, wire.sync_blocks_reply(out))
 
     def _on_pool_request(self, sender: bytes, hashes: List[bytes]) -> None:
@@ -267,6 +277,16 @@ class BlockSynchronizer:
             return True  # already have it
         if block.header.index != mine + 1:
             return False  # gap; re-request from tip
+        # one span a synced block (its quorum check, its execution and
+        # commit, the pool's eviction), never one a transaction
+        with tracing.span(
+            "sync.apply", cat="sync", height=block.header.index, txs=len(txs)
+        ):
+            return self._apply(block, txs, mine)
+
+    def _apply(
+        self, block: Block, txs: List[SignedTransaction], mine: int
+    ) -> bool:
         prev = self.bm.block_by_height(mine)
         if prev is not None and block.header.prev_block_hash != prev.hash():
             logger.warning("synced block %d does not link", block.header.index)
@@ -289,6 +309,9 @@ class BlockSynchronizer:
             logger.exception("synced block %d failed execution", block.header.index)
             return False
         self.pool.remove_included(block.tx_hashes)
+        metrics.inc("sync_blocks_applied_total")
+        if self.recovery is not None:
+            self.recovery.block_synced(len(txs))
         return True
 
     async def wait_for_height(self, height: int, timeout: float = 60.0) -> None:

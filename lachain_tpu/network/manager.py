@@ -55,6 +55,10 @@ class NetworkManager:
         # unrecoverable for the slot)
         self._undelivered: Dict[bytes, List[NetworkMessage]] = {}
         self._undelivered_cap = 2048
+        # peers a restarted node has not heard from yet (watch_first_frames);
+        # empty on every other node, so a frame pays one falsy test
+        self._unseen: set = set()
+        self._on_peer_seen: Optional[Callable[[int], None]] = None
         # trace-context trailers observed on verified inbound batches:
         # era -> {trace id hex}. Bounded to the newest _TRACE_ERA_KEEP
         # eras — the fleet merger only correlates recent eras, and a
@@ -362,6 +366,16 @@ class NetworkManager:
     def peers(self) -> List[bytes]:
         return list(self._workers.keys())
 
+    def watch_first_frames(
+        self, public_keys: List[bytes], on_seen: Callable[[int], None]
+    ) -> None:
+        """Call `on_seen(k)` at the first verified frame from each of
+        `public_keys`, k = how many of them are still silent then. What a
+        restarted node times its reconnection with (core/recovery.py): a
+        frame from a peer means that peer's worker has dialed it again."""
+        self._unseen = set(public_keys)
+        self._on_peer_seen = on_seen
+
     # -- sending -----------------------------------------------------------
 
     def wire_version_of(self, public_key: bytes) -> Optional[int]:
@@ -605,6 +619,9 @@ class NetworkManager:
             logger.warning("batch with bad signature dropped")
             return
         metrics.inc("network_frames_total", labels={"dir": "in"})
+        if self._unseen and batch.sender in self._unseen:
+            self._unseen.discard(batch.sender)
+            self._on_peer_seen(len(self._unseen))
         try:
             msgs = batch.messages()
         except (ValueError, zlib.error):

@@ -82,6 +82,10 @@ class ClientWorker:
         # message once a dead peer's queue hit the cap
         self._queues = {p: deque() for p in sorted(set(PRIORITY.values()))}
         self._wakeup = asyncio.Event()
+        # set by reset_backoff(): cuts a backoff sleep short (the wakeup
+        # above must not — a dead peer's queue passes a batch's worth at
+        # once and would turn the backoff into a redial loop)
+        self._retry_now = asyncio.Event()
         self._task: Optional[asyncio.Task] = None
         self._stopped = False
         self._queued_bytes = 0
@@ -121,6 +125,7 @@ class ClientWorker:
         (the queued/undelivered buffer drains on the first successful
         flush) instead of sleeping out the current backoff window."""
         self._backoff = self._flush_interval
+        self._retry_now.set()
         self._wakeup.set()
 
     def enqueue(self, msg: NetworkMessage) -> None:
@@ -179,6 +184,10 @@ class ClientWorker:
                 ok = await self._transmit(msgs)
                 if ok:
                     self._backoff = self._flush_interval
+                    if self.consecutive_failures:
+                        # the first frame through after failed dials: the
+                        # peer was gone (or unreachable) and is back
+                        metrics.inc("network_peer_reconnects_total")
                     self.consecutive_failures = 0
                 else:
                     # peer unreachable: requeue and back off EXPONENTIALLY
@@ -193,10 +202,21 @@ class ClientWorker:
                         self._queues[PRIORITY[m.kind]].appendleft(m)
                         self._queued_bytes += len(m.body) + 6
                     pause = max(self._backoff, self.backoff_floor)
-                    await asyncio.sleep(
-                        pause * (0.75 + 0.5 * self._jitter.random())
+                    began = metrics.monotonic()
+                    try:
+                        await asyncio.wait_for(
+                            self._retry_now.wait(),
+                            timeout=pause * (0.75 + 0.5 * self._jitter.random()),
+                        )
+                    except asyncio.TimeoutError:
+                        self._backoff = min(pause * 2, BACKOFF_MAX)
+                    self._retry_now.clear()
+                    # seconds this worker sat out between dials of a peer
+                    # it could not reach (a wait, not thread time)
+                    metrics.inc(
+                        "network_backoff_seconds_total",
+                        metrics.monotonic() - began,
                     )
-                    self._backoff = min(pause * 2, BACKOFF_MAX)
                     break
         # final flush on stop
         if self._pending():

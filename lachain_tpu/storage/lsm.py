@@ -57,6 +57,8 @@ _STAT_FIELDS = (
     "imm_memtables",
     "compact_backlog",  # tables beyond the compaction trigger point
     "trace_dropped",    # flight-recorder ring evictions
+    "wal_replayed",     # records open() replayed from the WAL segments
+    "wal_torn_bytes",   # torn tail open() cut off the active segment
 )
 
 # lsm.cpp trace record contract: 32-byte big-endian records, same frame as
@@ -186,19 +188,32 @@ class LsmKV(KVStore):
         compact_tables: int = 0,
         compact_rate_mbps: int = 0,
     ):
+        from ..utils import tracing
+
         self._lib = _load_lib()
         self._lock = threading.Lock()
-        self._h = self._lib.lsm_open2(
-            path.encode(), flush_threshold, cache_bytes,
-            compact_tables, compact_rate_mbps,
-        )
-        if not self._h:
-            raise IOError(f"cannot open LSM store at {path!r}")
+        # the engine's open: manifest, orphan sweep, WAL replay into the
+        # memtable (after a kill -9, everything since the last flush) and
+        # the cut of a torn tail; one span an open, what a restart pays
+        # before anything can read the store
+        with tracing.span(
+            "lsm.open", cat="storage", store=os.path.basename(path) or path
+        ) as sid:
+            self._h = self._lib.lsm_open2(
+                path.encode(), flush_threshold, cache_bytes,
+                compact_tables, compact_rate_mbps,
+            )
+            if not self._h:
+                raise IOError(f"cannot open LSM store at {path!r}")
+            found = self._read_stats()
+            self.opened_with = {
+                "wal_records": found["wal_replayed"],
+                "repaired": found["wal_torn_bytes"],
+            }
+            tracing.annotate(sid, **self.opened_with)
         # flight recorder: size the engine ring, align its clock, register
         # with the merged tracer (own pid per store; engine thread roles
         # become named rows in the Chrome export)
-        from ..utils import tracing
-
         self._trace_offset = tracing.clock_offset(self._lib.lsm_monotonic_ns)
         self._trace_dropped_seen = 0
         self._trace_pid = next(_next_trace_pid)
@@ -491,11 +506,14 @@ class LsmKV(KVStore):
         """Engine counters snapshot; publishes the read-path gauges
         (lsm_bloom_hits/misses, lsm_cache_hit_ratio, ...) as a side
         effect so an RPC metrics scrape after a commit sees them."""
-        arr = (ctypes.c_uint64 * len(_STAT_FIELDS))()
-        self._lib.lsm_stats(self._h, arr, len(_STAT_FIELDS))
-        out = dict(zip(_STAT_FIELDS, (int(v) for v in arr)))
+        out = self._read_stats()
         self._publish_metrics(out)
         return out
+
+    def _read_stats(self) -> Dict[str, int]:
+        arr = (ctypes.c_uint64 * len(_STAT_FIELDS))()
+        self._lib.lsm_stats(self._h, arr, len(_STAT_FIELDS))
+        return dict(zip(_STAT_FIELDS, (int(v) for v in arr)))
 
     @staticmethod
     def _publish_metrics(stats: Dict[str, int]) -> None:

@@ -834,6 +834,10 @@ struct Stats {
   u64 wal_fsyncs = 0;
   u64 wal_records = 0;
   u64 compactions = 0;
+  // what open() found: records replayed from the WAL segments, and bytes
+  // of a torn tail it cut off the active one (0 after a clean close)
+  u64 wal_replayed = 0;
+  u64 wal_torn_bytes = 0;
 };
 
 struct Lsm {
@@ -1011,12 +1015,14 @@ struct Lsm {
         }
         mem->ingest(copy, ops);
         off += 8 + len;
+        stats.wal_replayed++;
       }
       if (off < buf.size()) {
         if (!is_last) {
           tables.clear();
           return false;
         }
+        stats.wal_torn_bytes += buf.size() - off;
         int tfd = ::open(path.c_str(), O_WRONLY);
         bool ok = tfd >= 0 && ::ftruncate(tfd, (off_t)off) == 0 &&
                   ::fsync(tfd) == 0;
@@ -1706,9 +1712,11 @@ struct Lsm {
   }
 
   void fill_stats(u64* out, int n) {
-    u64 v[12] = {0};
+    u64 v[14] = {0};
     {
       std::lock_guard<std::mutex> g(mu);
+      v[12] = stats.wal_replayed;
+      v[13] = stats.wal_torn_bytes;
       v[0] = stats.bloom_neg;
       v[1] = stats.bloom_pass;
       v[2] = stats.cache_hit;
@@ -1732,7 +1740,7 @@ struct Lsm {
       std::lock_guard<std::mutex> g(trace.mu);
       v[11] = trace.dropped;
     }
-    for (int i = 0; i < n && i < 12; i++) out[i] = v[i];
+    for (int i = 0; i < n && i < 14; i++) out[i] = v[i];
   }
 };
 

@@ -146,6 +146,20 @@ def instant(name: str, cat: str = "era", **args) -> None:
         _done.append(sp)
 
 
+def completed(name: str, start: float, end: float, cat: str = "era", **args) -> None:
+    """Record a span whose ends are known only in hindsight (a restart's
+    catch-up ends at the last block it synced, which it knows once it has
+    rejoined): `start` and `end` on time.monotonic()."""
+    if not _done.maxlen:
+        return
+    sp = _Span(next(_ids), name, cat, start, args)
+    sp.end = max(end, start)
+    with _lock:
+        if _done.maxlen is not None and len(_done) == _done.maxlen:
+            _count_drop()
+        _done.append(sp)
+
+
 def span(name: str, cat: str = "era", **args):
     """Scoped begin/end; yields the span id for annotate()."""
     return _span(name, cat, args) if _done.maxlen else _OFF
