@@ -75,8 +75,8 @@ class BlockManager:
         self.state = state
         self.executer = executer
         # execution.lanes knob: 1 pins the serial oracle (default), N>1
-        # fixes the lane count, 0 = auto (cores, capped). Results are
-        # bit-identical either way (core/parallel_exec.py).
+        # fixes the lane count, 0 = the program decides (resolve_lanes).
+        # Results are bit-identical either way (core/parallel_exec.py).
         self.lanes = max(int(lanes), 0)
         self.on_block_persisted = []  # callbacks(block)
 

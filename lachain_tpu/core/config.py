@@ -250,21 +250,27 @@ class NodeConfig:
     def execution_lanes(self) -> int:
         """Parallel-execution lane count (DEPLOY.md "Parallel execution").
         Optional and additive (no config version bump): 1 pins the serial
-        executor, N > 1 fixes the lane count, 0 (the default) sizes lanes
-        from the host's cores. Every setting produces bit-identical
-        blocks — the knob trades merge/validation overhead against core
-        utilization, never semantics."""
+        executor, N > 1 fixes the lane count, 0 (the default) lets the
+        program decide by block: it takes the lane pipeline only for a
+        block shape measured to win on threads, and none does (the
+        executor is Python), so today 0 runs every block serially. Every
+        setting produces bit-identical blocks — the knob trades
+        merge/validation overhead against core utilization, never
+        semantics."""
         return int(self.raw.get("execution", {}).get("lanes", 0))
 
     @property
     def merkle_workers(self) -> int:
         """Parallel-merkleization worker count (DEPLOY.md "Parallel
         merkleization"). Optional and additive (no config version bump):
-        1 pins the serial walker (deferred batch hashing stays on), N > 1
-        fixes the subtrie worker count (capped at the 16-way fanout), 0
-        (the default) sizes workers from the host's cores. Every setting
-        produces bit-identical state roots — the knob only trades thread
-        overhead against core utilization."""
+        1 pins the serial walker on one thread (deferred batch hashing
+        stays on), N > 1 fixes the subtrie worker count (capped at the
+        16-way fanout), 0 (the default) lets the program decide by batch:
+        one walker, since shard workers are Python and lost to it at every
+        size measured, with a tree level's hashing on the host's cores
+        only where the level carries enough bytes to pay for the threads.
+        Every setting produces bit-identical state roots — the knob only
+        trades thread overhead against core utilization."""
         return int(self.raw.get("execution", {}).get("merkleWorkers", 0))
 
     @property

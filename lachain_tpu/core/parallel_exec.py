@@ -44,15 +44,14 @@ in `tx.to` — plans one lane: it runs on the caller's thread, recorded and
 then validated by the merge, the pipeline's cost with nothing to overlap
 (largest lane = the block). The planner sees addresses, not storage keys.
 
-On a single hardware thread the lanes buy no wall-clock (pure-Python
-execution under the GIL); the win there comes from the delta-checkpoint
-snapshot and the commit-path work this PR removes. On multi-core hosts
-the lanes overlap trie reads, keccak hashing and wasm interpretation,
-which all release the GIL in their native sections.
+What the lanes can overlap is what drops the GIL: a store read, a keccak.
+The executor, the snapshot and the translated VM tier are Python and hold
+it, so on the hosts measured the pipeline costs more than it overlaps in
+every block shape (resolve_lanes has the reading): a setting of 0 runs the
+serial executor, and the pipeline runs where a lane count N > 1 is fixed.
 """
 from __future__ import annotations
 
-import os
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -62,20 +61,25 @@ from ..utils import metrics, tracing
 from .execution import TransactionExecuter
 from .types import SignedTransaction, TransactionReceipt, warm_sender_caches
 
-# lanes=0 in config means "auto": one lane per core, clamped — beyond 8
-# lanes the merge walk and fork setup outweigh extra overlap
-_AUTO_LANE_CAP = 8
-# blocks smaller than this execute serially even when lanes are enabled:
+# blocks smaller than this execute serially even when a lane count is fixed:
 # fork + merge overhead beats any overlap win on tiny blocks
 MIN_PARALLEL_TXS = 32
 
 
 def resolve_lanes(configured: int) -> int:
-    """Map the execution.lanes knob to an effective lane count:
-    1 pins serial, N>1 is explicit, 0 = auto (cores, capped)."""
-    if configured >= 1:
-        return configured
-    return max(1, min(_AUTO_LANE_CAP, os.cpu_count() or 1))
+    """Map the execution.lanes knob to an effective lane count: 1 pins the
+    serial executor, N > 1 fixes the lane count, 0 = the program decides.
+
+    What it decides, PR 39: the serial executor, for every block. The lanes
+    overlap only what drops the GIL (a store read, a keccak); the executor
+    and the translated VM tier are Python and hold it. On the chip's host
+    (13 cores) 8 lanes lost to the serial executor in every shape measured,
+    transfers and contract calls, from one lane holding the whole block
+    down to eight equal lanes of an eighth each, alone on the host and with
+    seven validators executing at once (PERF.md section 6, PR 39, has the
+    table). A shape that wins there would be chosen here, from the plan's
+    largest lane; none does, so there is no such rule to keep."""
+    return max(configured, 1)
 
 
 class RecordingSnapshot(Snapshot):
@@ -266,8 +270,12 @@ def execute_block_parallel(
         ]
     largest = max((len(l) for l in lanes), default=0)
 
+    forks = []
+
     def run_lane(lane: List[Tuple[int, SignedTransaction]]):
-        snap = RecordingSnapshot(state.trie.fork(), base_roots)
+        fork = state.trie.fork()
+        forks.append(fork)
+        snap = RecordingSnapshot(fork, base_roots)
         out = []
         for gi, stx in lane:
             snap.begin_tx()
@@ -289,6 +297,10 @@ def execute_block_parallel(
                 max_workers=len(lanes), thread_name_prefix="exec-lane"
             ) as pool:
                 lane_results = list(pool.map(run_lane, lanes))
+        # what the lanes read from the store the merge and the freeze read
+        # again on the main trie: keep the decoded nodes
+        for fork in forks:
+            state.trie.absorb_cache(fork)
 
     by_index: Dict[int, tuple] = {}
     for lane_out in lane_results:

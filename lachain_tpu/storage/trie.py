@@ -15,6 +15,7 @@ makes rollback O(1). An LRU node cache fills the role of TrieHashMap's cache.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from collections import OrderedDict
@@ -23,6 +24,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from ..crypto.hashes import keccak256, keccak256_batch
+from ..utils import metrics
 from ..utils.serialization import Reader, write_bytes, write_u16
 from .kv import EntryPrefix, KVStore, prefixed
 
@@ -32,19 +34,31 @@ _NIBBLES = 64  # keccak256 -> 64 nibbles
 # batch-size floors for the two merkleization fast paths: below them the
 # bookkeeping costs more than the per-node keccak dispatch it saves
 MIN_DEFER_OPS = 32    # deferred level-batched hashing
-MIN_SHARD_OPS = 512   # subtrie-sharded workers
+MIN_SHARD_OPS = 512   # subtrie-sharded workers, where a worker count is fixed
+# a level's encodings go to native hashing threads only from this many
+# bytes on; below it the threads' start costs more than they hash
+MIN_HASH_THREAD_BYTES = 3 << 19
 
 _KECCAK_BATCH_BUCKETS = (16, 64, 256, 1024, 4096, 16384, 65536)
 
 
+@functools.cache
+def _host_cores() -> int:
+    """The host's cores, 16 at most (the subtrie fanout). Read once a
+    process: os.cpu_count() reads a file, 36-80 us a call on the chip's
+    host, and a freeze asks once a subtree."""
+    return min(os.cpu_count() or 1, 16)
+
+
 def resolve_merkle_workers(n: int) -> int:
-    """Merkle worker knob -> effective count: 0 = auto (host cores, capped
-    at the 16-way subtrie fanout), N pins it. 1 disables sharding but
-    keeps deferred batch hashing (the single-core win)."""
+    """Merkle worker knob -> the threads a batch may use: N pins it (capped
+    at the 16-way subtrie fanout), 0 = the program decides, from the host's
+    cores. 1 disables sharding and hashes on one thread but keeps deferred
+    batch hashing (the single-core win)."""
     n = int(n)
     if n > 0:
         return min(n, 16)
-    return min(os.cpu_count() or 1, 16)
+    return _host_cores()
 
 
 def _nibble(h: bytes, depth: int) -> int:
@@ -187,7 +201,8 @@ class Trie:
         self._read_pending: Optional[Dict[bytes, bytes]] = None
         # armed deferred-hash sink (apply_many bulk paths only)
         self._defer: Optional[_DeferredHasher] = None
-        # merkle worker knob (config execution.merkleWorkers): 0 = auto
+        # merkle worker knob (config execution.merkleWorkers): 0 = the
+        # program decides (apply_many)
         self.merkle_workers: int = 0
         # accumulated apply_many profile (reset_merkle_stats() to zero),
         # for the commit-phase bench breakdown
@@ -282,6 +297,14 @@ class Trie:
         t._read_pending = self._pending
         return t
 
+    def absorb_cache(self, fork: "Trie") -> None:
+        """Adopt the node cache of a fork that has finished (a shard worker,
+        an execution lane): nodes are content-addressed, so whatever a fork
+        decoded from the store or stored itself is valid here too. Only
+        while no other fork still peeks at this cache (_load)."""
+        self._cache.update(fork._cache)
+        self._trim_cache()
+
     def clear_cache(self) -> None:
         self._cache.clear()
 
@@ -289,6 +312,13 @@ class Trie:
         self._cache[h] = node
         if len(self._cache) > self._cache_size:
             self._cache.popitem(last=False)
+
+    def _trim_cache(self) -> None:
+        """One bulk trim after a bulk update, instead of per-put LRU churn
+        (recency inside one batch is meaningless anyway)."""
+        cache = self._cache
+        while len(cache) > self._cache_size:
+            cache.popitem(last=False)
 
     # -- public api ----------------------------------------------------------
     def get(self, root: bytes, key: bytes) -> Optional[bytes]:
@@ -379,13 +409,19 @@ class Trie:
         Large batches take one of two fast paths, both exact:
           * deferred batch hashing (>= MIN_DEFER_OPS): nodes are encoded
             level-by-level bottom-up and each level is hashed in one
-            threaded native keccak call;
-          * subtrie sharding (>= MIN_SHARD_OPS and workers > 1): the op
-            batch splits by top-level nibble, each subtrie runs on a
-            worker over a _shard_fork() handle, and the root is assembled
-            from the 16 child hashes on the caller thread.
+            native keccak call, on threads from MIN_HASH_THREAD_BYTES a
+            level on;
+          * subtrie sharding (>= MIN_SHARD_OPS and a worker count N > 1
+            fixed by the caller): the op batch splits by top-level nibble,
+            each subtrie runs on a worker over a _shard_fork() handle, and
+            the root is assembled from the 16 child hashes on the caller
+            thread.
 
-        `workers` overrides the handle's merkle_workers knob (0 = auto).
+        `workers` overrides the handle's merkle_workers knob. 0 = the
+        program decides: one walker whatever the batch's size, since on the
+        chip's host shard workers (Python walkers under the GIL) lost to it
+        at every size measured, 700 to 100,000 keys a subtree (PERF.md
+        section 6, PR 39); only the level hashing goes to the host's cores.
         `stream`, when given, receives each completed subtrie's NEW
         (key, encoding) node items as workers finish — the fsync-overlap
         seam StateManager.freeze_and_commit plugs the WAL into."""
@@ -395,11 +431,10 @@ class Trie:
             keccak256(k): v for k, v in writes.items()
         }
         ops = sorted(entries.items())
-        nworkers = resolve_merkle_workers(
-            self.merkle_workers if workers is None else workers
-        )
+        configured = int(self.merkle_workers if workers is None else workers)
+        nworkers = resolve_merkle_workers(configured)
         t0 = time.perf_counter()
-        if nworkers > 1 and len(ops) >= MIN_SHARD_OPS and root != EMPTY_ROOT:
+        if configured > 1 and len(ops) >= MIN_SHARD_OPS and root != EMPTY_ROOT:
             node = self._load(root)
             if isinstance(node, InternalNode):
                 return self._apply_sharded(
@@ -451,9 +486,10 @@ class Trie:
             resolved, hash_s, items = fork._resolve_deferred(defer, 1)
             if _DeferredHasher.is_token(sub):
                 sub = resolved[sub]
-            return nib, sub, items, hash_s
+            return nib, sub, items, hash_s, fork
 
         results: Dict[int, bytes] = {}
+        forks: List["Trie"] = []
         hash_s = 0.0
         hashed = 0
         with ThreadPoolExecutor(
@@ -472,13 +508,19 @@ class Trie:
                     pending_futs, return_when=FIRST_EXCEPTION
                 )
                 for fut in done:
-                    nib, sub, items, worker_hash_s = fut.result()
+                    nib, sub, items, worker_hash_s, fork = fut.result()
                     results[nib] = sub
+                    forks.append(fork)
                     self._pending.update(items)
                     hash_s += worker_hash_s
                     hashed += len(items)
                     if stream is not None and items:
                         stream(items)
+        # the workers are done, so nobody peeks at our cache any more: keep
+        # the nodes they wrote and read, as the serial walk keeps its own,
+        # or the next block reads back from the store what this one wrote
+        for fork in forks:
+            self.absorb_cache(fork)
         for nib in groups:
             children[nib] = results[nib]
         if children == list(node.children):
@@ -486,6 +528,7 @@ class Trie:
         else:
             out = self._collapse_or_store(children)
         self._set_merkle_stats(t0, hash_s, hashed, min(nworkers, len(groups)))
+        metrics.inc("trie_sharded_applies_total")
         return out
 
     def _resolve_deferred(
@@ -494,7 +537,9 @@ class Trie:
         """Hash a deferred sink's nodes level-by-level bottom-up through
         the native batch keccak, patching child tokens with the hashes of
         the level below. Returns (token -> hash, seconds spent hashing,
-        new (prefixed key, encoding) items stored).
+        new (prefixed key, encoding) items stored). A level goes to
+        `nthreads` native threads only where it carries MIN_HASH_THREAD_BYTES
+        of encodings; a smaller one is hashed on the calling thread.
 
         HOT PATH: ~one iteration per node per 10k-tx block commit. Token
         tests are inlined as `len(c) == 9` (real child refs are always 32
@@ -508,8 +553,6 @@ class Trie:
         trie_node = int(EntryPrefix.TRIE_NODE).to_bytes(2, "big")
         pending = self._pending
         cache = self._cache
-        from ..utils import metrics
-
         for tokens, bnodes in defer.buckets:
             patched: List[object] = []
             for n in bnodes:
@@ -528,8 +571,11 @@ class Trie:
                             break
                 patched.append(n)
             encs = [n.encode() for n in patched]
+            threads = nthreads
+            if threads != 1 and sum(map(len, encs)) < MIN_HASH_THREAD_BYTES:
+                threads = 1
             h0 = time.perf_counter()
-            hashes = keccak256_batch(encs, nthreads)
+            hashes = keccak256_batch(encs, threads)
             hash_s += time.perf_counter() - h0
             metrics.observe_hist(  # lint-allow: metric-name dimensionless batch-size distribution
                 "trie_keccak_batch_size",
@@ -543,11 +589,7 @@ class Trie:
             items.extend(pairs)
             resolved.update(zip(tokens, hashes))
             cache.update(zip(hashes, patched))
-        # one bulk trim instead of per-put LRU churn (_cache_put does a
-        # move_to_end + popitem dance per node; recency inside one batch
-        # is meaningless anyway)
-        while len(cache) > self._cache_size:
-            cache.popitem(last=False)
+        self._trim_cache()
         return resolved, hash_s, items
 
     def reset_merkle_stats(self) -> None:
@@ -568,8 +610,6 @@ class Trie:
         st["assemble_s"] = st.get("assemble_s", 0.0) + max(wall - hash_s, 0.0)
         st["nodes"] = int(st.get("nodes", 0)) + nodes
         st["workers"] = max(int(st.get("workers", 0)), workers)
-        from ..utils import metrics
-
         metrics.inc("trie_nodes_hashed_total", nodes)
         metrics.set_gauge("trie_merkle_workers", workers)
 
