@@ -154,6 +154,13 @@ class Hub:
     async def _handle_inbound(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
+        if not self._server.is_serving():
+            # accepted before stop() closed the server, started after it
+            # cancelled the readers: reading on would hold its
+            # wait_closed() until the peer hangs up, and a fleet stops its
+            # nodes one after another, so that peer is still running
+            writer.close()
+            return
         task = asyncio.current_task()
         if task is not None:
             self._reader_tasks.add(task)

@@ -407,10 +407,12 @@ def test_no_scope_spans_an_await():
 
 
 def test_a_scope_costs_well_under_five_microseconds():
+    """The best of many short rounds: a round a busy host preempts is slow,
+    one it leaves alone says what a scope costs."""
     tracing.reset_for_tests()
-    n = 50_000
+    n = 1_000
     best = float("inf")
-    for _ in range(3):
+    for _ in range(300):
         t0 = time.perf_counter()
         for _ in range(n):
             with tracing.account("frame_in"):

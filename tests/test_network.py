@@ -137,6 +137,36 @@ def test_manager_ping_roundtrip():
     asyncio.run(main())
 
 
+def test_hub_stop_returns_with_a_peer_connected_whose_reader_started_late():
+    """A connection accepted as stop() closes the server may start its
+    reader only after stop() cancelled the others; stop() must not then
+    wait for that peer to hang up (PR 40)."""
+    from lachain_tpu.network.hub import Hub
+
+    async def main():
+        hub = Hub("127.0.0.1", 0, on_batch=lambda data: None)
+        reader_of, accepted, late = hub._handle_inbound, asyncio.Event(), asyncio.Event()
+
+        async def handle(reader, writer):  # its first step, held past stop()'s cancel
+            accepted.set()
+            await late.wait()
+            await reader_of(reader, writer)
+
+        hub._handle_inbound = handle
+        await hub.start()
+        _peer_reader, peer = await asyncio.open_connection("127.0.0.1", hub.port)
+        try:
+            await asyncio.wait_for(accepted.wait(), 5)
+            stopping = asyncio.ensure_future(hub.stop())
+            await asyncio.sleep(0.05)
+            late.set()
+            await asyncio.wait_for(stopping, 5)
+        finally:
+            peer.close()
+
+    asyncio.run(main())
+
+
 def test_forged_batch_dropped():
     async def main():
         a = NetworkManager(ecdsa.generate_private_key(Rng(1)), flush_interval=0.01)

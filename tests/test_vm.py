@@ -889,22 +889,26 @@ def test_translator_speedup_over_interpreter():
         b.add_function([I32], [I32], [I32], body, export="f")
         return b
 
-    n = 50_000
+    n = 10_000
     from lachain_tpu.vm.interpreter import GasMeter
 
     inst = instantiate(build(), gas=GasMeter(1 << 62))
-    t0 = time.perf_counter()
-    r1 = inst.invoke("f", [n])
-    dt_tx = time.perf_counter() - t0
-    os.environ["LACHAIN_TPU_WASM"] = "interp"
-    try:
-        inst2 = instantiate(build(), gas=GasMeter(1 << 62))
+    inst2 = instantiate(build(), gas=GasMeter(1 << 62))
+    # the best of five rounds each, taken in turn: a round a busy host
+    # preempts is slow, one it leaves alone says what the engine costs
+    dt_tx = dt_in = float("inf")
+    for _ in range(5):
         t0 = time.perf_counter()
-        r2 = inst2.invoke("f", [n])
-        dt_in = time.perf_counter() - t0
-    finally:
-        os.environ.pop("LACHAIN_TPU_WASM", None)
-    assert r1 == r2
+        r1 = inst.invoke("f", [n])
+        dt_tx = min(dt_tx, time.perf_counter() - t0)
+        os.environ["LACHAIN_TPU_WASM"] = "interp"
+        try:
+            t0 = time.perf_counter()
+            r2 = inst2.invoke("f", [n])
+            dt_in = min(dt_in, time.perf_counter() - t0)
+        finally:
+            os.environ.pop("LACHAIN_TPU_WASM", None)
+        assert r1 == r2
     # gas parity: translatable functions bill identically on both engines
     assert inst.gas.spent == inst2.gas.spent
     assert dt_in / dt_tx >= 5, f"only {dt_in / dt_tx:.1f}x"
