@@ -5,14 +5,15 @@ Parity with the reference's ECDSA surface
 Secp256k1.Net): transaction + consensus-header signatures with public-key
 recovery, 65-byte (r || s || v) signatures, Ethereum-style addresses.
 
-Pure Python (curve ops on ints). SIGNING IS NOT CONSTANT-TIME on either
-backend: both this oracle and the C++ port use branchy double-and-add over
-the secret nonce, so timing/cache side channels can leak nonce bits of a
-frequently-signing key (lattice attacks). Both are therefore DEVNET-GRADE
-for signing; verification/recovery take only public inputs and are
-unaffected. A production deployment must swap sign_hash for a
-constant-time implementation (complete formulas + branchless window
-selection) before exposing validator keys to co-located adversaries.
+Pure Python (curve ops on ints) as the oracle; the C++ backend
+(crypto/native/secp256k1.cpp) gives the same bytes. The native signer is
+constant time in the nonce and the private key: a fixed-base comb with
+every table entry of a window read under a mask, no branch on either
+secret. THE ORACLE'S SIGNING IS NOT: its double-and-add branches on every
+nonce bit, so timing/cache side channels can leak nonce bits of a
+frequently-signing key (lattice attacks); LACHAIN_TPU_ECDSA=python, which
+forces it, is devnet-grade for signing. Verification and recovery take
+only public inputs.
 """
 from __future__ import annotations
 
@@ -353,7 +354,7 @@ def _tpu_recover(hashes, sigs):
 def _batch_threads() -> int:
     """Threads a batch entry offers the library: the host's cores, 16 at
     most. Read once a process: os.cpu_count() reads a file, 36-80 us a
-    call on the chip's host, where a batch of one recovery costs 230."""
+    call on the chip's host, where a batch of one recovery costs ~70."""
     return min(_os_mod.cpu_count() or 1, 16)
 
 
@@ -391,9 +392,9 @@ def recover_hash_batch(
     entry (lt_ec_recover_batch) — the pool-ingest path (role of the
     reference's background TransactionVerifier,
     Blockchain/Operations/TransactionVerifier.cs:23-72). Threads scale on
-    multi-core hosts; on this 1-core CI box the win is the amortized
-    fixed-base G table + windowed multiplies (~2x vs round 2). Entries
-    with non-standard lengths fall back to the scalar path."""
+    multi-core hosts; on one core the batch is the scalar recovery an
+    item. Entries with non-standard lengths fall back to the scalar
+    path."""
     n = len(hashes)
     if n != len(sigs):
         raise ValueError("hashes/sigs length mismatch")
