@@ -9,14 +9,18 @@ This module computes them that way, batched: all pending items that share a
 (field, k, n) — or for decode a (field, k, erasure-pattern) — are
 column-concatenated into ONE matrix product per group, which is the shape
 "the designated second TPU kernel" (ops/rs.py docstring, PAPER.md §2a)
-wants: a log/exp table gather plus an XOR reduction over the contraction
-axis. When this process owns an accelerator (crypto/provider.py decides; or
-LACHAIN_RS_DEVICE=1 forces it) products of at least _DEVICE_MIN_COLS
-columns are jitted and dispatched to the device, sharded across the PR 14
-mesh along the column (slot-payload) axis; otherwise the same gather + XOR
-runs vectorized in numpy. Both paths use the identical exp/log tables,
-so results are bit-identical to ops/rs.py (tests/test_rs_batch.py pins a
-200-seed differential).
+wants. On the host (numpy) a product is a log/exp table gather plus an XOR
+reduction over the contraction axis. When this process owns an accelerator
+(crypto/provider.py decides; or LACHAIN_RS_DEVICE=1 forces it) products of
+at least _DEVICE_MIN_COLS columns run on the device as ONE 0/1 matrix
+product on the matrix unit instead: multiplying by a constant `a` is linear
+over GF(2), an (bits x bits) bit matrix, so `A (r,k) x B (k,c)` is
+`A_bin (bits*r, bits*k) @ B's bit planes (bits*k, c)`, reduced mod 2 and
+packed back into symbols (bit_matrix, _mm). Its columns are sharded across
+the device mesh (parallel/mesh.py) along the column (slot-payload) axis.
+Both paths compute the same field arithmetic exactly, so results are
+bit-identical to ops/rs.py (tests/test_rs_batch.py pins a 200-seed
+differential, and the device product against GF.matmul).
 
 GF(2^16) (poly x^16+x^12+x^3+x+1 = 0x1100B, generator 2) backs shard counts
 past GF(2^8)'s 255 evaluation points: symbols are big-endian uint16 pairs,
@@ -27,12 +31,13 @@ engine-internal fallback when no host shim is attached).
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..utils import tracing
+from ..utils import metrics, tracing
 
 # device dispatch is worth its ferry cost only past a column threshold;
 # below it the numpy path wins outright
@@ -98,6 +103,23 @@ class GF:
             )
         return out
 
+    def bit_matrix(self, a: np.ndarray) -> np.ndarray:
+        """Multiplication by `a` (r,k) as a 0/1 matrix over GF(2), shape
+        (bits*r, bits*k): row block o, column block i holds bit o of
+        a * 2^i, so that bit_matrix(a) @ planes(b) mod 2 = planes(a x b),
+        where planes(b) stacks bit i of every symbol of b as row block i."""
+        a = np.ascontiguousarray(a, dtype=self.dtype)
+        r, k = a.shape
+        bits = self.bits
+        # a * 2^i = exp[log a + i] (2 generates the field); 0 stays 0
+        powers = np.where(
+            a != 0, self.exp[self.log[a] + np.arange(bits)[:, None, None]], 0
+        ).transpose(1, 0, 2)  # (r, i, k)
+        out = np.empty((bits, r, bits, k), dtype=np.uint8)
+        for o in range(bits):
+            out[o] = (powers >> o) & 1
+        return out.reshape(bits * r, bits * k)
+
     def mat_inv(self, mat: np.ndarray) -> Optional[np.ndarray]:
         """Gauss-Jordan inversion (first-nonzero pivot, same scan order as
         ops/rs.py::_gf_mat_inv); None when singular."""
@@ -159,6 +181,9 @@ def field_for(n: int) -> GF:
 
 _VCACHE: Dict[Tuple[int, int, int], np.ndarray] = {}
 _ICACHE: Dict[Tuple[int, int, Tuple[int, ...]], Optional[np.ndarray]] = {}
+# the device product's bit matrices, under the key of the matrix they
+# expand (a _VCACHE or an _ICACHE key)
+_BCACHE: Dict[tuple, np.ndarray] = {}
 _CACHE_CAP = 512
 
 
@@ -198,10 +223,17 @@ def _inverse_for(
     return inv
 
 
-# -- device dispatch ---------------------------------------------------------
+def _bit_matrix_for(field: GF, a: np.ndarray, key: tuple) -> np.ndarray:
+    """field.bit_matrix(a), cached under `key`, the key of `a` itself."""
+    a_bin = _BCACHE.get(key)
+    if a_bin is None:
+        if len(_BCACHE) >= _CACHE_CAP:
+            _BCACHE.clear()
+        a_bin = _BCACHE[key] = field.bit_matrix(a)
+    return a_bin
 
-_JIT_CACHE: Dict[int, object] = {}
-_EXP_DEV: Dict[int, object] = {}
+
+# -- device dispatch ---------------------------------------------------------
 
 
 def device_enabled() -> bool:
@@ -218,40 +250,43 @@ def device_enabled() -> bool:
     return device_platform() not in (None, "cpu")
 
 
-def _device_jit(bits: int):
-    fn = _JIT_CACHE.get(bits)
-    if fn is None:
-        import jax
+def _mm(a_bin, b):
+    """The device's GF product: `b` (k, c) of symbols (uint8 for GF(2^8),
+    uint16 for GF(2^16): the field's width is read from it) into bit planes
+    (bits*k, c), one exact 0/1 matmul against a_bin (bits*r, bits*k) on the
+    matrix unit (bf16 in, f32 sums of at most bits*k ones), mod 2, packed
+    back into (r, c) symbols."""
+    import jax.numpy as jnp
 
-        def _mm(exp, log_a, mask_a, log_b, mask_b):
-            import jax.numpy as jnp
-
-            def body(j, acc):
-                la = jax.lax.dynamic_slice_in_dim(log_a, j, 1, 1)  # (r,1)
-                ma = jax.lax.dynamic_slice_in_dim(mask_a, j, 1, 1)
-                lb = jax.lax.dynamic_slice_in_dim(log_b, j, 1, 0)  # (1,c)
-                mb = jax.lax.dynamic_slice_in_dim(mask_b, j, 1, 0)
-                prod = jnp.where(ma & mb, exp[la + lb], 0).astype(exp.dtype)
-                return acc ^ prod
-
-            import jax.numpy as jnp
-
-            acc0 = jnp.zeros(
-                (log_a.shape[0], log_b.shape[1]), dtype=exp.dtype
-            )
-            return jax.lax.fori_loop(0, log_a.shape[1], body, acc0)
-
-        fn = _JIT_CACHE[bits] = jax.jit(_mm)
-    return fn
+    bits = b.dtype.itemsize * 8
+    k, c = b.shape
+    shifts = jnp.arange(bits, dtype=b.dtype)
+    planes = (b[None] >> shifts[:, None, None]) & 1  # (i, k, c)
+    sums = jnp.dot(
+        a_bin.astype(jnp.bfloat16),
+        planes.reshape(bits * k, c).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    )
+    out_bits = (sums.astype(jnp.int32) & 1).reshape(bits, -1, c)  # (o, r, c)
+    weights = shifts.astype(jnp.int32)[:, None, None]
+    return jnp.sum(out_bits << weights, axis=0).astype(b.dtype)
 
 
-def _matmul_device(field: GF, a: np.ndarray, b: np.ndarray, era=None):
-    """One jitted gather+XOR matmul on the device, columns padded to a
-    power of two and (when the mesh has >1 device) sharded along the
-    column axis — each device owns a contiguous run of slot payloads."""
+@functools.cache
+def _device_jit():
     import jax
 
-    a = np.ascontiguousarray(a, dtype=field.dtype)
+    return jax.jit(_mm)
+
+
+def _matmul_device(field: GF, a: np.ndarray, b: np.ndarray, key: tuple, era=None):
+    """One jitted bit-plane matmul on the device (_mm), columns padded to a
+    power of two and (when the mesh has >1 device) sharded along the
+    column axis — each device owns a contiguous run of slot payloads, and
+    the bit matrix of `a` (cached under `key`) is replicated."""
+    import jax
+
+    a_bin = _bit_matrix_for(field, a, key)
     b = np.ascontiguousarray(b, dtype=field.dtype)
     c = b.shape[1]
     ndev = jax.device_count()
@@ -260,10 +295,6 @@ def _matmul_device(field: GF, a: np.ndarray, b: np.ndarray, era=None):
         c_pad *= 2
     b_pad = np.zeros((b.shape[0], c_pad), dtype=field.dtype)
     b_pad[:, :c] = b
-    log_a = field.log[a]
-    log_b = field.log[b_pad]
-    mask_a = a != 0
-    mask_b = b_pad != 0
     with tracing.span(
         "rs.device",
         era=era,
@@ -273,28 +304,31 @@ def _matmul_device(field: GF, a: np.ndarray, b: np.ndarray, era=None):
         cols_padded=int(c_pad),
         devices=int(ndev),
     ):
-        exp_dev = _EXP_DEV.get(field.bits)
-        if exp_dev is None:
-            exp_dev = _EXP_DEV[field.bits] = jax.device_put(field.exp)
-        args = (log_b, mask_b)
+        args = (a_bin, b_pad)
         if ndev > 1 and c_pad % ndev == 0:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
 
             from ..parallel.mesh import make_mesh
 
-            sharding = NamedSharding(make_mesh(), P(None, "shares"))
-            args = tuple(jax.device_put(x, sharding) for x in args)
-        out = _device_jit(field.bits)(exp_dev, log_a, mask_a, *args)
-        out = np.asarray(jax.device_get(out))
+            mesh = make_mesh()
+            args = (
+                jax.device_put(a_bin, NamedSharding(mesh, P())),
+                jax.device_put(b_pad, NamedSharding(mesh, P(None, "shares"))),
+            )
+        out = np.asarray(jax.device_get(_device_jit()(*args)))
     return out[:, :c]
 
 
-def _matmul(field: GF, a: np.ndarray, b: np.ndarray, era=None) -> np.ndarray:
+def _matmul(
+    field: GF, a: np.ndarray, b: np.ndarray, key: tuple, era=None
+) -> np.ndarray:
     # a device failure propagates: the numpy path is for small products
     # and host-backend processes, not a landing for exceptions
     if b.shape[1] >= _DEVICE_MIN_COLS and device_enabled():
-        return _matmul_device(field, a, b, era=era)
+        metrics.inc("rs_matmul_total", labels={"path": "device"})
+        return _matmul_device(field, a, b, key, era=era)
+    metrics.inc("rs_matmul_total", labels={"path": "host"})
     return field.matmul(a, b)
 
 
@@ -333,7 +367,9 @@ def encode_batch(
         v = vandermonde(field, k, n)
         coeffs = [_coeff_matrix(field, items[i][0], k) for i in members]
         widths = [c.shape[1] for c in coeffs]
-        out = _matmul(field, v, np.concatenate(coeffs, axis=1), era=era)
+        out = _matmul(
+            field, v, np.concatenate(coeffs, axis=1), (bits, k, n), era=era
+        )
         off = 0
         for i, w in zip(members, widths):
             block = out[:, off : off + w]
@@ -389,7 +425,9 @@ def decode_batch(
             )
             received.append(mat)
             widths.append(mat.shape[1])
-        out = _matmul(field, inv, np.concatenate(received, axis=1), era=era)
+        out = _matmul(
+            field, inv, np.concatenate(received, axis=1), (bits, k, xs), era=era
+        )
         off = 0
         for i, w in zip(members, widths):
             coeffs = out[:, off : off + w]

@@ -284,6 +284,137 @@ def test_device_failure_propagates(monkeypatch):
     assert rs_batch.encode(b"small", 3, 7) == rs.encode(b"small", 3, 7)
 
 
+# --- the device product: a GF(2) bit-plane matmul ----------------------------
+
+
+def test_bit_matrix_is_multiplication_gf8():
+    """For every a, x in GF(2^8): bit_matrix(a) times the bits of x, mod 2,
+    is the bits of a*x."""
+    import numpy as np
+
+    gf = rs_batch.GF8
+    a_bin = gf.bit_matrix(np.arange(256).reshape(256, 1))  # (8*256, 8)
+    xs = np.arange(256)
+    x_bits = (xs[None, :] >> np.arange(8)[:, None]) & 1  # (8, 256)
+    prod = (a_bin.astype(np.int64) @ x_bits) & 1  # (o*256 + a, x)
+    got = (prod.reshape(8, 256, 256) << np.arange(8)[:, None, None]).sum(0)
+    want = [[gf.mul(a, x) for x in range(256)] for a in range(256)]
+    assert got.tolist() == want
+
+
+def _device_case(name):
+    """(field, a, c): a matrix and a column count for the differential."""
+    import zlib
+
+    import numpy as np
+
+    gf8, gf16 = rs_batch.GF8, rs_batch.gf16()
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+
+    def rand(field, r, k):
+        return rng.integers(0, 1 << field.bits, (r, k)).astype(field.dtype)
+
+    if name == "gf8_64x22":
+        return gf8, rand(gf8, 64, 22), 5003
+    if name == "gf8_22x22":
+        return gf8, rand(gf8, 22, 22), 4097
+    if name == "gf16_300x90":
+        return gf16, rand(gf16, 300, 90), 777
+    if name == "gf8_zeros":
+        return gf8, np.zeros((64, 22), np.uint8), 1000
+    if name == "gf8_all_ff":
+        return gf8, np.full((64, 22), 0xFF, np.uint8), 1000
+    if name == "gf16_all_ffff":
+        return gf16, np.full((20, 7), 0xFFFF, np.uint16), 1000
+    if name == "gf8_identity":
+        return gf8, np.eye(22, dtype=np.uint8), 1000
+    if name == "gf8_vandermonde":
+        return gf8, rs_batch.vandermonde(gf8, 22, 64), 4500
+    if name == "gf8_decode_inverse":
+        xs = tuple(range(22, 44))
+        return gf8, rs_batch._inverse_for(gf8, 22, xs), 4500
+    if name == "gf16_vandermonde_512":
+        return gf16, rs_batch.vandermonde(gf16, 172, 512), 300
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("devices", [8, 1])
+@pytest.mark.parametrize(
+    "name",
+    [
+        "gf8_64x22",
+        "gf8_22x22",
+        "gf16_300x90",
+        "gf8_zeros",
+        "gf8_all_ff",
+        "gf16_all_ffff",
+        "gf8_identity",
+        "gf8_vandermonde",
+        "gf8_decode_inverse",
+        "gf16_vandermonde_512",
+    ],
+)
+def test_device_product_equals_numpy(name, devices, monkeypatch):
+    """_matmul_device (bit planes, one matmul, mod 2, packed) equals the
+    numpy GF.matmul bit for bit, on the 8-device sharded path the test
+    platform forces and on one device."""
+    import jax
+    import numpy as np
+
+    assert jax.device_count() == 8
+    if devices == 1:
+        monkeypatch.setattr(jax, "device_count", lambda: 1)
+    field, a, c = _device_case(name)
+    rng = np.random.default_rng(c)
+    b = rng.integers(0, 1 << field.bits, (a.shape[1], c)).astype(field.dtype)
+    b[:, :3] = 0  # zero columns beside random ones
+    b[:, 3] = field.order  # an all-ones column
+    got = rs_batch._matmul_device(field, a, b, ("test", name))
+    assert got.dtype == field.dtype and got.shape == (a.shape[0], c)
+    assert np.array_equal(got, field.matmul(a, b))
+
+
+def _device_counts():
+    from lachain_tpu.utils import metrics
+
+    return tuple(
+        metrics.counter_value("rs_matmul_total", labels={"path": p})
+        for p in ("device", "host")
+    )
+
+
+def test_new_matrix_same_shape_compiles_nothing(monkeypatch):
+    """The bit matrix is an argument, not a traced constant: another
+    payload and another erasure pattern of the same shapes reuse the one
+    compiled program; each product counts once in rs_matmul_total."""
+    monkeypatch.setenv("LACHAIN_RS_DEVICE", "1")
+    n, k = 7, 3
+    rng = random.Random(11)
+
+    def round_trip(lost):
+        items = [(rng.randbytes(7000), k, n) for _ in range(2)]  # 4668 cols
+        enc = rs_batch.encode_batch(items)
+        holes = [
+            [None if i in lost else s for i, s in enumerate(shards)]
+            for shards in enc
+        ]
+        assert rs_batch.decode_batch([(h, k) for h in holes]) == [
+            d for d, _k, _n in items
+        ]
+        assert enc == [rs.encode(d, k, n) for d, _k, _n in items]
+
+    before = _device_counts()
+    round_trip({0, 1})
+    assert _device_counts() == (before[0] + 2, before[1])
+    compiled = rs_batch._device_jit()._cache_size()
+    round_trip({2, 5, 6})  # another decode matrix, the same shapes
+    assert rs_batch._device_jit()._cache_size() == compiled
+    assert _device_counts() == (before[0] + 4, before[1])
+    # under the column floor: the host path, once a product
+    rs_batch.encode_batch([(b"small", 3, 7), (b"other", 2, 4)])
+    assert _device_counts() == (before[0] + 4, before[1] + 2)
+
+
 # --- end-to-end: block-hash identity on vs off, both engines -----------------
 
 
