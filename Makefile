@@ -75,20 +75,20 @@ test-pipeline:
 test-sync:
 	$(PYTEST) $(PYTEST_ARGS) -m "sync and not slow"
 
-# optimistic lane-parallel execution: plan/run/merge determinism, the
-# randomized serial-vs-parallel differential (receipts + roots + trie
-# node sets bit-identical), forced-conflict degradation, delta
-# checkpoints, sharded pool admission. The slice to run after touching
-# core/parallel_exec.py, core/execution.py, storage/state.py checkpoints
-# or core/tx_pool.py
+# block execution: the randomized deferred-vs-immediate freeze
+# differential over executor blocks (receipts + roots + trie node sets
+# bit-identical), the hashing routes over the benchmark's block shapes,
+# delta checkpoints, canonical ordering, sharded pool admission. The
+# slice to run after touching core/block_manager.py, core/execution.py,
+# storage/state.py checkpoints or core/tx_pool.py
 test-exec:
 	$(PYTEST) $(PYTEST_ARGS) -m exec
 
-# parallel merkleization: the 200-seed sharded-vs-serial apply_many
-# differential (roots + node sets + pending buffers), deferred batch
-# hashing, streamed-commit coverage. The slice to run after touching
-# storage/trie.py apply_many/_bulk, the batch keccak, or the
-# StateManager streamed commit
+# merkleization: the 200-seed deferred-vs-immediate apply_many
+# differential (roots + node sets + pending buffers), the hashing
+# threads' byte floor, the node cache across freezes. The slice to run
+# after touching storage/trie.py apply_many/_bulk, the batch keccak, or
+# the StateManager streamed commit
 test-trie:
 	$(PYTEST) $(PYTEST_ARGS) -m trie
 

@@ -188,6 +188,12 @@ def _build_node(cfg, config_path=None):
     )
     if cfg.hardfork.heights:
         set_hardfork_heights(cfg.hardfork.heights, force=True)
+    if cfg.ignored_keys:
+        logger.warning(
+            "config keys %s have no effect: blocks execute and freeze on "
+            "one path",
+            ", ".join(cfg.ignored_keys),
+        )
     if cfg.trace_capacity is not None:
         # resize the merged rings now; native engines created after this
         # point (LSM store below, consensus engine per era) size their
@@ -237,8 +243,6 @@ def _build_node(cfg, config_path=None):
         wallet=wallet,
         block_interval=cfg.blockchain.target_block_time_ms / 1000.0,
         pipeline_window=cfg.blockchain.pipeline_window,
-        exec_lanes=cfg.execution_lanes,
-        merkle_workers=cfg.merkle_workers,
     )
     if cfg.idle_alert_fraction is not None:
         # observability.idleAlertFraction: /healthz reads degraded when

@@ -27,11 +27,6 @@ from ..utils import tracing
 from ..utils import txtrace
 from ..utils.serialization import write_u32, write_u64
 from .execution import TransactionExecuter, set_balance
-from .parallel_exec import (
-    MIN_PARALLEL_TXS,
-    execute_block_parallel,
-    resolve_lanes,
-)
 from .types import (
     Block,
     BlockHeader,
@@ -56,8 +51,8 @@ class EmulationResult:
 
 # process-wide emulation memo: key -> (EmulationResult, exported trie node
 # buffer); bounded FIFO. See BlockManager.emulate for the sharing argument.
-# Lock-guarded: parallel-execution lane workers and the pipelined-era
-# scheduler can emulate from different threads concurrently.
+# Lock-guarded: the pipelined-era scheduler can emulate from different
+# threads concurrently.
 _EMULATE_MEMO: Dict[tuple, Tuple[EmulationResult, dict]] = {}
 _EMULATE_MEMO_MAX = 8
 _EMULATE_MEMO_LOCK = threading.Lock()
@@ -71,13 +66,12 @@ class BlockManager:
         executer: TransactionExecuter,
         lanes: int = 1,
     ):
+        # `lanes` stays only until perfbench/reference.py stops passing it
+        if lanes != 1:
+            raise ValueError(f"lanes={lanes}: blocks execute on one lane")
         self._kv = kv
         self.state = state
         self.executer = executer
-        # execution.lanes knob: 1 pins the serial oracle (default), N>1
-        # fixes the lane count, 0 = the program decides (resolve_lanes).
-        # Results are bit-identical either way (core/parallel_exec.py).
-        self.lanes = max(int(lanes), 0)
         self.on_block_persisted = []  # callbacks(block)
 
     # -- ordering (deterministic across validators) ---------------------------
@@ -130,23 +124,12 @@ class BlockManager:
             em, nodes = hit
             self.state.trie.absorb_pending(nodes)
             return em
-        lanes = resolve_lanes(self.lanes)
         with tracing.span("exec.block", cat="exec", era=block_index):
-            if lanes > 1 and len(txs) >= MIN_PARALLEL_TXS:
-                snap, receipts, _stats = execute_block_parallel(
-                    self.executer,
-                    self.state,
-                    txs,
-                    block_index,
-                    base_roots,
-                    lanes,
-                )
-            else:
-                snap = self.state.new_snapshot(base_roots)
-                receipts = []
-                for i, stx in enumerate(txs):
-                    res = self.executer.execute(snap, stx, block_index, i)
-                    receipts.append(res.receipt)
+            snap = self.state.new_snapshot(base_roots)
+            receipts = []
+            for i, stx in enumerate(txs):
+                res = self.executer.execute(snap, stx, block_index, i)
+                receipts.append(res.receipt)
             event_addrs = tuple(
                 v[:20] for v in snap._writes["events"].values() if v
             )

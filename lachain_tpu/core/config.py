@@ -21,6 +21,11 @@ CURRENT_VERSION = 7
 # coordinates a real activation height across the validator set
 HARDFORK_HEIGHT_NEVER = 2**62
 
+# (section, key) pairs older configs carry and this program ignores: the
+# lane count and merkle worker count of the threaded execution and freeze,
+# which are gone; every block executes and freezes on one path
+RETIRED_KEYS = (("execution", "lanes"), ("execution", "merkleWorkers"))
+
 # -- migrations --------------------------------------------------------------
 # each migrates version N -> N+1 (reference runs 17 of these sequentially)
 
@@ -247,31 +252,14 @@ class NodeConfig:
         return engine
 
     @property
-    def execution_lanes(self) -> int:
-        """Parallel-execution lane count (DEPLOY.md "Parallel execution").
-        Optional and additive (no config version bump): 1 pins the serial
-        executor, N > 1 fixes the lane count, 0 (the default) lets the
-        program decide by block: it takes the lane pipeline only for a
-        block shape measured to win on threads, and none does (the
-        executor is Python), so today 0 runs every block serially. Every
-        setting produces bit-identical blocks — the knob trades
-        merge/validation overhead against core utilization, never
-        semantics."""
-        return int(self.raw.get("execution", {}).get("lanes", 0))
-
-    @property
-    def merkle_workers(self) -> int:
-        """Parallel-merkleization worker count (DEPLOY.md "Parallel
-        merkleization"). Optional and additive (no config version bump):
-        1 pins the serial walker on one thread (deferred batch hashing
-        stays on), N > 1 fixes the subtrie worker count (capped at the
-        16-way fanout), 0 (the default) lets the program decide by batch:
-        one walker, since shard workers are Python and lost to it at every
-        size measured, with a tree level's hashing on the host's cores
-        only where the level carries enough bytes to pay for the threads.
-        Every setting produces bit-identical state roots — the knob only
-        trades thread overhead against core utilization."""
-        return int(self.raw.get("execution", {}).get("merkleWorkers", 0))
+    def ignored_keys(self) -> List[str]:
+        """Keys a config may still carry that the program no longer reads
+        (RETIRED_KEYS); the file loads, and `run` says they do nothing."""
+        return [
+            f"{section}.{key}"
+            for section, key in RETIRED_KEYS
+            if key in (self.raw.get(section) or {})
+        ]
 
     @property
     def trace_capacity(self) -> Optional[int]:

@@ -67,8 +67,6 @@ class Devnet:
         kv_factory: Optional[Callable[[int], KVStore]] = None,
         pipeline_window: int = 0,
         journals: Optional[List] = None,
-        exec_lanes: int = 1,
-        merkle_workers: int = 1,
         adversary=None,
         link_shaper=None,
         rbc_batch: bool = False,
@@ -114,13 +112,7 @@ class Devnet:
             # full system-contract registry (deploy/LRC-20/governance/staking)
             # so the devnet exercises the same execution surface as a real node
             executer = system_contracts.make_executer(chain_id)
-            # exec_lanes=1 keeps devnet harnesses on the serial oracle by
-            # default; campaigns opt into lanes explicitly (results are
-            # bit-identical either way — core/parallel_exec.py)
-            bm = BlockManager(kv, state, executer, lanes=exec_lanes)
-            # like exec_lanes: devnet harnesses default to the serial
-            # merkle walker; campaigns opt in (roots identical either way)
-            state.trie.merkle_workers = merkle_workers
+            bm = BlockManager(kv, state, executer)
             bm.build_genesis(
                 self.initial_balances,
                 chain_id,

@@ -71,8 +71,6 @@ class Node:
         advertise_host: Optional[str] = None,
         relay=None,  # "host:port:pubhex" or a list of them — NAT'd mode
         pipeline_window: int = 0,
-        exec_lanes: int = 0,
-        merkle_workers: int = 0,
     ):
         self.index = index
         # era-pipelining lookahead (config blockchain.pipelineWindow). On a
@@ -102,11 +100,7 @@ class Node:
             self.kv,
             self.state,
             executer or system_contracts.make_executer(chain_id),
-            lanes=exec_lanes,
         )
-        # parallel-merkleization knob (config execution.merkleWorkers):
-        # rides the shared trie handle so every freeze/commit sees it
-        self.state.trie.merkle_workers = merkle_workers
         # a store that holds a chain already: this process is a restart,
         # and times its way back into the committee (core/recovery.py).
         # None on a fresh store, so a node that never went away pays nothing

@@ -151,7 +151,6 @@ def run_stream_workload(
     state = StateManager(kv)
     state.stream_threshold = 64
     state._STREAM_BATCH = 100
-    state.trie.merkle_workers = 4
     bm = BlockManager(kv, state, execution.TransactionExecuter(chain_id))
     bm.build_genesis({sender: 10**18}, chain_id)
 

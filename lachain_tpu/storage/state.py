@@ -104,22 +104,15 @@ class Snapshot:
         self._undo.append((tree, key, buf.get(key, Snapshot._ABSENT)))
         buf[key] = None
 
-    def freeze(self, workers: Optional[int] = None, stream=None) -> StateRoots:
+    def freeze(self) -> StateRoots:
         """Flush buffered writes -> new immutable roots (Approve). Bulk
         application: each shared internal node rebuilds once per freeze
         instead of once per key (Trie.apply_many; root bit-identical to
-        the sequential replay for any worker count). `stream` forwards
-        each completed subtrie's node batch to the caller as it finishes
-        (StateManager.freeze_and_commit overlaps the WAL fsync with it)."""
-        new_roots = {}
-        for name in SUBTREES:
-            new_roots[name] = self._trie.apply_many(
-                getattr(self.base, name),
-                self._writes[name],
-                workers=workers,
-                stream=stream,
-            )
-        return StateRoots(**new_roots)
+        the sequential replay)."""
+        return StateRoots(**{
+            name: self._trie.apply_many(getattr(self.base, name), self._writes[name])
+            for name in SUBTREES
+        })
 
     def discard(self) -> None:
         """Rollback: drop buffered writes (outstanding checkpoints die too)."""
@@ -237,56 +230,6 @@ class StateManager:
             "streamed_batches": streamed,
             "nodes": len(nodes),
         }
-
-    def freeze_and_commit(
-        self, height: int, snap: Snapshot, workers: Optional[int] = None
-    ) -> StateRoots:
-        """Freeze + commit with full fsync overlap: each subtrie's node
-        batch is submitted to the WAL writer AS ITS WORKER FINISHES, so
-        the disk absorbs completed subtries while the remaining ones are
-        still hashing. The root-referencing rows are written LAST, in a
-        synchronous batch behind a barrier — same ordering invariant as
-        commit(). Engines without async batches just freeze-then-commit."""
-        import time as _time
-
-        kv = self._kv
-        if not (
-            getattr(kv, "supports_async_batches", False)
-            and sum(len(w) for w in snap._writes.values())
-            >= self.stream_threshold
-        ):
-            roots = snap.freeze(workers=workers)
-            self.commit(height, roots)
-            return roots
-
-        from .crashpoints import crash_point
-
-        streamed_keys: set = set()
-        tickets: list = []
-        fsync_wait = [0.0]
-
-        def stream(items):
-            t0 = _time.perf_counter()
-            tickets.append(kv.write_batch_async(items))
-            fsync_wait[0] += _time.perf_counter() - t0
-            streamed_keys.update(k for k, _ in items)
-            crash_point("trie.merkle.subtree_streamed")
-
-        roots = snap.freeze(workers=workers, stream=stream)
-        nodes = self.trie.peek_pending()
-        remaining = [(k, v) for k, v in nodes if k not in streamed_keys]
-        t0 = _time.perf_counter()
-        if tickets:
-            kv.write_barrier(tickets[-1])
-        kv.write_batch(remaining + self._root_rows(height, roots))
-        self.trie.confirm_pending(nodes)
-        self._committed = roots
-        self.commit_stats = {
-            "wal_fsync_s": fsync_wait[0] + _time.perf_counter() - t0,
-            "streamed_batches": len(tickets),
-            "nodes": len(nodes),
-        }
-        return roots
 
     def roots_at(self, height: int) -> Optional[StateRoots]:
         enc = self._kv.get(prefixed(EntryPrefix.SNAPSHOT_INDEX, write_u64(height)))

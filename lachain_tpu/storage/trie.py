@@ -17,11 +17,9 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 from collections import OrderedDict
-from concurrent.futures import FIRST_EXCEPTION, ThreadPoolExecutor, wait
 from dataclasses import dataclass
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 from ..crypto.hashes import keccak256, keccak256_batch
 from ..utils import metrics
@@ -31,10 +29,9 @@ from .kv import EntryPrefix, KVStore, prefixed
 EMPTY_ROOT = b"\x00" * 32
 _NIBBLES = 64  # keccak256 -> 64 nibbles
 
-# batch-size floors for the two merkleization fast paths: below them the
+# batch-size floor for deferred level-batched hashing: below it the
 # bookkeeping costs more than the per-node keccak dispatch it saves
-MIN_DEFER_OPS = 32    # deferred level-batched hashing
-MIN_SHARD_OPS = 512   # subtrie-sharded workers, where a worker count is fixed
+MIN_DEFER_OPS = 32
 # a level's encodings go to native hashing threads only from this many
 # bytes on; below it the threads' start costs more than they hash
 MIN_HASH_THREAD_BYTES = 3 << 19
@@ -44,21 +41,10 @@ _KECCAK_BATCH_BUCKETS = (16, 64, 256, 1024, 4096, 16384, 65536)
 
 @functools.cache
 def _host_cores() -> int:
-    """The host's cores, 16 at most (the subtrie fanout). Read once a
-    process: os.cpu_count() reads a file, 36-80 us a call on the chip's
-    host, and a freeze asks once a subtree."""
+    """The native hashing threads a level may use: the host's cores, 16 at
+    most. Read once a process: os.cpu_count() reads a file, 36-80 us a call
+    on the chip's host, and a freeze asks once a subtree."""
     return min(os.cpu_count() or 1, 16)
-
-
-def resolve_merkle_workers(n: int) -> int:
-    """Merkle worker knob -> the threads a batch may use: N pins it (capped
-    at the 16-way subtrie fanout), 0 = the program decides, from the host's
-    cores. 1 disables sharding and hashes on one thread but keeps deferred
-    batch hashing (the single-core win)."""
-    n = int(n)
-    if n > 0:
-        return min(n, 16)
-    return _host_cores()
 
 
 def _nibble(h: bytes, depth: int) -> int:
@@ -68,8 +54,7 @@ def _nibble(h: bytes, depth: int) -> int:
 
 def _group_by_nibble(pairs, depth: int) -> Dict[int, list]:
     """Partition (kh, ...) pairs by their nibble at `depth` — the one
-    grouping rule both bulk paths share (canonical structure depends on
-    the two staying identical)."""
+    grouping rule of the bulk walk and the subtree builder."""
     groups: Dict[int, list] = {}
     for kh, v in pairs:
         groups.setdefault(_nibble(kh, depth), []).append((kh, v))
@@ -192,21 +177,8 @@ class Trie:
         self._cache: OrderedDict[bytes, object] = OrderedDict()
         self._cache_size = cache_size
         self._pending: Dict[bytes, bytes] = {}  # prefixed key -> encoding
-        # read-only view of a parent trie's node cache (see fork()); never
-        # mutated through this handle
-        self._read_cache: Optional[OrderedDict] = None
-        # read-only view of a parent trie's PENDING buffer (_shard_fork):
-        # a shard worker starts with an empty buffer of its own, so its
-        # new nodes are exactly `_pending` after the run — no diffing
-        self._read_pending: Optional[Dict[bytes, bytes]] = None
-        # armed deferred-hash sink (apply_many bulk paths only)
+        # armed deferred-hash sink (apply_many's deferred route only)
         self._defer: Optional[_DeferredHasher] = None
-        # merkle worker knob (config execution.merkleWorkers): 0 = the
-        # program decides (apply_many)
-        self.merkle_workers: int = 0
-        # accumulated apply_many profile (reset_merkle_stats() to zero),
-        # for the commit-phase bench breakdown
-        self.merkle_stats: Dict[str, float] = {}
 
     # -- node io -------------------------------------------------------------
     def _store(self, node) -> bytes:
@@ -225,19 +197,8 @@ class Trie:
         if node is not None:
             self._cache.move_to_end(h)
             return node
-        if self._read_cache is not None:
-            # forked handle: peek the parent's cache WITHOUT touching its
-            # LRU order (move_to_end is what makes the parent cache unsafe
-            # to share between threads; a bare get is a single C-level dict
-            # read, and the parent thread is quiescent while forks run)
-            node = self._read_cache.get(h)
-            if node is not None:
-                self._cache_put(h, node)
-                return node
         key = prefixed(EntryPrefix.TRIE_NODE, h)
         enc = self._pending.get(key)
-        if enc is None and self._read_pending is not None:
-            enc = self._read_pending.get(key)
         if enc is None:
             enc = self._kv.get(key)
         if enc is None:
@@ -272,38 +233,6 @@ class Trie:
         Re-absorbing an already-persisted node is harmless — same key, same
         encoding — it just rides the next commit batch again."""
         self._pending.update(nodes)
-
-    def fork(self) -> "Trie":
-        """A private handle over the SAME kv for a concurrent reader
-        (parallel execution lanes): its own LRU cache and pending buffer
-        (seeded with ours — forked roots may reference not-yet-committed
-        nodes), plus a read-only peek into our cache so a fork does not
-        start cold. The fork is disposable: nodes it stores stay in its
-        own pending buffer and are simply dropped with it (lane-local
-        speculative state never rides a commit batch)."""
-        t = Trie(self._kv, self._cache_size)
-        t._pending = dict(self._pending)
-        t._read_cache = self._cache
-        return t
-
-    def _shard_fork(self) -> "Trie":
-        """A worker handle for subtrie-sharded merkleization: like fork(),
-        but the pending buffer starts EMPTY and chains read-only over ours
-        (copying 100k inherited entries per worker would eat the win). The
-        worker's newly stored nodes are exactly its `_pending`, which the
-        caller absorbs — unlike lane forks, shard results are canonical."""
-        t = Trie(self._kv, self._cache_size)
-        t._read_cache = self._cache
-        t._read_pending = self._pending
-        return t
-
-    def absorb_cache(self, fork: "Trie") -> None:
-        """Adopt the node cache of a fork that has finished (a shard worker,
-        an execution lane): nodes are content-addressed, so whatever a fork
-        decoded from the store or stored itself is valid here too. Only
-        while no other fork still peeks at this cache (_load)."""
-        self._cache.update(fork._cache)
-        self._trim_cache()
 
     def clear_cache(self) -> None:
         self._cache.clear()
@@ -396,160 +325,50 @@ class Trie:
     # whole simulated era.
 
     def apply_many(
-        self,
-        root: bytes,
-        writes: Dict[bytes, Optional[bytes]],
-        workers: Optional[int] = None,
-        stream: Optional[Callable[[List[Tuple[bytes, bytes]]], None]] = None,
+        self, root: bytes, writes: Dict[bytes, Optional[bytes]]
     ) -> bytes:
         """Apply a {key: value-or-None(delete)} batch; returns the new root
-        (bit-identical to sequential put/delete in any order, for any
-        worker count).
+        (bit-identical to sequential put/delete in any order).
 
-        Large batches take one of two fast paths, both exact:
-          * deferred batch hashing (>= MIN_DEFER_OPS): nodes are encoded
-            level-by-level bottom-up and each level is hashed in one
-            native keccak call, on threads from MIN_HASH_THREAD_BYTES a
-            level on;
-          * subtrie sharding (>= MIN_SHARD_OPS and a worker count N > 1
-            fixed by the caller): the op batch splits by top-level nibble,
-            each subtrie runs on a worker over a _shard_fork() handle, and
-            the root is assembled from the 16 child hashes on the caller
-            thread.
-
-        `workers` overrides the handle's merkle_workers knob. 0 = the
-        program decides: one walker whatever the batch's size, since on the
-        chip's host shard workers (Python walkers under the GIL) lost to it
-        at every size measured, 700 to 100,000 keys a subtree (PERF.md
-        section 6, PR 39); only the level hashing goes to the host's cores.
-        `stream`, when given, receives each completed subtrie's NEW
-        (key, encoding) node items as workers finish — the fsync-overlap
-        seam StateManager.freeze_and_commit plugs the WAL into."""
+        One walker; the batch picks how it hashes:
+          * below MIN_DEFER_OPS each node is hashed as it is stored;
+          * from MIN_DEFER_OPS on, nodes are encoded level-by-level
+            bottom-up and each level is hashed in one native keccak call
+            (_resolve_deferred: on the host's cores only from
+            MIN_HASH_THREAD_BYTES a level)."""
         if not writes:
             return root
         entries: Dict[bytes, Optional[bytes]] = {
             keccak256(k): v for k, v in writes.items()
         }
         ops = sorted(entries.items())
-        configured = int(self.merkle_workers if workers is None else workers)
-        nworkers = resolve_merkle_workers(configured)
-        t0 = time.perf_counter()
-        if configured > 1 and len(ops) >= MIN_SHARD_OPS and root != EMPTY_ROOT:
-            node = self._load(root)
-            if isinstance(node, InternalNode):
-                return self._apply_sharded(
-                    root, node, ops, nworkers, stream, t0
-                )
-        return self._apply_serial(root, ops, nworkers, stream, t0)
-
-    def _apply_serial(self, root, ops, nworkers, stream, t0) -> bytes:
-        """Single-walker bulk application; defers hashing into per-level
-        native batch calls when the batch is big enough to pay for it."""
         if len(ops) < MIN_DEFER_OPS:
-            new_root = self._bulk(root, ops, 0)
-            self._set_merkle_stats(t0, 0.0, 0, 1)
-            return new_root
+            return self._bulk(root, ops, 0)
         self._defer = _DeferredHasher()
         try:
             out = self._bulk(root, ops, 0)
         finally:
             defer, self._defer = self._defer, None
-        resolved, hash_s, items = self._resolve_deferred(defer, nworkers)
+        resolved = self._resolve_deferred(defer)
         if _DeferredHasher.is_token(out):
             out = resolved[out]
-        if stream is not None and items:
-            stream(items)
-        self._set_merkle_stats(t0, hash_s, len(items), 1)
         return out
 
-    def _apply_sharded(
-        self, root_hash, node, ops, nworkers, stream, t0
-    ) -> bytes:
-        """Subtrie-sharded merkleization over the 16-way top-level fanout.
-        Each worker owns an independent subtrie (disjoint key ranges), so
-        its node set is canonical regardless of scheduling; the caller
-        thread replays the serial path's depth-0 step — per-nibble child
-        patch, no-op short-circuit, collapse rule — over the 16 child
-        hashes, which is what makes the root bit-identical to `_bulk`."""
-        groups = _group_by_nibble(ops, 0)
-        children = list(node.children)
-
-        def run(nib: int, group) -> tuple:
-            fork = self._shard_fork()
-            fork._defer = _DeferredHasher()
-            try:
-                sub = fork._bulk(children[nib], group, 1)
-            finally:
-                defer, fork._defer = fork._defer, None
-            # per-worker native hashing stays single-threaded: the
-            # parallelism budget is already spent on the worker pool
-            resolved, hash_s, items = fork._resolve_deferred(defer, 1)
-            if _DeferredHasher.is_token(sub):
-                sub = resolved[sub]
-            return nib, sub, items, hash_s, fork
-
-        results: Dict[int, bytes] = {}
-        forks: List["Trie"] = []
-        hash_s = 0.0
-        hashed = 0
-        with ThreadPoolExecutor(
-            max_workers=min(nworkers, len(groups)),
-            thread_name_prefix="merkle",
-        ) as pool:
-            futs = [
-                pool.submit(run, nib, group)
-                for nib, group in sorted(groups.items())
-            ]
-            # absorb/stream in COMPLETION order: a finished subtrie's node
-            # batch can hit the WAL while its siblings are still hashing
-            pending_futs = set(futs)
-            while pending_futs:
-                done, pending_futs = wait(
-                    pending_futs, return_when=FIRST_EXCEPTION
-                )
-                for fut in done:
-                    nib, sub, items, worker_hash_s, fork = fut.result()
-                    results[nib] = sub
-                    forks.append(fork)
-                    self._pending.update(items)
-                    hash_s += worker_hash_s
-                    hashed += len(items)
-                    if stream is not None and items:
-                        stream(items)
-        # the workers are done, so nobody peeks at our cache any more: keep
-        # the nodes they wrote and read, as the serial walk keeps its own,
-        # or the next block reads back from the store what this one wrote
-        for fork in forks:
-            self.absorb_cache(fork)
-        for nib in groups:
-            children[nib] = results[nib]
-        if children == list(node.children):
-            out = root_hash
-        else:
-            out = self._collapse_or_store(children)
-        self._set_merkle_stats(t0, hash_s, hashed, min(nworkers, len(groups)))
-        metrics.inc("trie_sharded_applies_total")
-        return out
-
-    def _resolve_deferred(
-        self, defer: _DeferredHasher, nthreads: int
-    ) -> Tuple[Dict[bytes, bytes], float, List[Tuple[bytes, bytes]]]:
+    def _resolve_deferred(self, defer: _DeferredHasher) -> Dict[bytes, bytes]:
         """Hash a deferred sink's nodes level-by-level bottom-up through
         the native batch keccak, patching child tokens with the hashes of
-        the level below. Returns (token -> hash, seconds spent hashing,
-        new (prefixed key, encoding) items stored). A level goes to
-        `nthreads` native threads only where it carries MIN_HASH_THREAD_BYTES
-        of encodings; a smaller one is hashed on the calling thread.
+        the level below, into the pending buffer; returns token -> hash.
+        A level goes to the host's cores (_host_cores) only where it
+        carries MIN_HASH_THREAD_BYTES of encodings; a smaller one is hashed
+        on the calling thread.
 
         HOT PATH: ~one iteration per node per 10k-tx block commit. Token
         tests are inlined as `len(c) == 9` (real child refs are always 32
         bytes) and leaves — the bulk of every batch — skip the patch
         machinery entirely; the Python bookkeeping here must stay well
         under the per-node ctypes crossing it saves, or deferral is a
-        net loss at merkle_workers=1."""
+        net loss on one core."""
         resolved: Dict[bytes, bytes] = {}
-        items: List[Tuple[bytes, bytes]] = []
-        hash_s = 0.0
         trie_node = int(EntryPrefix.TRIE_NODE).to_bytes(2, "big")
         pending = self._pending
         cache = self._cache
@@ -571,12 +390,8 @@ class Trie:
                             break
                 patched.append(n)
             encs = [n.encode() for n in patched]
-            threads = nthreads
-            if threads != 1 and sum(map(len, encs)) < MIN_HASH_THREAD_BYTES:
-                threads = 1
-            h0 = time.perf_counter()
-            hashes = keccak256_batch(encs, threads)
-            hash_s += time.perf_counter() - h0
+            big = sum(map(len, encs)) >= MIN_HASH_THREAD_BYTES
+            hashes = keccak256_batch(encs, _host_cores() if big else 1)
             metrics.observe_hist(  # lint-allow: metric-name dimensionless batch-size distribution
                 "trie_keccak_batch_size",
                 len(encs),
@@ -584,34 +399,12 @@ class Trie:
             )
             # bulk C-level stores instead of a per-node interpreted loop
             keys = [trie_node + h for h in hashes]
-            pairs = list(zip(keys, encs))
-            pending.update(pairs)
-            items.extend(pairs)
+            pending.update(zip(keys, encs))
             resolved.update(zip(tokens, hashes))
             cache.update(zip(hashes, patched))
         self._trim_cache()
-        return resolved, hash_s, items
-
-    def reset_merkle_stats(self) -> None:
-        """Zero the accumulated apply_many profile (bench phase breakdowns
-        call this before a timed section so the totals cover exactly it)."""
-        self.merkle_stats = {}
-
-    def _set_merkle_stats(
-        self, t0: float, hash_s: float, nodes: int, workers: int
-    ) -> None:
-        # ACCUMULATES across apply_many calls: a Snapshot.freeze applies
-        # one batch per subtree, and the commit-phase breakdown wants the
-        # whole-freeze totals, not the last subtree's
-        wall = time.perf_counter() - t0
-        st = self.merkle_stats
-        st["wall_s"] = st.get("wall_s", 0.0) + wall
-        st["hash_s"] = st.get("hash_s", 0.0) + hash_s
-        st["assemble_s"] = st.get("assemble_s", 0.0) + max(wall - hash_s, 0.0)
-        st["nodes"] = int(st.get("nodes", 0)) + nodes
-        st["workers"] = max(int(st.get("workers", 0)), workers)
-        metrics.inc("trie_nodes_hashed_total", nodes)
-        metrics.set_gauge("trie_merkle_workers", workers)
+        metrics.inc("trie_nodes_hashed_total", len(resolved))
+        return resolved
 
     def _bulk(self, node_hash: bytes, ops, depth: int) -> bytes:
         if not ops:

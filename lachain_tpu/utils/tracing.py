@@ -723,9 +723,6 @@ PHASES = (
     "tpke_verify",
     "tpke_decrypt",
     "exec",
-    "exec_plan",
-    "exec_lanes",
-    "exec_merge",
     "merkle",
     "commit",
 )
@@ -735,15 +732,8 @@ _PHASE_PRIORITY = {
     # merkle outranks exec: the merkle.freeze span nests inside exec.block,
     # and commit attribution must separate hashing from tx execution.
     # exec outranks commit: the block-execution span nests inside the
-    # root_produce commit crossing, and the refactored executor
-    # (core/parallel_exec.py) is what the exec column exists to expose
+    # root_produce commit crossing
     "merkle": 2,
-    # the lane pipeline's three steps (core/parallel_exec.py) nest inside
-    # exec.block and split it: `exec` keeps what they do not cover, which is
-    # all of it where a block ran on the serial executor
-    "exec_plan": 2.5,
-    "exec_lanes": 2.5,
-    "exec_merge": 2.5,
     "exec": 3,
     "propose": 4,
     "commit": 5,
@@ -773,9 +763,6 @@ _SPAN_PHASE = {
     "hb.era_decrypt": "tpke_decrypt",
     "hb.apply_era_results": "tpke_decrypt",
     "exec.block": "exec",
-    "exec.plan": "exec_plan",
-    "exec.lanes": "exec_lanes",
-    "exec.merge": "exec_merge",
     "merkle.freeze": "merkle",
 }
 

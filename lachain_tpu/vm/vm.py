@@ -34,8 +34,9 @@ CODE_PREFIX = b"c:"  # 'contracts' subtree: code by address
 
 # decoded-module cache: Module objects are immutable after decode, so
 # repeated/nested invocations skip the binary re-parse (keyed by code hash).
-# Lock-guarded: parallel execution lanes (core/parallel_exec.py) decode
-# concurrently, and an unguarded move_to_end can race a sibling's eviction
+# Lock-guarded: under era pipelining (core/devnet.py) blocks execute on the
+# scheduler's thread and its tail thread at once, and an unguarded
+# move_to_end can race a sibling's eviction
 _MODULE_CACHE: "OrderedDict[bytes, object]" = None  # type: ignore[assignment]
 _MODULE_CACHE_MAX = 64
 _MODULE_CACHE_LOCK = threading.Lock()

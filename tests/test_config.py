@@ -97,3 +97,97 @@ def test_v6_to_v7_storage_engine_migration():
     assert migrate(v6)["storage"]["engine"] == "lsm"
     # fresh v7 configs default to the native engine
     assert NodeConfig.from_dict({"version": 7}).storage_engine == "lsm"
+
+
+@pytest.mark.parametrize(
+    "execution",
+    [{"lanes": 8}, {"merkleWorkers": 8}, {"lanes": 8, "merkleWorkers": 8}],
+    ids=["lanes", "merkleWorkers", "both"],
+)
+def test_a_retired_execution_key_loads_warns_once_and_commits_serially(
+    tmp_path, caplog, monkeypatch, execution
+):
+    """`execution.lanes` and `execution.merkleWorkers` chose threaded routes
+    that are gone. A config that still sets them is an operator's file: it
+    loads, the node it builds commits blocks on the one path, and the
+    start-up log says once which keys do nothing."""
+    import asyncio
+    import json
+    import logging
+    import random
+
+    from lachain_tpu import cli
+    from lachain_tpu.consensus.keys import trusted_key_gen
+    from lachain_tpu.core import execution as ex
+    from lachain_tpu.core import system_contracts as sc
+    from lachain_tpu.core.types import (
+        BlockHeader,
+        MultiSig,
+        Transaction,
+        sign_transaction,
+        tx_merkle_root,
+    )
+    from lachain_tpu.core.vault import PrivateWallet
+    from lachain_tpu.crypto import ecdsa
+
+    class Rng:
+        def __init__(self, seed):
+            self._r = random.Random(seed)
+
+        def randbelow(self, n):
+            return self._r.randrange(n)
+
+    # _build_node sets the staking cycle globals: restore them afterwards
+    for name in ("CYCLE_DURATION", "VRF_SUBMISSION_PHASE", "ATTENDANCE_DETECTION_DURATION"):
+        monkeypatch.setattr(sc, name, getattr(sc, name))
+    chain = 97
+    pub, _privs = trusted_key_gen(4, 1, rng=Rng(2))
+    user = ecdsa.generate_private_key(Rng(3))
+    sender = ecdsa.address_from_public_key(ecdsa.public_key_bytes(user))
+    wallet = str(tmp_path / "wallet.json")
+    PrivateWallet(ecdsa_priv=ecdsa.generate_private_key(Rng(4)), path=wallet).save()
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps({
+        "version": CURRENT_VERSION,
+        "genesis": {
+            "chainId": chain,
+            "consensusKeys": pub.encode().hex(),
+            "validatorIndex": -1,
+            "balances": {"0x" + sender.hex(): str(10**18)},
+        },
+        "vault": {"path": wallet},
+        "execution": execution,
+    }))
+    cfg = NodeConfig.load(str(path))
+    keys = [f"execution.{k}" for k in execution]
+    assert cfg.ignored_keys == keys
+
+    async def build_and_commit():
+        with caplog.at_level(logging.WARNING, logger="lachain_tpu.cli"):
+            node, _peers = cli._build_node(cfg)
+        bm = node.block_manager
+        for height in (1, 2):
+            txs = bm.order_transactions([
+                sign_transaction(
+                    Transaction(to=b"\x42" * 20, value=5, nonce=2 * (height - 1) + i,
+                                gas_price=1, gas_limit=21000),
+                    user, chain,
+                )
+                for i in range(2)
+            ], chain)
+            em = bm.emulate(txs, height)
+            header = BlockHeader(
+                index=height, prev_block_hash=bm.block_by_height(height - 1).hash(),
+                merkle_root=tx_merkle_root([t.hash() for t in txs]),
+                state_hash=em.state_hash, nonce=height,
+            )
+            bm.execute_block(header, txs, MultiSig(()))
+        return node
+
+    node = asyncio.run(build_and_commit())
+    assert node.block_manager.current_height() == 2
+    assert ex.get_balance(node.state.new_snapshot(), b"\x42" * 20) == 20
+    warned = [r.getMessage() for r in caplog.records if r.name == "lachain_tpu.cli"]
+    assert len(warned) == 1, warned
+    assert all(key in warned[0] for key in keys)
+    assert "no effect" in warned[0]

@@ -1,14 +1,15 @@
-"""Which path a block takes, and that the path never shows (PR 39).
+"""Which hashing route a block's freeze takes, and that the route never
+shows.
 
-`execution.lanes` and `execution.merkleWorkers` choose between a serial
-and a threaded route through `BlockManager.emulate` and `Trie.apply_many`.
-Held here, over the benchmark's two block shapes (perfbench/traffic/
-full.json: 700 transfers from 256 senders to 250 recipients; smallbank-
-full.json: 700 calls of one contract) and a block of 24 disjoint groups:
-every setting gives the same receipts, roots and pending node set; a
-setting of 0 sends neither benchmark shape to a thread pool while a
-forced N > 1 still does; and a freeze that went through shard workers
-leaves its nodes in the caller's cache as a serial one does.
+`Trie.apply_many` picks from its input: each node hashed as it is stored
+below MIN_DEFER_OPS ops, deferred level-batched hashing from there on, and
+native hashing threads only for a level of MIN_HASH_THREAD_BYTES. Held
+here, over the benchmark's two block shapes (perfbench/traffic/full.json:
+700 transfers from 256 senders to 250 recipients; smallbank-full.json: 700
+calls of one contract) and a block of 24 disjoint groups: every route gives
+the same receipts, roots and pending node set as the immediate walk; a
+block of hb64.full's size hashes on one thread; and a freeze leaves what it
+wrote in the handle's cache on either side of the byte floor.
 """
 import itertools
 import json
@@ -17,6 +18,7 @@ import random
 
 import pytest
 
+import lachain_tpu.storage.trie as trie_mod
 from lachain_tpu.core import block_manager as bm_mod
 from lachain_tpu.core import execution, system_contracts
 from lachain_tpu.core.block_manager import BlockManager
@@ -29,8 +31,7 @@ from lachain_tpu.core.types import (
 from lachain_tpu.crypto import ecdsa
 from lachain_tpu.storage.kv import MemoryKV
 from lachain_tpu.storage.state import StateManager
-from lachain_tpu.storage.trie import EMPTY_ROOT, MIN_SHARD_OPS, Trie
-from lachain_tpu.utils import metrics
+from lachain_tpu.storage.trie import EMPTY_ROOT, Trie, _host_cores
 from perfbench import traffic, traffic_smallbank
 
 pytestmark = [pytest.mark.exec, pytest.mark.trie]
@@ -39,10 +40,15 @@ CHAIN = 225
 SEED = 39
 BLOCK = 700
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SETTINGS = [(0, 0), (1, 1), (8, 8)]  # (exec_lanes, merkle_workers)
 SHAPES = ["transfers", "smallbank", "groups24"]
-LANE_BLOCKS = "exec_blocks_parallel_total"
-SHARDED = "trie_sharded_applies_total"
+# route -> (MIN_DEFER_OPS, MIN_HASH_THREAD_BYTES): the immediate walk with
+# the defer floor raised past any block, deferred hashing at the program's
+# floors, and deferred hashing with every level sent to the threads
+ROUTES = {
+    "immediate": (1 << 30, trie_mod.MIN_HASH_THREAD_BYTES),
+    "deferred": (trie_mod.MIN_DEFER_OPS, trie_mod.MIN_HASH_THREAD_BYTES),
+    "threaded": (trie_mod.MIN_DEFER_OPS, 0),
+}
 
 
 def _mix(name):
@@ -89,12 +95,28 @@ def _blocks(shape):
     return _BLOCKS[shape]
 
 
-def _emulate_two_blocks(shape, lanes, workers):
-    """Both blocks through BlockManager.emulate under one setting, the first
-    committed before the second. -> per block (receipts, roots, node keys)."""
-    addrs, genesis, blocks = _blocks(shape)
+def _route(monkeypatch, route):
+    """Set the trie's floors for `route`; -> the list that records, per
+    native keccak batch, (threads asked for, bytes hashed)."""
+    defer_from, thread_from = ROUTES[route]
+    monkeypatch.setattr(trie_mod, "MIN_DEFER_OPS", defer_from)
+    monkeypatch.setattr(trie_mod, "MIN_HASH_THREAD_BYTES", thread_from)
+    calls = []
+    real = trie_mod.keccak256_batch
+
+    def spy(encs, threads):
+        calls.append((threads, sum(map(len, encs))))
+        return real(encs, threads)
+
+    monkeypatch.setattr(trie_mod, "keccak256_batch", spy)
+    return calls
+
+
+def _emulate(shape, blocks):
+    """`blocks` through BlockManager.emulate, each committed before the
+    next. -> per block (receipts, roots, node keys)."""
+    addrs, genesis, _ = _blocks(shape)
     state = StateManager(MemoryKV())
-    state.trie.merkle_workers = workers
     executer = system_contracts.make_executer(CHAIN)
     snap = state.new_snapshot()
     for a in addrs:
@@ -102,11 +124,11 @@ def _emulate_two_blocks(shape, lanes, workers):
     for i, stx in enumerate(genesis):
         assert executer.execute(snap, stx, 0, i).ok
     state.commit(0, snap.freeze())
-    bm = BlockManager(state._kv, state, executer, lanes=lanes)
+    bm = BlockManager(state._kv, state, executer)
     out = []
     for height, txs in enumerate(blocks, start=1):
         ordered = BlockManager.order_transactions(txs, CHAIN)
-        bm_mod._EMULATE_MEMO.clear()  # every setting shares one purity key
+        bm_mod._EMULATE_MEMO.clear()  # every route shares one purity key
         em = bm.emulate(ordered, height)
         assert all(r.status == 1 for r in em.receipts), shape
         nodes = dict(state.trie.peek_pending())
@@ -119,40 +141,46 @@ _ORACLE = {}
 
 
 def _oracle(shape):
+    """Both blocks of a shape on the immediate walk."""
     if shape not in _ORACLE:
-        _ORACLE[shape] = _emulate_two_blocks(shape, 1, 1)
+        with pytest.MonkeyPatch.context() as m:
+            _route(m, "immediate")
+            _ORACLE[shape] = _emulate(shape, _blocks(shape)[2])
     return _ORACLE[shape]
 
 
-def _counters():
-    return [metrics.counter_value(n) or 0 for n in (LANE_BLOCKS, SHARDED)]
-
-
-@pytest.mark.parametrize("lanes,workers", SETTINGS)
+@pytest.mark.parametrize("route", list(ROUTES))
 @pytest.mark.parametrize("shape", SHAPES)
-def test_every_setting_gives_the_same_bytes(shape, lanes, workers):
-    got = _emulate_two_blocks(shape, lanes, workers)
-    for height, (mine, want) in enumerate(zip(got, _oracle(shape)), start=1):
-        assert mine[0] == want[0], (shape, height, "receipts")
-        assert mine[1] == want[1], (shape, height, "roots")
-        assert mine[2] == want[2], (shape, height, "pending node set")
+def test_every_setting_gives_the_same_bytes(shape, route, monkeypatch):
+    want = _oracle(shape)
+    calls = _route(monkeypatch, route)
+    got = _emulate(shape, _blocks(shape)[2])
+    for height, (mine, oracle) in enumerate(zip(got, want), start=1):
+        assert mine[0] == oracle[0], (shape, height, "receipts")
+        assert mine[1] == oracle[1], (shape, height, "roots")
+        assert mine[2] == oracle[2], (shape, height, "pending node set")
+    # the route was the one asked for
+    if route == "immediate":
+        assert calls == []
+    else:
+        assert calls
+        want_threads = _host_cores() if route == "threaded" else 1
+        assert {threads for threads, _ in calls} == {want_threads}, route
 
 
-@pytest.mark.parametrize("shape", ["transfers", "smallbank"])
-def test_zero_sends_a_benchmark_block_to_no_thread_pool(shape):
-    before = _counters()
-    _emulate_two_blocks(shape, 0, 0)
-    assert _counters() == before
-
-
-@pytest.mark.parametrize("shape", ["transfers", "smallbank"])
-def test_a_forced_count_still_takes_lanes_and_shard_workers(shape):
-    before = _counters()
-    _emulate_two_blocks(shape, 8, 8)
-    lane_blocks, sharded = (a - b for a, b in zip(_counters(), before))
-    assert lane_blocks == 2
-    # block 2 at least: balances and receipts over a root that is not empty
-    assert sharded >= 2
+def test_a_block_of_hb64_size_hashes_on_one_thread(monkeypatch):
+    """hb64.full's blocks hold up to 1000 transfers (perfbench/configs/
+    hb64-sim.json txs_per_block): no level of their freeze comes near
+    MIN_HASH_THREAD_BYTES, so the program's byte rule hashes each on the
+    calling thread."""
+    _, _, (first, second) = _blocks("transfers")
+    block = first + second[: 1000 - len(first)]
+    assert len(block) == 1000
+    calls = _route(monkeypatch, "deferred")
+    _emulate("transfers", [block])
+    assert calls and {threads for threads, _ in calls} == {1}
+    largest = max(size for _, size in calls)
+    assert largest < trie_mod.MIN_HASH_THREAD_BYTES // 2, largest
 
 
 class CountingKV(MemoryKV):
@@ -165,15 +193,16 @@ class CountingKV(MemoryKV):
         return super().get(key)
 
 
-@pytest.mark.parametrize("workers", [1, 8])
-def test_the_next_freeze_reads_back_no_node_the_last_one_wrote(workers):
-    """Commit a freeze, then freeze again over the same keys: whichever path
-    the first took, what it wrote is in the handle's cache, so the second
-    asks the store for none of it."""
-    rng = random.Random(workers)
+@pytest.mark.parametrize("route", ["deferred", "threaded"])
+def test_the_next_freeze_reads_back_no_node_the_last_one_wrote(route, monkeypatch):
+    """Commit a freeze, then freeze again over the same keys: whichever
+    side of the byte floor the first hashed on, what it wrote is in the
+    handle's cache, so the second asks the store for none of it."""
+    calls = _route(monkeypatch, route)
+    rng = random.Random(route)
     kv = CountingKV()
     trie = Trie(kv)
-    keys = [rng.randbytes(20) for _ in range(3 * MIN_SHARD_OPS)]
+    keys = [rng.randbytes(20) for _ in range(1536)]
 
     def commit():
         items = trie.peek_pending()
@@ -181,13 +210,13 @@ def test_the_next_freeze_reads_back_no_node_the_last_one_wrote(workers):
         trie.confirm_pending(items)
         return {k for k, _ in items}
 
-    root = trie.apply_many(EMPTY_ROOT, {k: b"0" for k in keys}, workers=1)
+    root = trie.apply_many(EMPTY_ROOT, {k: b"0" for k in keys})
     commit()
-    sharded_before = metrics.counter_value(SHARDED) or 0
-    root = trie.apply_many(root, {k: b"1" for k in keys[: 2 * MIN_SHARD_OPS]}, workers=workers)
-    assert (metrics.counter_value(SHARDED) or 0) - sharded_before == (workers > 1)
+    root = trie.apply_many(root, {k: b"1" for k in keys[:1024]})
     wrote = commit()
-    assert len(wrote) > 2 * MIN_SHARD_OPS
+    assert len(wrote) > 1024
     kv.read.clear()
-    trie.apply_many(root, {k: b"2" for k in keys[: 2 * MIN_SHARD_OPS]}, workers=workers)
+    trie.apply_many(root, {k: b"2" for k in keys[:1024]})
     assert not wrote.intersection(kv.read)
+    want_threads = _host_cores() if route == "threaded" else 1
+    assert {threads for threads, _ in calls} == {want_threads}

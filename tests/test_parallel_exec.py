@@ -1,28 +1,21 @@
-"""Optimistic lane-parallel execution tests.
+"""Block execution tests.
 
-The contract under test (core/parallel_exec.py): for ANY ordered block,
-the lane/merge pipeline produces receipts, frozen roots and trie node
-sets bit-identical to the serial oracle — the only thing parallelism may
-change is wall-clock. Pinned here by a randomized differential over
-transfers, failing txs, system-contract calls and wasm invocations with
-engineered conflicts, plus directed tests for the merge validator, the
-lane planner, the delta-checkpoint undo log and the sharded pool.
+A block executes on one path: the serial executor over a snapshot, then
+`Snapshot.freeze`. Pinned here: over random executor blocks (transfers,
+failing txs, system-contract calls, wasm invocations) the deferred freeze
+gives receipts, roots and trie node sets bit-identical to the immediate
+walk; the delta-checkpoint undo log; canonical ordering; the sharded
+pool; the era report's exec row; and BlockManager's one lane.
 """
 import random
 import threading
 
 import pytest
 
+import lachain_tpu.storage.trie as trie_mod
 from lachain_tpu.core import block_manager as bm_mod
 from lachain_tpu.core import execution, system_contracts
 from lachain_tpu.core.block_manager import BlockManager
-from lachain_tpu.core.parallel_exec import (
-    MIN_PARALLEL_TXS,
-    RecordingSnapshot,
-    execute_block_parallel,
-    plan_lanes,
-    resolve_lanes,
-)
 from lachain_tpu.core.tx_pool import TransactionPool
 from lachain_tpu.core.types import (
     SignedTransaction,
@@ -91,26 +84,25 @@ def _fresh_chain():
     return state, executer, roots, caddr
 
 
-def _run_serial(ordered):
+def _run(ordered, monkeypatch, defer_from, thread_from):
+    """Block 1 on the serial executor, frozen with the trie's floors at
+    `defer_from` ops (MIN_DEFER_OPS) and `thread_from` bytes a level
+    (MIN_HASH_THREAD_BYTES) -> (receipts, roots, node keys, nodes the
+    deferred route hashed)."""
     state, executer, base, _ = _fresh_chain()
     snap = state.new_snapshot(base)
     receipts = [
         executer.execute(snap, stx, 1, i).receipt
         for i, stx in enumerate(ordered)
     ]
-    roots = snap.freeze()
+    hashed = metrics.counter_value("trie_nodes_hashed_total") or 0
+    with monkeypatch.context() as m:
+        m.setattr(trie_mod, "MIN_DEFER_OPS", defer_from)
+        m.setattr(trie_mod, "MIN_HASH_THREAD_BYTES", thread_from)
+        roots = snap.freeze()
+    hashed = (metrics.counter_value("trie_nodes_hashed_total") or 0) - hashed
     nodes = {k for k, _ in state.trie.peek_pending()}
-    return receipts, roots, nodes
-
-
-def _run_parallel(ordered, n_lanes, partition=None):
-    state, executer, base, _ = _fresh_chain()
-    merged, receipts, stats = execute_block_parallel(
-        executer, state, ordered, 1, base, n_lanes, partition=partition
-    )
-    roots = merged.freeze()
-    nodes = {k for k, _ in state.trie.peek_pending()}
-    return receipts, roots, nodes, stats
+    return receipts, roots, nodes, hashed
 
 
 def _random_block(rng, caddr, min_txs=24, max_txs=48):
@@ -161,201 +153,82 @@ def _random_block(rng, caddr, min_txs=24, max_txs=48):
 
 
 # ---------------------------------------------------------------------------
-# the headline differential: parallel == serial, bit for bit
+# the headline differential: deferred freeze == immediate walk, bit for bit
 # ---------------------------------------------------------------------------
 
 
-def test_differential_parallel_vs_serial_randomized():
-    """>=200 seeded random blocks: receipts, state roots AND the trie
-    node set must be bit-identical between the serial oracle and the
-    lane/merge pipeline at random lane counts."""
-    total_validated = total_stragglers = 0
+def test_differential_parallel_vs_serial_randomized(monkeypatch):
+    """200 seeded random blocks: receipts, state roots AND the trie node
+    set must be bit-identical between the immediate per-node walk and the
+    deferred level-batched freeze, every other block with each level on
+    the hashing threads."""
+    failed = succeeded = 0
     _, _, _, caddr = _fresh_chain()
     for seed in range(200):
         rng = random.Random(seed)
         ordered = _random_block(rng, caddr)
-        s_receipts, s_roots, s_nodes = _run_serial(ordered)
-        # the footprint planner is conservative (overlapping accounts
-        # coalesce into one lane), so every third block ignores it and
-        # scatters txs round-robin — the adversarial placement that makes
-        # the merge validator actually catch cross-lane conflicts
-        partition = (lambda i, stx: i) if seed % 3 == 0 else None
-        p_receipts, p_roots, p_nodes, stats = _run_parallel(
-            ordered, rng.randint(2, 4), partition=partition
+        i_receipts, i_roots, i_nodes, i_hashed = _run(
+            ordered, monkeypatch, 1 << 30, trie_mod.MIN_HASH_THREAD_BYTES
         )
-        assert [r.encode() for r in p_receipts] == [
-            r.encode() for r in s_receipts
+        d_receipts, d_roots, d_nodes, d_hashed = _run(
+            ordered, monkeypatch, 1,
+            0 if seed % 2 else trie_mod.MIN_HASH_THREAD_BYTES,
+        )
+        assert [r.encode() for r in d_receipts] == [
+            r.encode() for r in i_receipts
         ], f"receipt divergence at seed {seed}"
-        assert p_roots == s_roots, f"root divergence at seed {seed}"
-        assert p_roots.state_hash() == s_roots.state_hash()
-        assert p_nodes == s_nodes, f"trie node set divergence at seed {seed}"
-        total_validated += stats.validated
-        total_stragglers += stats.stragglers
-        assert stats.validated + stats.stragglers == stats.txs
-    # the mix must exercise BOTH merge outcomes or the test proves nothing
-    assert total_validated > 0
-    assert total_stragglers > 0
-
-
-def test_forced_full_conflict_degrades_to_one_serial_pass():
-    """partition= forces a single sender's nonce chain round-robin across
-    lanes: every tx after the first fails lane validation. Degradation
-    contract: stragglers re-execute at most once (== one serial pass) and
-    the result is STILL bit-identical to the oracle."""
-    priv, _ = _ACCOUNTS[1]
-    to = _ACCOUNTS[2][1]
-    ordered = BlockManager.order_transactions(
-        [_tx(priv, to, 10 + i, i) for i in range(40)], CHAIN
-    )
-    s_receipts, s_roots, s_nodes = _run_serial(ordered)
-    p_receipts, p_roots, p_nodes, stats = _run_parallel(
-        ordered, 4, partition=lambda i, stx: i
-    )
-    # tx0 read the base state and validates; every other tx read a stale
-    # nonce in its lane and re-executes exactly once
-    assert stats.validated == 1
-    assert stats.stragglers == len(ordered) - 1
-    assert stats.stragglers <= len(ordered)  # <= one serial pass, by count
-    assert [r.encode() for r in p_receipts] == [r.encode() for r in s_receipts]
-    assert p_roots == s_roots
-    assert p_nodes == s_nodes
-    assert all(r.status == 1 for r in p_receipts)
-
-
-def test_block_manager_lanes_bit_identical_and_parallel_path_taken():
-    """The emulate() seam: a lanes=4 BlockManager returns the same
-    EmulationResult as the lanes=1 oracle on a >= MIN_PARALLEL_TXS block,
-    via the actual parallel path (counter increment proves it ran)."""
-    priv_a, a = _ACCOUNTS[1]
-    priv_b, b = _ACCOUNTS[2]
-    n = MIN_PARALLEL_TXS + 8
-    txs = [_tx(priv_a, b, 5, i) for i in range(n // 2)]
-    txs += [_tx(priv_b, a, 7, i) for i in range(n - n // 2)]
-    ordered = BlockManager.order_transactions(txs, CHAIN)
-
-    def emulate_with(lanes):
-        state, executer, _, _ = _fresh_chain()
-        kv = state._kv
-        bm = BlockManager(kv, state, executer, lanes=lanes)
-        bm_mod._EMULATE_MEMO.clear()  # both runs share one purity key
-        return bm.emulate(ordered, 1)
-
-    before = metrics.counter_value("exec_blocks_parallel_total") or 0
-    em_serial = emulate_with(1)
-    em_parallel = emulate_with(4)
-    after = metrics.counter_value("exec_blocks_parallel_total") or 0
-    assert after == before + 1
-    assert em_parallel.state_hash == em_serial.state_hash
-    assert em_parallel.roots == em_serial.roots
-    assert [r.encode() for r in em_parallel.receipts] == [
-        r.encode() for r in em_serial.receipts
-    ]
-    assert em_parallel.event_addrs == em_serial.event_addrs
+        assert d_roots == i_roots, f"root divergence at seed {seed}"
+        assert d_roots.state_hash() == i_roots.state_hash()
+        assert d_nodes == i_nodes, f"trie node set divergence at seed {seed}"
+        # each leg took its route: only the deferred one batch-hashes
+        assert i_hashed == 0 and d_hashed == len(d_nodes), seed
+        failed += sum(r.status != 1 for r in d_receipts)
+        succeeded += sum(r.status == 1 for r in d_receipts)
+    # the mix must hold failing and applied txs or the test proves little
+    assert failed > 0
+    assert succeeded > 0
 
 
 # ---------------------------------------------------------------------------
-# lane planning
+# BlockManager's one lane
 # ---------------------------------------------------------------------------
 
 
-def test_plan_lanes_same_sender_single_lane_in_order():
-    priv, _ = _ACCOUNTS[1]
-    ordered = [_tx(priv, _ACCOUNTS[2][1], 1, i) for i in range(10)]
-    lanes = plan_lanes(ordered, CHAIN, 4)
-    populated = [l for l in lanes if l]
-    assert len(populated) == 1  # one nonce chain -> one lane
-    assert [i for i, _ in populated[0]] == list(range(10))
-
-
-def test_plan_lanes_transitive_footprints_coalesce():
-    # A->X, B->X and B->Y, C->Y: one connected component -> one lane
-    pa, _ = _ACCOUNTS[1]
-    pb, _ = _ACCOUNTS[2]
-    pc, _ = _ACCOUNTS[3]
-    x, y = _ACCOUNTS[4][1], _ACCOUNTS[5][1]
-    ordered = [
-        _tx(pa, x, 1, 0),
-        _tx(pb, x, 1, 0),
-        _tx(pb, y, 1, 1),
-        _tx(pc, y, 1, 0),
-    ]
-    lanes = plan_lanes(ordered, CHAIN, 4)
-    populated = [l for l in lanes if l]
-    assert len(populated) == 1
-    # disjoint footprints spread across lanes
-    ordered2 = [_tx(pa, x, 1, 0), _tx(pc, y, 1, 0)]
-    lanes2 = plan_lanes(ordered2, CHAIN, 2)
-    assert all(len(l) == 1 for l in lanes2)
-
-
-def test_plan_lanes_deterministic_and_exhaustive():
-    rng = random.Random(42)
-    _, _, _, caddr = _fresh_chain()
-    ordered = _random_block(rng, caddr)
-    a = plan_lanes(ordered, CHAIN, 3)
-    b = plan_lanes(ordered, CHAIN, 3)
-    assert a == b
-    flat = sorted(i for lane in a for i, _ in lane)
-    assert flat == list(range(len(ordered)))  # every tx exactly once
-    for lane in a:
-        assert [i for i, _ in lane] == sorted(i for i, _ in lane)
-
-
-def test_resolve_lanes():
-    assert resolve_lanes(1) == 1
-    assert resolve_lanes(3) == 3
-    assert resolve_lanes(0) >= 1
+@pytest.mark.parametrize("lanes", [1, 0, 4])
+def test_block_manager_takes_one_lane_only(lanes):
+    """`lanes=1` is what perfbench/reference.py passes; any other count
+    would ask for a path that no longer exists."""
+    state, executer, _, _ = _fresh_chain()
+    if lanes == 1:
+        bm = BlockManager(state._kv, state, executer, lanes=lanes)
+        assert bm.current_height() == 0
+    else:
+        with pytest.raises(ValueError, match="one lane"):
+            BlockManager(state._kv, state, executer, lanes=lanes)
 
 
 # ---------------------------------------------------------------------------
-# RecordingSnapshot: the read/write footprint the merge validates
+# Snapshot restore: a reverted write leaves nothing behind
 # ---------------------------------------------------------------------------
 
 
-def _recording_snap():
+def test_snapshot_restore_drops_reverted_writes():
     state, _, base, _ = _fresh_chain()
-    return RecordingSnapshot(state.trie.fork(), base)
-
-
-def test_recording_snapshot_reads_and_delta():
-    snap = _recording_snap()
+    snap = state.new_snapshot(base)
     a = _ACCOUNTS[1][1]
-    snap.begin_tx()
-    bal = execution.get_balance(snap, a)  # external read
-    execution.set_balance(snap, a, bal - 1)
-    execution.get_balance(snap, a)  # own-write read: no dependency
-    reads, delta = snap.end_tx()
-    assert list(reads) == [("balances", b"b:" + a)]
-    assert [(t, k) for t, k, _ in delta] == [("balances", b"b:" + a)]
-
-
-def test_recording_snapshot_restore_drops_reverted_writes():
-    snap = _recording_snap()
-    snap.begin_tx()
+    was = execution.get_balance(snap, a)
     cp = snap.checkpoint()
+    # two writes of one key after the checkpoint: the undo log must pop
+    # both to leave the key absent, not at its first written value
     snap.put("storage", b"k1", b"v1")
     snap.put("storage", b"k1", b"v2")
+    execution.set_balance(snap, a, was - 1)
+    execution.set_balance(snap, a, was - 2)
     snap.restore(cp)
-    # a fully reverted write exports NO delta (it would clobber an
-    # interleaved lane's write at merge time)...
-    reads, delta = snap.end_tx()
-    assert delta == []
-    snap.begin_tx()
-    # ...and a post-restore read of that key IS an external dependency
+    assert snap._writes["storage"] == {} and snap._writes["balances"] == {}
+    # reads fall through to the base roots again
     assert snap.get("storage", b"k1") is None
-    reads, _ = snap.end_tx()
-    assert ("storage", b"k1") in reads
-
-
-def test_recording_snapshot_partial_restore_keeps_live_writes():
-    snap = _recording_snap()
-    snap.begin_tx()
-    snap.put("storage", b"k", b"keep")
-    cp = snap.checkpoint()
-    snap.put("storage", b"k", b"drop")
-    snap.restore(cp)
-    _, delta = snap.end_tx()
-    assert delta == [("storage", b"k", b"keep")]
+    assert execution.get_balance(snap, a) == was
 
 
 # ---------------------------------------------------------------------------
@@ -520,7 +393,7 @@ def test_pool_sharded_semantics_preserved():
 def test_era_report_has_exec_phase_row():
     assert "exec" in tracing.PHASES
     state, executer, _, _ = _fresh_chain()
-    bm = BlockManager(state._kv, state, executer, lanes=1)
+    bm = BlockManager(state._kv, state, executer)
     priv, _ = _ACCOUNTS[1]
     txs = [_tx(priv, _ACCOUNTS[2][1], 1, i) for i in range(4)]
     bm_mod._EMULATE_MEMO.clear()
@@ -530,26 +403,8 @@ def test_era_report_has_exec_phase_row():
     assert "exec" in report["phases"]
     ent = next(e for e in report["eras"] if e["era"] == 7)
     assert ent["phases_s"]["exec"] > 0
-    assert "exec" in tracing.era_report_table(report).splitlines()[0]
-    # a serial block has no lane pipeline: the row stays whole
-    split = ("exec_plan", "exec_lanes", "exec_merge")
-    assert all(p in report["phases"] for p in split)
-    assert all(ent["phases_s"][p] == 0 for p in split)
-    # a block through the lanes splits it into plan / lanes / merge
-    bm4 = BlockManager(state._kv, state, executer, lanes=4)
-    many = [
-        _tx(priv, _ACCOUNTS[2 + i % 3][1], 1, i)
-        for i in range(MIN_PARALLEL_TXS)
-    ]
-    bm_mod._EMULATE_MEMO.clear()
-    with tracing.span("era", era=8):
-        bm4.emulate(many, 8)
-    report = tracing.era_report()
-    ent = next(e for e in report["eras"] if e["era"] == 8)
-    assert all(ent["phases_s"][p] > 0 for p in split)
+    assert ent["phases_s"]["merkle"] > 0  # the freeze nests inside exec.block
     header = tracing.era_report_table(report).splitlines()[0]
-    assert all(p in header for p in split)
-    lanes = [s for s in tracing.snapshot() if s["name"] == "exec.lanes"][-1]
-    merge = [s for s in tracing.snapshot() if s["name"] == "exec.merge"][-1]
-    assert lanes["args"]["largest_lane"] == MIN_PARALLEL_TXS  # one sender
-    assert lanes["args"]["lanes"] == 1 and merge["args"]["stragglers"] == 0
+    assert "exec" in header
+    # one executor: the block's execution is one column, never split
+    assert [p for p in report["phases"] if p.startswith("exec")] == ["exec"]

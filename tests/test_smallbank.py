@@ -1,7 +1,7 @@
 """Blockbench's Smallbank contract (lachain_tpu/vm/contracts/smallbank.py)
 against the benchmark's VM-free dict model (perfbench/reference_smallbank.py):
-both VM tiers, wrap-around, the lane planner's one group, a four-validator
-devnet, the VM's counters, and the traffic generator of the cell
+both VM tiers, wrap-around, a Zipf block through BlockManager.emulate, a
+four-validator devnet, the VM's counters, and the traffic generator of the cell
 `hb7-smallbank.full` (perfbench/traffic_smallbank.py).
 """
 import json
@@ -11,8 +11,9 @@ import random
 import pytest
 
 from lachain_tpu.core import execution, system_contracts
+from lachain_tpu.core import block_manager as bm_mod
+from lachain_tpu.core.block_manager import BlockManager
 from lachain_tpu.core.devnet import Devnet
-from lachain_tpu.core.parallel_exec import execute_block_parallel
 from lachain_tpu.core.types import Transaction, sign_transaction
 from lachain_tpu.crypto import ecdsa
 from lachain_tpu.storage.kv import MemoryKV
@@ -240,44 +241,33 @@ def _zipf_block(count):
     ]
 
 
-def test_zipf_block_through_four_lanes_equals_the_serial_executor():
+def test_zipf_block_through_emulate_reads_back_the_model():
+    """The 200-call block as the cell draws it, through the block path a
+    validator runs (BlockManager.emulate, frozen and committed): the store
+    reads back the VM-free model's balances and return data."""
     block = _zipf_block(200)
-    state, executer, roots = _chain()
-    serial = state.new_snapshot(roots)
-    want = [executer.execute(serial, stx, 1, i).receipt for i, stx in enumerate(block)]
-    want_roots = serial.freeze()
-
-    state, executer, roots = _chain()
-    largest = metrics.counter_value("exec_lane_txs_largest_total")
-    merged, receipts, stats = execute_block_parallel(executer, state, block, 1, roots, 4)
-    assert [r.encode() for r in receipts] == [r.encode() for r in want]
-    assert merged.freeze() == want_roots
-    # one address in every tx.to: one footprint group, one lane, no straggler
-    assert (stats.lanes, stats.lane_sizes, stats.stragglers) == (1, [200], 0)
-    assert metrics.counter_value("exec_lane_txs_largest_total") - largest == 200
-    spans = {s["name"]: s for s in tracing.snapshot() if s["name"].startswith("exec.")}
-    assert spans["exec.lanes"]["args"]["lanes"] == 1
-    assert spans["exec.lanes"]["args"]["largest_lane"] == 200
-    assert spans["exec.merge"]["args"]["stragglers"] == 0
-    assert spans["exec.plan"]["args"]["era"] == 1
-
-
-def test_spread_over_lanes_by_force_the_hot_accounts_become_stragglers():
-    """What a planner that splits one contract's calls would meet: the same
-    block, calls dealt round-robin over 4 lanes, still bit-identical, the
-    conflicts on hot accounts re-executed by the merge."""
-    block = _zipf_block(120)
-    state, executer, roots = _chain()
-    serial = state.new_snapshot(roots)
-    want = [executer.execute(serial, stx, 1, i).receipt for i, stx in enumerate(block)]
-    want_roots = serial.freeze()
-    state, executer, roots = _chain()
-    merged, receipts, stats = execute_block_parallel(
-        executer, state, block, 1, roots, 4, partition=lambda i, stx: i
-    )
-    assert [r.encode() for r in receipts] == [r.encode() for r in want]
-    assert merged.freeze() == want_roots
-    assert stats.lanes == 4 and stats.stragglers > 0
+    state, executer, _roots = _chain()
+    bm = BlockManager(state._kv, state, executer)
+    bm_mod._EMULATE_MEMO.clear()
+    em = bm.emulate(block, 1)
+    assert all(r.status == 1 for r in em.receipts)
+    state.commit(1, em.roots)
+    bank = ref.Bank()
+    for stx, receipt in zip(block, em.receipts):
+        want = bank.apply(stx.tx.invocation)
+        assert receipt.return_data == (b"" if want is None else want.to_bytes(32, "big"))
+    snap = state.new_snapshot()
+    accounts = sorted(bank.touched)
+    words = [
+        [
+            int.from_bytes(snap.get("storage", CONTRACT + ref.storage_key(t, a)) or b"", "big")
+            for t in (ref.TAG_SAVING, ref.TAG_CHECKING)
+        ]
+        for a in accounts
+    ]
+    assert len(accounts) > 100 and words == bank.balances(accounts)
+    spans = [s for s in tracing.snapshot() if s["name"] == "exec.block"]
+    assert spans and spans[-1]["args"]["era"] == 1
 
 
 def test_vm_counters_count_calls_seconds_gas_and_storage_words():
@@ -319,7 +309,7 @@ def test_four_validators_commit_smallbank_calls_and_read_back_the_model():
     addrs = [ecdsa.address_from_public_key(ecdsa.public_key_bytes(k)) for k in keys]
     net = Devnet(
         n=4, f=1, chain_id=CHAIN, seed=3, txs_per_block=64,
-        initial_balances={a: 10**24 for a in addrs}, engine="native", exec_lanes=4,
+        initial_balances={a: 10**24 for a in addrs}, engine="native",
     )
     try:
         assert net.submit_tx(_deployment(keys[0]))

@@ -4,9 +4,9 @@
 Rule families D, L, P, E, M over `lachain_tpu/` (AST-based, zero
 dependencies) and rule B over the checkout:
 
-D. **Determinism** — the consensus modules (`consensus/`,
-   `core/parallel_exec.py`, `storage/trie.py`) must replay bit-identically:
-   two runs from the same journal/seed may never diverge. Wall-clock reads
+D. **Determinism** — the consensus modules (`consensus/`, `storage/trie.py`)
+   must replay bit-identically: two runs from the same journal/seed may
+   never diverge. Wall-clock reads
    (`time.time`, `datetime.now`), the process-global RNG (`random.*` on the
    module, unseeded `random.Random()`), entropy taps (`os.urandom`,
    `secrets.*`, `uuid.uuid4`), the builtin `hash()` (salted per process via
@@ -113,7 +113,6 @@ PACKAGE = "lachain_tpu"
 # rule D applies to these path prefixes/files (relative to the package root)
 DETERMINISTIC_PREFIXES = ("consensus/",)
 DETERMINISTIC_FILES = (
-    "core/parallel_exec.py",
     "storage/trie.py",
     # RTT estimation feeds consensus-adjacent timeout scaling: monotonic
     # clocks are fine (injected for tests), wall clock is not
