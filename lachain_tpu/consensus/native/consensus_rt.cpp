@@ -822,6 +822,13 @@ struct Engine {
   std::deque<Entry> q;
   std::vector<Validator> vals;
   Bits muted;
+  // validators hosted outside this engine (rt_set_remote): what a local
+  // validator sends them is appended to `out` once per message, in the
+  // record form rt_inject reads, instead of being queued; what they send
+  // enters through rt_inject
+  Bits remote;
+  bool any_remote = false;
+  std::string out;
   uint64_t delivered = 0;
   uint64_t opq_pending[8] = {0};  // queued opaque entries per kind (flush cue)
   bool stop_req = false;  // pulsed by Python on top-level protocol completion
@@ -907,6 +914,21 @@ struct Engine {
       if (m->refs == 0) delete m;
       return;
     }
+    if (any_remote) {
+      bool far = false;
+      for (int t = 0; t < n; t++) {
+        if (remote.test(t)) {
+          far = true;
+          continue;
+        }
+        if (m->type == MT_OPAQUE) opq_pending[m->opq_kind & 7]++;
+        m->refs++;
+        q.push_back({sender, t, m});
+      }
+      if (far) out_record(sender, -1, *m);
+      if (m->refs == 0) delete m;
+      return;
+    }
     if (m->type == MT_OPAQUE) opq_pending[m->opq_kind & 7] += n;
     for (int t = 0; t < n; t++) {
       m->refs++;
@@ -918,9 +940,91 @@ struct Engine {
       if (m->refs == 0) delete m;
       return;
     }
+    if (any_remote && remote.test(target)) {
+      out_record(sender, target, *m);
+      if (m->refs == 0) delete m;
+      return;
+    }
     if (m->type == MT_OPAQUE) opq_pending[m->opq_kind & 7]++;
     m->refs++;
     q.push_back({sender, target, m});
+  }
+
+  // -- the seam to remote validators ----------------------------------------
+  // One record form both ways (native_rt.py _SEAM_HEAD): be32 sender |
+  // be32 target (-1: every remote validator) | be32 era | u8 type |
+  // be32 agreement | be32 epoch | u8 value | u8 opq_kind | be32 shard_index |
+  // be32 len + root | be32 nbranch + (be32 len + hash)* | be32 len + data
+  void out_record(int sender, int target, const Msg& m) {
+    put_be32(out, (uint32_t)sender);
+    put_be32(out, (uint32_t)target);
+    put_be32(out, (uint32_t)m.era);
+    out.push_back((char)m.type);
+    put_be32(out, (uint32_t)m.agreement);
+    put_be32(out, (uint32_t)m.epoch);
+    out.push_back((char)m.value);
+    out.push_back((char)m.opq_kind);
+    put_be32(out, (uint32_t)m.shard_index);
+    put_be32(out, (uint32_t)m.root.size());
+    out += m.root;
+    put_be32(out, (uint32_t)m.branch.size());
+    for (auto& h : m.branch) {
+      put_be32(out, (uint32_t)h.size());
+      out += h;
+    }
+    put_be32(out, (uint32_t)m.data.size());
+    out += m.data;
+  }
+
+  // Queues the records of `p` as messages from remote senders to local
+  // targets; era >= 0 replaces every record's era. Stops at the first
+  // record that is malformed or not remote -> local; returns how many were
+  // queued.
+  size_t inject(int era, const uint8_t* p, size_t len) {
+    size_t off = 0, count = 0;
+    auto be32 = [&]() {
+      uint32_t v = get_be32(p + off);
+      off += 4;
+      return v;
+    };
+    auto field = [&](std::string& s, size_t cap) {
+      if (off + 4 > len) return false;
+      uint32_t l = be32();
+      if (l > cap || off + l > len) return false;
+      s.assign(reinterpret_cast<const char*>(p + off), l);
+      off += l;
+      return true;
+    };
+    while (off + 27 <= len) {
+      int sender = (int)be32(), target = (int)be32(), rec_era = (int)be32();
+      Msg* m = new Msg();
+      m->type = p[off++];
+      m->agreement = (int32_t)be32();
+      m->epoch = (int32_t)be32();
+      m->value = p[off++];
+      m->opq_kind = p[off++];
+      m->shard_index = (int32_t)be32();
+      bool ok = field(m->root, 64) && off + 4 <= len;
+      if (ok) {
+        uint32_t nb = be32();
+        ok = nb <= 64;
+        m->branch.resize(ok ? nb : 0);
+        for (uint32_t j = 0; ok && j < nb; j++) ok = field(m->branch[j], 64);
+      }
+      ok = ok && field(m->data, len) && m->type <= MT_OPAQUE && sender >= 0 &&
+           sender < n && remote.test(sender) && target >= 0 && target < n &&
+           !remote.test(target);
+      if (!ok) {
+        delete m;
+        break;
+      }
+      m->era = era >= 0 ? era : rec_era;
+      if (m->type == MT_OPAQUE) opq_pending[m->opq_kind & 7]++;
+      m->refs++;
+      q.push_back({sender, target, m});
+      count++;
+    }
+    return count;
   }
 
   // -- adversarial pop (simulator.py::_pop) ---------------------------------
@@ -2264,7 +2368,7 @@ void NRoot::maybe_verify() {
 
 extern "C" {
 
-int lt_crt_version() { return 7; }
+int lt_crt_version() { return 8; }
 
 // Engines are single-threaded by contract: one engine = one queue = one
 // dispatch loop. The pipelined era window (native_rt.py) therefore runs ONE
@@ -2381,6 +2485,32 @@ size_t rt_debug_state(void* h, int vid, char* buf, size_t cap) {
 }
 
 void rt_mute(void* h, int vid) { static_cast<Engine*>(h)->muted.set(vid); }
+
+// -- the seam to validators hosted elsewhere (version 8) --------------------
+// A remote validator runs no protocol here: what local validators send it is
+// kept as records (Engine::out_record) for rt_out_drain, and what it sends
+// enters through rt_inject, in the engine's own message types.
+void rt_set_remote(void* h, int vid) {
+  Engine* E = static_cast<Engine*>(h);
+  if (vid < 0 || vid >= E->n) return;
+  E->remote.set(vid);
+  E->any_remote = true;
+}
+
+// Two-call drain (pattern of rt_trace_drain): size query with buf == NULL,
+// then the copying call, which consumes the records.
+size_t rt_out_drain(void* h, uint8_t* buf, size_t cap) {
+  Engine* E = static_cast<Engine*>(h);
+  if (!buf || E->out.size() > cap) return E->out.size();
+  size_t got = E->out.size();
+  std::memcpy(buf, E->out.data(), got);
+  E->out.clear();
+  return got;
+}
+
+size_t rt_inject(void* h, int era, const uint8_t* data, size_t len) {
+  return static_cast<Engine*>(h)->inject(era, data, len);
+}
 
 void rt_advance_era(void* h, int vid, int era) {
   static_cast<Engine*>(h)->advance_era(vid, era);

@@ -27,7 +27,7 @@ import os
 import struct
 import weakref
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Set
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 from ..utils import metrics, tracing
 from ..utils.native_build import ensure_built
@@ -70,6 +70,82 @@ KIND_COIN = 2
 OWN_HB = 1
 OWN_COIN = 2
 OWN_ROOT = 4
+
+# engine message types (consensus_rt.cpp enum MsgType)
+MT_BVAL, MT_AUX, MT_CONF, MT_VAL, MT_ECHO, MT_READY, MT_OPAQUE = range(7)
+
+# -- the seam to validators hosted outside the engine -------------------------
+# One record form both ways (consensus_rt.cpp Engine::out_record/inject):
+# be32 sender | be32 target (-1: every remote validator) | be32 era |
+# u8 type | be32 agreement | be32 epoch | u8 value | u8 opq_kind |
+# be32 shard_index | be32 len + root | be32 nbranch + (be32 len + hash)* |
+# be32 len + data
+_SEAM_HEAD = struct.Struct(">iiiBiiBBiI")
+
+
+class SeamRecord(NamedTuple):
+    sender: int
+    target: int  # -1: a broadcast
+    era: int
+    type: int  # MT_*
+    agreement: int  # BB/coin: agreement; VAL/ECHO/READY: the RBC slot
+    epoch: int
+    value: int  # BVAL/AUX: the bit; CONF: the 2-bit set
+    opq_kind: int  # MT_OPAQUE: KIND_*
+    shard_index: int
+    root: bytes
+    branch: Tuple[bytes, ...]
+    data: bytes
+
+
+def encode_seam_record(
+    sender: int,
+    target: int,
+    type: int,
+    agreement: int = 0,
+    epoch: int = 0,
+    value: int = 0,
+    opq_kind: int = 0,
+    shard_index: int = 0,
+    root: bytes = b"",
+    branch: Sequence[bytes] = (),
+    data: bytes = b"",
+    era: int = 0,
+) -> bytes:
+    parts = [
+        _SEAM_HEAD.pack(
+            sender, target, era, type, agreement, epoch, value, opq_kind,
+            shard_index, len(root),
+        ),
+        root,
+        len(branch).to_bytes(4, "big"),
+    ]
+    for h in branch:
+        parts += [len(h).to_bytes(4, "big"), h]
+    parts += [len(data).to_bytes(4, "big"), data]
+    return b"".join(parts)
+
+
+def decode_seam_records(blob: bytes) -> List[SeamRecord]:
+    out = []
+    off, end = 0, len(blob)
+    while off < end:
+        head = _SEAM_HEAD.unpack_from(blob, off)
+        off += _SEAM_HEAD.size
+        root = blob[off : off + head[9]]
+        off += head[9]
+        nbranch = int.from_bytes(blob[off : off + 4], "big")
+        off += 4
+        branch = []
+        for _ in range(nbranch):
+            ln = int.from_bytes(blob[off : off + 4], "big")
+            branch.append(blob[off + 4 : off + 4 + ln])
+            off += 4 + ln
+        ln = int.from_bytes(blob[off : off + 4], "big")
+        data = blob[off + 4 : off + 4 + ln]
+        off += 4 + ln
+        out.append(SeamRecord(*head[:9], root, tuple(branch), data))
+    return out
 
 # labeled counter of every engine->Python boundary crossing; op
 # "opaque_message" is the legacy per-message callback the batched ops replace
@@ -127,7 +203,7 @@ def load_rt():
     lib = ctypes.CDLL(lib_path)
     lib.lt_crt_version.restype = ctypes.c_int
     _crt_ver = lib.lt_crt_version()
-    assert _crt_ver in (6, 7), _crt_ver
+    assert _crt_ver in (6, 7, 8), _crt_ver
     lib.rt_new.restype = ctypes.c_void_p
     lib.rt_new.argtypes = [
         ctypes.c_int,
@@ -186,6 +262,23 @@ def load_rt():
         ctypes.c_size_t,
     ]
     lib.rt_mute.argtypes = [ctypes.c_void_p, ctypes.c_int]
+    # version 8: the seam to validators hosted outside the engine
+    lib._lt_has_seam = _crt_ver >= 8
+    if lib._lt_has_seam:
+        lib.rt_set_remote.argtypes = [ctypes.c_void_p, ctypes.c_int]
+        lib.rt_out_drain.restype = ctypes.c_size_t
+        lib.rt_out_drain.argtypes = [
+            ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_uint8),
+            ctypes.c_size_t,
+        ]
+        lib.rt_inject.restype = ctypes.c_size_t
+        lib.rt_inject.argtypes = [
+            ctypes.c_void_p,
+            ctypes.c_int,
+            ctypes.c_char_p,
+            ctypes.c_size_t,
+        ]
     lib.rt_advance_era.argtypes = [ctypes.c_void_p, ctypes.c_int, ctypes.c_int]
     lib.rt_post_acs_input.argtypes = [
         ctypes.c_void_p,
@@ -635,6 +728,16 @@ class NativeEraRouter(EraRouter):
             return self._native_results[pid]
         return super().result_of(pid)
 
+    def coin_values(self, era: int) -> Dict[Tuple[int, int], bool]:
+        """(agreement, epoch) -> value of every coin this validator combined
+        in `era`; the hosts are kept until the era after next begins."""
+        hs = self._era_hosts.get(era)
+        return {
+            key: host._signer.signature.parity
+            for key, host in (hs.coins.items() if hs else ())
+            if host._signer.signature is not None
+        }
+
     def native_state(self) -> str:
         """Engine-side state of this validator's natively-owned protocols
         (for watchdog stall reports)."""
@@ -799,8 +902,19 @@ class NativeSimulatedNetwork:
         fault_plan=None,
         journals: Optional[List] = None,
         pipeline_window: int = 0,
+        committee=None,
     ):
+        # committee (consensus/committee_script.py): the other N-1 members of
+        # the committee, hosted outside this process. The engine then hosts
+        # validator 0 alone (private_keys holds its keys only); what it sends
+        # them is handed to committee.react(records), whose answers enter the
+        # engine in its own message types (rt_inject)
         self.n = public_keys.n
+        self.committee = committee
+        if committee is not None and (pipeline_window or fault_plan or muted):
+            raise ValueError(
+                "a committee network runs eras one at a time, without faults"
+            )
         self.muted = set(muted or set())
         self.fault_plan = fault_plan
         if fault_plan is not None:
@@ -883,6 +997,12 @@ class NativeSimulatedNetwork:
         # threshold for the native coin's combine trigger (CommonCoin needs
         # t+1 shares before a combine can possibly succeed)
         self._lib.rt_set_coin_need(self._h, self._coin_need)
+        hosted = range(1) if committee is not None else range(self.n)
+        if committee is not None:
+            if not self._lib._lt_has_seam:
+                raise RuntimeError("the loaded consensus engine has no seam")
+            for v in range(1, self.n):
+                self._lib.rt_set_remote(self._h, v)
         self.routers: List[NativeEraRouter] = [
             NativeEraRouter(
                 era=era,
@@ -893,7 +1013,7 @@ class NativeSimulatedNetwork:
                 extra_factories=extra_factories,
                 journal=journals[i] if journals is not None else None,
             )
-            for i in range(self.n)
+            for i in hosted
         ]
         for r in self.routers:
             r.pipeline_window = self.pipeline_window
@@ -1133,8 +1253,8 @@ class NativeSimulatedNetwork:
                 self._lib.rt_set_owned(h, vid, mask)
 
     def _sync_ownership(self) -> None:
-        for vid in range(self.n):
-            self._sync_owner(vid)
+        for r in self.routers:
+            self._sync_owner(r._my_id)
 
     def set_root_context(self, vid: int, producer, ecdsa_priv, ecdsa_pubs) -> None:
         """Give validator `vid` its block-production context so RootProtocol
@@ -1364,6 +1484,27 @@ class NativeSimulatedNetwork:
         )
         return processed
 
+    def _answer_committee(self) -> bool:
+        """Hand what the hosted validator sent the committee since the last
+        call to committee.react and queue its answers; True when any were
+        queued."""
+        size = self._lib.rt_out_drain(self._h, None, 0)
+        if not size:
+            return False
+        buf = (ctypes.c_uint8 * size)()
+        got = self._lib.rt_out_drain(self._h, buf, size)
+        queued = 0
+        for era, blob, count in self.committee.react(
+            decode_seam_records(bytes(buf)[:got])
+        ):
+            n = self._lib.rt_inject(self._h, era, blob, len(blob))
+            if n != count:
+                raise RuntimeError(
+                    f"the engine queued {n} of {count} committee messages"
+                )
+            queued += n
+        return queued > 0
+
     def post_request(self, validator: int, pid, value) -> None:
         self._sync_ownership()
         # proposal injection does the RBC encode (erasure coding) before
@@ -1389,6 +1530,10 @@ class NativeSimulatedNetwork:
                 )
                 self.delivered_count += processed
                 self._raise_cb_error()
+                # the committee answers what validator 0 sent before any
+                # batch flushes: its answers can only make the batches larger
+                if self.committee is not None and self._answer_committee():
+                    continue
                 metrics.set_gauge(
                     "consensus_dispatch_queue_depth",
                     self._lib.rt_queue_len(self._h),

@@ -49,6 +49,55 @@ class DevnetNode:
     producer: BlockProducer
 
 
+def devnet_keys(n: int, f: int, seed: int):
+    """The committee's keys (trusted_key_gen) drawn from `seed`: the same
+    for every harness given the same seed."""
+    rng = random.Random(seed)
+
+    class _Rng:
+        def randbelow(self, k):
+            return rng.randrange(k)
+
+    return trusted_key_gen(n, f, rng=_Rng())
+
+
+def make_node(
+    i: int,
+    kv: KVStore,
+    public_keys,
+    chain_id: int,
+    initial_balances: Dict[bytes, int],
+    txs_per_block: int,
+) -> DevnetNode:
+    """Validator i's store, state, chain from genesis, pool and producer."""
+    state = StateManager(kv)
+    # full system-contract registry (deploy/LRC-20/governance/staking)
+    # so the devnet exercises the same execution surface as a real node
+    executer = system_contracts.make_executer(chain_id)
+    bm = BlockManager(kv, state, executer)
+    bm.build_genesis(
+        initial_balances,
+        chain_id,
+        validator_pubs=list(public_keys.ecdsa_pub_keys),
+    )
+    pool = TransactionPool(
+        kv,
+        chain_id,
+        account_nonce=StateNonces(state),
+    )
+    producer = BlockProducer(
+        bm, pool, public_keys.n, txs_per_block, proposal_seed=i
+    )
+    return DevnetNode(
+        index=i,
+        kv=kv,
+        state=state,
+        block_manager=bm,
+        pool=pool,
+        producer=producer,
+    )
+
+
 class Devnet:
     """N-validator in-process chain with HoneyBadger consensus."""
 
@@ -92,48 +141,24 @@ class Devnet:
         self.pipeline_window = max(int(pipeline_window), 0)
         if self.pipeline_window > 0 and engine != "native":
             raise ValueError("era pipelining requires engine='native'")
-        rng = random.Random(seed)
-
-        class _Rng:
-            def randbelow(self, k):
-                return rng.randrange(k)
-
-        self.public_keys, self.private_keys = trusted_key_gen(n, f, rng=_Rng())
+        self.public_keys, self.private_keys = devnet_keys(n, f, seed)
         self.initial_balances = dict(initial_balances or {})
 
         # kv_factory(node_index) -> KVStore lets campaigns run each
         # validator on a DURABLE engine (LsmKV/SqliteKV store per node)
         # instead of the default in-memory store — the state-root identity
         # tests drive the same devnet over both engines this way
-        self.nodes: List[DevnetNode] = []
-        for i in range(n):
-            kv = kv_factory(i) if kv_factory is not None else MemoryKV()
-            state = StateManager(kv)
-            # full system-contract registry (deploy/LRC-20/governance/staking)
-            # so the devnet exercises the same execution surface as a real node
-            executer = system_contracts.make_executer(chain_id)
-            bm = BlockManager(kv, state, executer)
-            bm.build_genesis(
+        self.nodes: List[DevnetNode] = [
+            make_node(
+                i,
+                kv_factory(i) if kv_factory is not None else MemoryKV(),
+                self.public_keys,
+                chain_id,
                 self.initial_balances,
-                chain_id,
-                validator_pubs=list(self.public_keys.ecdsa_pub_keys),
+                txs_per_block,
             )
-            pool = TransactionPool(
-                kv,
-                chain_id,
-                account_nonce=StateNonces(state),
-            )
-            producer = BlockProducer(bm, pool, n, txs_per_block, proposal_seed=i)
-            self.nodes.append(
-                DevnetNode(
-                    index=i,
-                    kv=kv,
-                    state=state,
-                    block_manager=bm,
-                    pool=pool,
-                    producer=producer,
-                )
-            )
+            for i in range(n)
+        ]
 
         def root_factory_for(node: DevnetNode):
             def factory(pid, router):
@@ -401,6 +426,86 @@ class Devnet:
 
     def height(self, node: int = 0) -> int:
         return self.nodes[node].block_manager.current_height()
+
+
+class CommitteeValidator:
+    """Validator 0 of an N-member committee whose other N-1 members are
+    hosted elsewhere and reach it only as messages (`committee`, e.g.
+    consensus/committee_script.py): its own store, pool and producer, the
+    native engine with the TPKE and RBC era batchers, over a network that
+    hosts it alone. Devnet's era loop for one validator: a block is
+    committed when this validator has persisted it."""
+
+    def __init__(
+        self,
+        public_keys,
+        private_keys,
+        committee,
+        *,
+        chain_id: int = DEFAULT_CHAIN_ID,
+        seed: int = 0,
+        txs_per_block: int = 1000,
+        initial_balances: Optional[Dict[bytes, int]] = None,
+        rbc_batch: bool = True,
+        kv: Optional[KVStore] = None,
+    ):
+        from ..consensus.native_rt import NativeSimulatedNetwork
+
+        self.n, self.f = public_keys.n, public_keys.f
+        self.chain_id = chain_id
+        self.public_keys = public_keys
+        self.node = make_node(
+            0,
+            kv if kv is not None else MemoryKV(),
+            public_keys,
+            chain_id,
+            dict(initial_balances or {}),
+            txs_per_block,
+        )
+        self.net = NativeSimulatedNetwork(
+            public_keys,
+            [private_keys],
+            era=1,
+            seed=seed,
+            use_rbc_batcher=rbc_batch,
+            committee=committee,
+        )
+        self.router = self.net.routers[0]
+        self.net.set_root_context(
+            0,
+            self.node.producer,
+            private_keys.ecdsa_priv,
+            public_keys.ecdsa_pub_keys,
+        )
+
+    def submit_tx(self, stx: SignedTransaction) -> bool:
+        """A client's transaction, to this validator's pool alone."""
+        from ..utils import tracing, txtrace
+
+        with tracing.span("devnet.submit_tx", "pool"):
+            txtrace.stamp(stx.hash(), "submit")
+            return self.node.pool.add(stx)
+
+    def run_era(self, era: int, max_messages: int = 2_000_000) -> Block:
+        """One era to this validator's committed block."""
+        from ..utils import tracing
+
+        pid = M.RootProtocolId(era=era)
+        with tracing.span("era", era=era):
+            with tracing.span("era.advance", "engine", era=era):
+                self.router.advance_era(era)
+            self.net.post_request(0, pid, None)
+            ok = self.net.run(
+                lambda: self.router.result_of(pid) is not None,
+                max_messages=max_messages,
+            )
+        if not ok:
+            raise RuntimeError(f"era {era} did not complete")
+        return self.router.result_of(pid)
+
+    def close(self) -> None:
+        self.node.kv.close()
+        self.net.close()
 
 
 # -- fast-sync fixtures -------------------------------------------------------
