@@ -81,7 +81,11 @@ class BlockManager:
     ) -> List[SignedTransaction]:
         """Canonical execution order: (sender, nonce, hash) — every honest
         node derives the identical order from the agreed tx set
-        (role of the reference's fee-ordering in BlockProducer.CreateHeader)."""
+        (role of the reference's fee-ordering in BlockProducer.CreateHeader).
+        The one place a block's senders are resolved: every sender the
+        objects and the memo do not hold is recovered first in one threaded
+        native call, so the sort reads warm caches only."""
+        warm_sender_caches(txs, chain_id)
         return sorted(
             txs,
             key=lambda stx: (
@@ -160,9 +164,8 @@ class BlockManager:
         # block exec metrics (reference Prometheus summaries,
         # BlockManager.cs:62-127)
         with metrics.measure("block_execute"):
-            # batch-recover every sender up front (threaded native entry);
-            # ordering + execution then hit warm caches only
-            warm_sender_caches(txs, self.executer.chain_id)
+            # ordering recovers every sender the caches miss in one batch
+            # (none after create_header); execution then hits warm caches
             txs = self.order_transactions(txs, self.executer.chain_id)
             # tx lifecycle: execution reached this block (stamped before
             # emulate so a memo hit — block already emulated during header

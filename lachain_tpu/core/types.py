@@ -153,8 +153,9 @@ def _resolve_senders(stxs, chain_id: int) -> None:
        recoveries in TransactionManager's verify cache,
        TransactionManager.cs:141-171);
     3. one call for every key both missed, each key once:
-       `ecdsa.recover_address_batch`, which returns addresses, so no
-       recovered key is decompressed in Python for its keccak. The
+       `ecdsa.recover_address_batch_host`, the threaded native address
+       entry at every size, never the chip route, so no recovered key is
+       decompressed in Python for its keccak. The
        `ecdsa_recover` part of the loop thread's ledger is this step
        alone;
     4. the memo (cleared whole once it holds more than
@@ -163,8 +164,10 @@ def _resolve_senders(stxs, chain_id: int) -> None:
 
     Counters, one `inc` a call at most each, none for a call step 1
     answered whole: `txpool_sender_memo_hits_total` (objects step 2
-    answered) and `txpool_sender_recoveries_total` (keys handed to step
-    3). Recoveries over transactions committed is 1 in a healthy node."""
+    answered), `txpool_sender_recoveries_total` (keys handed to step 3)
+    and `txpool_sender_recovery_calls_total` (step 3's calls, so
+    recoveries over calls is the keys a call). Recoveries over
+    transactions committed is 1 in a healthy node."""
     pending: dict = {}  # memo key -> the objects that wait for it
     hits = 0
     for stx in stxs:
@@ -186,10 +189,11 @@ def _resolve_senders(stxs, chain_id: int) -> None:
         return
     keys = list(pending)
     with tracing.account("ecdsa_recover"):  # both caches missed
-        addrs = ecdsa.recover_address_batch(
+        addrs = ecdsa.recover_address_batch_host(
             [h for h, _ in keys], [sig for _, sig in keys]
         )
     metrics.inc("txpool_sender_recoveries_total", len(keys))
+    metrics.inc("txpool_sender_recovery_calls_total")
     if len(_SENDER_MEMO) > _SENDER_MEMO_MAX:
         _SENDER_MEMO.clear()
     for key, addr in zip(keys, addrs):
@@ -203,8 +207,10 @@ def warm_sender_caches(stxs, chain_id: int) -> None:
     share one threaded native call — the pool/sync bulk-ingest path (role
     of the reference's background TransactionVerifier,
     Blockchain/Operations/TransactionVerifier.cs:23-72): gossip admission
-    (`Node._on_pool_txs`), block sync, `la_sendRawTransactionBatch`, the
-    lane planner. Safe to call with any mix: see `_resolve_senders`."""
+    (`Node._on_pool_txs`), block sync, `la_sendRawTransactionBatch`, and
+    every block's ordering (`BlockManager.order_transactions`, which
+    `create_header` and `execute_block` go through). Safe to call with
+    any mix: see `_resolve_senders`."""
     _resolve_senders(stxs, chain_id)
 
 

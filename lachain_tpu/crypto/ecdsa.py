@@ -326,7 +326,8 @@ def recover_hash(msg_hash: bytes, sig: bytes) -> Optional[bytes]:
 
 # batches at least this large route to the TPU recover kernel when a chip
 # is present (ops/psecp.py: per-lane windowed scalar muls on the MXU);
-# smaller batches stay on the native threaded path
+# smaller batches, and every recover_address_batch_host, stay on the
+# native threaded path
 import os as _os_mod
 
 _TPU_RECOVER_MIN = int(_os_mod.environ.get("LTPU_TPU_ECDSA_MIN", "2048"))
@@ -439,8 +440,35 @@ def _address_of(pub: Optional[bytes]) -> Optional[bytes]:
     return None if pub is None else address_from_public_key(pub)
 
 
-@metrics.timed("crypto_ec_recover_address_batch")
 def recover_address_batch(
+    hashes: Sequence[bytes],
+    sigs: Sequence[bytes],
+    nthreads: Optional[int] = None,
+) -> List[Optional[bytes]]:
+    """The signers' 20-byte addresses (None where a signature is invalid)
+    by any route: at _TPU_RECOVER_MIN regular items on a chip, from the
+    keys of recover_hash_batch's chip route (ops/psecp.py), each
+    decompressed by a Python modular square root (~140 us) for its
+    keccak; otherwise recover_address_batch_host. Both give
+    address_from_public_key(_recover_hash_py(h, s)) or None. The node
+    resolves senders through recover_address_batch_host alone; the
+    benchmark's reference (perfbench/reference_share.py) calls this one,
+    so on a chip it derives a block's senders without the library's
+    address entry that the node's order comes from."""
+    if len(hashes) == len(sigs) and _native_lib() is not None:
+        regular = sum(
+            1 for h, s in zip(hashes, sigs) if len(h) == 32 and len(s) == 65
+        )
+        if regular >= _TPU_RECOVER_MIN:
+            from .provider import device_platform
+
+            if device_platform() == "tpu":
+                return [_address_of(p) for p in recover_hash_batch(hashes, sigs)]
+    return recover_address_batch_host(hashes, sigs, nthreads)
+
+
+@metrics.timed("crypto_ec_recover_address_batch")
+def recover_address_batch_host(
     hashes: Sequence[bytes],
     sigs: Sequence[bytes],
     nthreads: Optional[int] = None,
@@ -450,12 +478,12 @@ def recover_address_batch(
     the affine point its recovery already holds, so no key is compressed
     only to be decompressed again (a Python modular square root, ~140 us)
     for its keccak. What core/types.py resolves senders through; a batch
-    of one is the scalar path. Every route gives
+    of one is the scalar path. Regular items take this entry at every
+    size, on a chip too: an address from the chip route's keys costs that
+    square root a key. Every route gives
     address_from_public_key(_recover_hash_py(h, s)) or None, and the ones
     without the native entry derive it exactly so, as before the entry
-    existed: no library (the oracle), an item of irregular length, and
-    recover_hash_batch's chip route at _TPU_RECOVER_MIN regular items,
-    which returns keys."""
+    existed: no library (the oracle) and an item of irregular length."""
     n = len(hashes)
     if n != len(sigs):
         raise ValueError("hashes/sigs length mismatch")
@@ -467,11 +495,6 @@ def recover_address_batch(
     ]
     if lib is None or not regular:
         return [_address_of(recover_hash(h, s)) for h, s in zip(hashes, sigs)]
-    if len(regular) >= _TPU_RECOVER_MIN:
-        from .provider import device_platform
-
-        if device_platform() == "tpu":
-            return [_address_of(p) for p in recover_hash_batch(hashes, sigs)]
     out: List[Optional[bytes]] = [None] * n
     if len(regular) < n:
         regular_set = set(regular)

@@ -583,6 +583,40 @@ def test_recover_address_batch_on_the_chip_route(monkeypatch):
     assert handed == [4]  # under the threshold: the native entry
 
 
+def test_recover_address_batch_host_never_takes_the_chip_route(monkeypatch):
+    """The node's address route: on a chip, at any size, addresses come
+    from the native address entry. recover_address_batch_host never takes
+    recover_hash_batch's device route, which returns keys, and gives the
+    oracle's addresses, an irregular item still None; recover_address_batch
+    at the same threshold still takes the chip route."""
+    from lachain_tpu.crypto import ecdsa, provider
+
+    privs = [ecdsa.generate_private_key() for _ in range(5)]
+    hashes = [bytes([i]) * 32 for i in range(5)]
+    sigs = [ecdsa.sign_hash(p, h) for p, h in zip(privs, hashes)]
+    sigs[3] = sigs[3][:64]
+    want = []
+    for h, s in zip(hashes, sigs):
+        pub = ecdsa._recover_hash_py(h, s)
+        want.append(None if pub is None else ecdsa.address_from_public_key(pub))
+    assert want[3] is None and None not in want[:3] and want[4] is not None
+    handed = []
+
+    def device(hs, ss):
+        handed.append(len(hs))
+        return [ecdsa.recover_hash(h, s) for h, s in zip(hs, ss)]
+
+    monkeypatch.setattr(ecdsa, "_TPU_RECOVER_MIN", 4)
+    monkeypatch.setattr(provider, "device_platform", lambda: "tpu")
+    monkeypatch.setattr(ecdsa, "_tpu_recover", device)
+    assert ecdsa.recover_address_batch_host(hashes, sigs) == want
+    assert ecdsa.recover_address_batch_host(hashes * 3, sigs * 3) == want * 3
+    assert ecdsa.recover_address_batch_host(hashes[:3], sigs[:3]) == want[:3]
+    assert handed == []
+    assert ecdsa.recover_address_batch(hashes, sigs) == want
+    assert handed == [4]  # the four regular items, on the chip route
+
+
 def test_warm_sender_caches():
     from lachain_tpu.core.types import (
         Transaction,

@@ -7,7 +7,9 @@
     keys and hashes and every irregular signature the wire can carry,
     scalar and batch, with the native library and without it;
 (c) what the two caches remember: a chain id, a failed recovery, a bound;
-(d) a mixed list costs one native call for its regular misses.
+(d) a mixed list costs one native call for its regular misses;
+(e) a block decoded anew costs one native call for all its senders,
+    whether `create_header` or `execute_block` orders it first.
 """
 import os
 import random
@@ -18,19 +20,28 @@ import pytest
 
 from lachain_tpu.core import types as T
 from lachain_tpu.core.block_manager import BlockManager
-from lachain_tpu.core.block_producer import decode_tx_batch, encode_tx_batch
+from lachain_tpu.core.block_producer import (
+    BlockProducer,
+    decode_tx_batch,
+    encode_tx_batch,
+)
+from lachain_tpu.core.execution import TransactionExecuter
 from lachain_tpu.core.types import (
+    MultiSig,
     SignedTransaction,
     Transaction,
     sign_transaction,
     warm_sender_caches,
 )
 from lachain_tpu.crypto import ecdsa
+from lachain_tpu.storage.kv import MemoryKV
+from lachain_tpu.storage.state import StateManager
 from lachain_tpu.utils import metrics
 
 CHAIN = 77
 RECOVERIES = "txpool_sender_recoveries_total"
 HITS = "txpool_sender_memo_hits_total"
+CALLS = "txpool_sender_recovery_calls_total"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -394,10 +405,12 @@ def test_a_mixed_list_makes_one_native_call(monkeypatch):
     spy = _SpyLib(ecdsa._native_lib())
     monkeypatch.setattr(ecdsa, "_native_lib", lambda: spy)
     before = _counts()
+    calls = metrics.counter_value(CALLS)
     warm_sender_caches(mixed, CHAIN)
     # keys handed to step 3: three misses, the invalid, the repeated one
     # once, the two of irregular length; the memo answered one object
     assert _moved(before) == (7, 1)
+    assert metrics.counter_value(CALLS) == calls + 1
     batch = [a for name, a in spy.calls if name == "lt_ec_recover_address_batch"]
     assert len(batch) == 1 and batch[0][2] == 5  # the regular misses, once
     # irregular items take the oracle's route, as before: recover_hash,
@@ -417,3 +430,111 @@ def test_a_mixed_list_makes_one_native_call(monkeypatch):
     spy.calls.clear()
     warm_sender_caches(_redecoded(mixed), CHAIN)  # all from the memo now
     assert spy.calls == [] and _moved(before) == (7, 1 + len(mixed))
+    warm_sender_caches(mixed, CHAIN)  # all from the objects
+    assert spy.calls == [] and _moved(before) == (7, 1 + len(mixed))
+    assert metrics.counter_value(CALLS) == calls + 1
+
+
+# -- (e) a block's senders in one call --------------------------------------
+
+_SENDERS = 20
+_PER_SENDER = 16
+
+
+def _block_of_peers():
+    """(the block's transactions, each one's true sender): 320 transfers,
+    16 nonces of each of 20 keys, in shuffled order, and two signatures
+    no key made (r = 0), as the peers' proposals bring them."""
+    rng = random.Random(43)
+    privs = [ecdsa.generate_private_key() for _ in range(_SENDERS)]
+    addrs = [ecdsa.address_from_public_key(ecdsa.public_key_bytes(p)) for p in privs]
+    items = [
+        (sign_transaction(_tx(rng, nonce=k), privs[i], CHAIN), addrs[i])
+        for i in range(_SENDERS)
+        for k in range(_PER_SENDER)
+    ]
+    for i in (3, 11):
+        stx = items[i][0]
+        items.append(
+            (SignedTransaction(stx.tx, bytes(32) + stx.signature[32:]), None)
+        )
+    rng.shuffle(items)
+    # the oracle names the same senders: one transaction of each key, and
+    # both that no key made
+    for i in range(_SENDERS):
+        stx, want = next(it for it in items if it[1] == addrs[i])
+        assert _oracle(stx.tx.signing_hash(CHAIN), stx.signature) == want
+    for stx, want in items:
+        if want is None:
+            assert _oracle(stx.tx.signing_hash(CHAIN), stx.signature) is None
+    return [stx for stx, _ in items], [a for _, a in items], addrs
+
+
+def _chain_of(addrs):
+    kv = MemoryKV()
+    state = StateManager(kv)
+    bm = BlockManager(kv, state, TransactionExecuter(CHAIN))
+    bm.build_genesis({a: 10**24 for a in addrs}, CHAIN)
+    return bm
+
+
+def _batch_calls(spy):
+    names = [name for name, _ in spy.calls]
+    assert set(names) <= {"lt_ec_recover_address_batch"}, names
+    return [a[2] for _, a in spy.calls]
+
+
+@pytest.mark.parametrize("first", ["order", "create_header"])
+def test_a_block_decoded_anew_makes_one_native_call(monkeypatch, first):
+    """What validator 0 of hb128-share meets each era: a block whose
+    transactions it never admitted, decoded from the agreed proposals.
+    Ordering it, alone or in create_header, recovers every sender in ONE
+    call of the threaded address entry, and orders as the per-object
+    sort over the true senders does; ordering again, and executing the
+    block after create_header, recover nothing. On a chip too: the
+    block is far above the chip route's threshold, and never takes it."""
+    from lachain_tpu.crypto import provider
+
+    stxs, senders, addrs = _block_of_peers()
+    n = len(stxs)
+    block = decode_tx_batch(encode_tx_batch(stxs))
+    assert all("_sender_cache" not in s.__dict__ for s in block)
+    bm = _chain_of(addrs)
+    spy = _SpyLib(ecdsa._native_lib())
+    monkeypatch.setattr(ecdsa, "_native_lib", lambda: spy)
+    monkeypatch.setattr(ecdsa, "_TPU_RECOVER_MIN", 4)
+    monkeypatch.setattr(provider, "device_platform", lambda: "tpu")
+
+    def device(hs, ss):
+        raise AssertionError("a block's senders took the chip route")
+
+    monkeypatch.setattr(ecdsa, "_tpu_recover", device)
+    before = _counts()
+    calls = metrics.counter_value(CALLS)
+    if first == "order":
+        ordered = BlockManager.order_transactions(block, CHAIN)
+    else:
+        header = BlockProducer(bm, None, 1, n).create_header(1, block, 1)
+        ordered = None
+    assert _batch_calls(spy) == [n]
+    assert _moved(before) == (n, 0)
+    assert metrics.counter_value(CALLS) == calls + 1
+    by_hash = {s.hash(): a for s, a in zip(stxs, senders)}
+    want = sorted(
+        block,
+        key=lambda s: (by_hash[s.hash()] or b"\xff" * 20, s.tx.nonce, s.hash()),
+    )
+    assert want[-2:] == [s for s in want if by_hash[s.hash()] is None]
+    assert [s.sender(CHAIN) for s in block] == [by_hash[s.hash()] for s in block]
+    again = BlockManager.order_transactions(block, CHAIN)
+    assert again == want
+    if ordered is not None:
+        assert ordered == want
+    else:
+        assert header.merkle_root == T.tx_merkle_root([s.hash() for s in want])
+        # the node executes the block it decoded again: the memo answers
+        executed = bm.execute_block(header, _redecoded(block), MultiSig(()))
+        assert executed.tx_hashes == tuple(s.hash() for s in want)
+        assert _moved(before) == (n, n)
+    assert _batch_calls(spy) == [n]
+    assert metrics.counter_value(CALLS) == calls + 1
