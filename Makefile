@@ -75,20 +75,20 @@ test-pipeline:
 test-sync:
 	$(PYTEST) $(PYTEST_ARGS) -m "sync and not slow"
 
-# block execution: the randomized deferred-vs-immediate freeze
-# differential over executor blocks (receipts + roots + trie node sets
-# bit-identical), the hashing routes over the benchmark's block shapes,
+# block execution: the randomized native-vs-Python freeze differential
+# over executor blocks (receipts + roots + trie node sets bit-identical),
+# both walkers over the benchmark's block shapes,
 # delta checkpoints, canonical ordering, sharded pool admission. The
 # slice to run after touching core/block_manager.py, core/execution.py,
 # storage/state.py checkpoints or core/tx_pool.py
 test-exec:
 	$(PYTEST) $(PYTEST_ARGS) -m exec
 
-# merkleization: the 200-seed deferred-vs-immediate apply_many
-# differential (roots + node sets + pending buffers), the hashing
-# threads' byte floor, the node cache across freezes. The slice to run
-# after touching storage/trie.py apply_many/_bulk, the batch keccak, or
-# the StateManager streamed commit
+# merkleization: the 200-seed native-vs-Python apply_many differential
+# (roots + node sets + pending buffers), the native walks' node table and
+# its shared budget, the node cache across freezes. The slice to run
+# after touching storage/trie.py, crypto/native/trie.cpp, or the
+# StateManager streamed commit
 test-trie:
 	$(PYTEST) $(PYTEST_ARGS) -m trie
 

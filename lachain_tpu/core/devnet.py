@@ -70,7 +70,7 @@ def make_node(
     txs_per_block: int,
 ) -> DevnetNode:
     """Validator i's store, state, chain from genesis, pool and producer."""
-    state = StateManager(kv)
+    state = StateManager(kv, native=True)
     # full system-contract registry (deploy/LRC-20/governance/staking)
     # so the devnet exercises the same execution surface as a real node
     executer = system_contracts.make_executer(chain_id)

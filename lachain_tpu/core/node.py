@@ -93,7 +93,7 @@ class Node:
         self.fsck_report = fsck(self.kv, repair=True)
         if self.fsck_report.fatal:
             raise FsckError(self.fsck_report)
-        self.state = StateManager(self.kv)
+        self.state = StateManager(self.kv, native=True)
         from . import system_contracts
 
         self.block_manager = BlockManager(

@@ -158,9 +158,11 @@ class StateManager:
     stream_threshold = 4096
     _STREAM_BATCH = 4096
 
-    def __init__(self, kv: KVStore):
+    def __init__(self, kv: KVStore, native: bool = False):
+        """`native`: the trie walks in native code (a validator's own
+        state); without it, in Python (storage/trie.py)."""
         self._kv = kv
-        self.trie = Trie(kv)
+        self.trie = Trie(kv, native=native)
         self._committed: StateRoots = self._load_latest()
         # last commit's profile (streamed batches, fsync-wait seconds) for
         # the bench's commit-phase breakdown

@@ -12,16 +12,31 @@ their canonical encoding) instead of carrying monotone version ids
 snapshot: "versions" are simply root hashes, which collapses the reference's
 Committed/Approved/Pending tier machinery into plain values (state.py) and
 makes rollback O(1). An LRU node cache fills the role of TrieHashMap's cache.
+
+Two walkers, which give the same roots and the same stored nodes. A Trie
+made with `native=True` (a validator's own state: core/node.py, devnet's
+make_node) runs `apply_many` and `get` in C++ (crypto/native/trie.cpp) over
+a node table of its own, the process's tables bounded together in bytes
+(NODE_TABLE_BYTES): one call walks, builds, encodes and hashes a whole
+tree's batch, and one call answers a read. Every other Trie, and a native
+one where the library is absent, walks in Python below, hashing each node
+as it is stored: the oracle the native walk is tested against, and the
+walker the benchmark's reference re-executes the chain on, so that its
+state roots are checked by code independent of the native walk.
 """
 from __future__ import annotations
 
+import ctypes
 import functools
+import itertools
 import os
+import weakref
+from array import array
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
 
-from ..crypto.hashes import keccak256, keccak256_batch
+from ..crypto.hashes import keccak256
 from ..utils import metrics
 from ..utils.serialization import Reader, write_bytes, write_u16
 from .kv import EntryPrefix, KVStore, prefixed
@@ -29,19 +44,97 @@ from .kv import EntryPrefix, KVStore, prefixed
 EMPTY_ROOT = b"\x00" * 32
 _NIBBLES = 64  # keccak256 -> 64 nibbles
 
-# batch-size floor for deferred level-batched hashing: below it the
-# bookkeeping costs more than the per-node keccak dispatch it saves
-MIN_DEFER_OPS = 32
-# a level's encodings go to native hashing threads only from this many
-# bytes on; below it the threads' start costs more than they hash
-MIN_HASH_THREAD_BYTES = 3 << 19
+# the bound on all of a process's native node tables together, each node
+# charged its encoding plus 128 bytes; the live tables share it evenly. A
+# deployment runs one validator a process, whose table then holds a
+# 10,000-transfer block's working set (the balances tree's ~21,000 leaves
+# with their interior, and the receipts tree, whose leaves a new key
+# splits, growing by ~50,000 stored nodes a block) for 21 blocks before
+# the oldest receipts leave; hb64-sim's 64 validators get 4 MiB each
+NODE_TABLE_BYTES = 256 << 20
+_DELETED = 0xFFFFFFFF  # a value length that marks a delete
+# lt_trie_* statuses (trie.cpp); 3 is a bad node encoding
+_MISSING, _LOADER_ERROR = 1, 2
+_LOADER = ctypes.CFUNCTYPE(
+    ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64
+)
+_native_cache = [False, None]
 
-_KECCAK_BATCH_BUCKETS = (16, 64, 256, 1024, 4096, 16384, 65536)
+
+def native_walks():
+    """The library that carries the trie's native walks, or None where the
+    hash library is absent (crypto.hashes._native_lib): then a native Trie
+    walks in Python. Probed once a process."""
+    if not _native_cache[0]:
+        _native_cache[0] = True
+        from ..crypto.hashes import _native_lib
+
+        lib = _native_lib()
+        if lib is not None:
+            if not hasattr(lib, "lt_trie_apply"):
+                raise RuntimeError(
+                    "the native library lacks the trie's walks (lt_trie_*): "
+                    "a stale build"
+                )
+            _bind(lib)
+            _native_cache[1] = lib
+    return _native_cache[1]
+
+
+def _bind(lib) -> None:
+    vp, u64, u64p = ctypes.c_void_p, ctypes.c_uint64, ctypes.POINTER(ctypes.c_uint64)
+    lib.lt_trie_new.argtypes = [u64]
+    lib.lt_trie_new.restype = vp
+    lib.lt_trie_free.argtypes = [vp]
+    lib.lt_trie_free.restype = None
+    lib.lt_trie_clear.argtypes = [vp]
+    lib.lt_trie_clear.restype = None
+    lib.lt_trie_counts.argtypes = [vp, u64p]
+    lib.lt_trie_counts.restype = None
+    lib.lt_trie_size.argtypes = [vp, u64p]
+    lib.lt_trie_size.restype = None
+    lib.lt_trie_insert.argtypes = [vp, ctypes.c_char_p, u64, ctypes.c_char_p, vp, u64]
+    lib.lt_trie_insert.restype = None
+    lib.lt_trie_apply.argtypes = [
+        vp, ctypes.c_char_p, ctypes.c_char_p, vp, ctypes.c_char_p, vp, u64,
+        ctypes.c_uint32, ctypes.c_int, _LOADER, ctypes.c_char_p, u64p,
+    ]
+    lib.lt_trie_apply.restype = vp
+    lib.lt_trie_result_free.argtypes = [vp]
+    lib.lt_trie_result_free.restype = None
+    lib.lt_trie_get.argtypes = [
+        vp, ctypes.c_char_p, ctypes.c_char_p, u64, _LOADER, u64p,
+    ]
+    lib.lt_trie_get.restype = ctypes.c_int64
+
+
+def _make_loader(pending: Dict[bytes, bytes], kv: KVStore, errors: list):
+    """The native table's one way to a node it lacks: what `_load` reads,
+    the write-back buffer first, then the store. Holds the buffer and the
+    store, not the Trie, so the table's owner is freed as usual."""
+    prefix = prefixed(EntryPrefix.TRIE_NODE)
+
+    def load(h, buf, cap):
+        try:
+            key = prefix + ctypes.string_at(h, 32)
+            enc = pending.get(key)
+            if enc is None:
+                enc = kv.get(key)
+            if enc is None:
+                return -1
+            if len(enc) <= cap:
+                ctypes.memmove(buf, enc, len(enc))
+            return len(enc)
+        except BaseException as exc:  # noqa: BLE001 - re-raised by the caller
+            errors.append(exc)
+            return -2
+
+    return _LOADER(load)
 
 
 @functools.cache
 def _host_cores() -> int:
-    """The native hashing threads a level may use: the host's cores, 16 at
+    """The native hashing threads a walk may use: the host's cores, 16 at
     most. Read once a process: os.cpu_count() reads a file, 36-80 us a call
     on the chip's host, and a freeze asks once a subtree."""
     return min(os.cpu_count() or 1, 16)
@@ -104,63 +197,6 @@ def _decode(data: bytes):
     raise ValueError("bad trie node encoding")
 
 
-class _DeferredHasher:
-    """Deferred-hash node sink for bulk merkleization: while armed on a
-    Trie, `_store` hands out a 9-byte placeholder token instead of hashing
-    the node. `Trie._resolve_deferred` then encodes the accumulated nodes
-    level-by-level bottom-up, hashes each level's encodings in ONE native
-    batch call (crypto.hashes.keccak256_batch) and patches child
-    references — collapsing ~one Python→C keccak crossing per node into
-    one per tree level (~6 for a 100k-node block).
-
-    Token contract (what keeps `_bulk`'s no-op short-circuits and the
-    collapse rules bit-identical to the immediate-hash path): a token is
-    never equal to a real 32-byte hash, to EMPTY_ROOT, or to a different
-    token, and tokens are handed out only for genuinely stored nodes — so
-    `children == list(node.children)` still means exactly "nothing changed
-    under this branch"."""
-
-    __slots__ = ("nodes", "levels", "buckets", "count")
-    PREFIX = 0xFE
-
-    def __init__(self):
-        self.nodes: Dict[bytes, object] = {}  # token -> node (for _load)
-        self.levels: Dict[bytes, int] = {}  # token -> bottom-up level
-        # per-level (tokens, nodes) parallel lists — the batch-hash units
-        self.buckets: List[Tuple[List[bytes], List[object]]] = []
-        self.count = 0
-
-    def store(self, node) -> bytes:
-        # HOT: once per stored node. The level is known right here —
-        # children are always stored before their parent — so computing
-        # it now saves _resolve_deferred a whole extra pass. b"\xfe" ==
-        # PREFIX inlined; token child refs are the only 9-byte refs.
-        token = b"\xfe" + self.count.to_bytes(8, "big")
-        self.count += 1
-        lvl = 0
-        if type(node) is InternalNode:
-            levels = self.levels
-            for c in node.children:
-                if len(c) == 9:
-                    cl = levels[c]
-                    if cl >= lvl:
-                        lvl = cl + 1
-        self.levels[token] = lvl
-        self.nodes[token] = node
-        buckets = self.buckets
-        if lvl >= len(buckets):  # parents are at most one level above
-            buckets.append(([], []))
-        bt, bn = buckets[lvl]
-        bt.append(token)
-        bn.append(node)
-        return token
-
-    @staticmethod
-    def is_token(h: bytes) -> bool:
-        # real node hashes are 32 bytes; tokens are 9
-        return len(h) == 9 and h[0] == _DeferredHasher.PREFIX
-
-
 class Trie:
     """Handle over a KV store; every mutation returns a NEW root hash.
 
@@ -172,18 +208,22 @@ class Trie:
     better crash ordering than the old eager puts (which leaked orphan
     nodes from uncommitted emulations onto disk)."""
 
-    def __init__(self, kv: KVStore, cache_size: int = 65536):
+    def __init__(
+        self, kv: KVStore, cache_size: int = 65536, native: bool = False
+    ):
         self._kv = kv
         self._cache: OrderedDict[bytes, object] = OrderedDict()
         self._cache_size = cache_size
         self._pending: Dict[bytes, bytes] = {}  # prefixed key -> encoding
-        # armed deferred-hash sink (apply_many's deferred route only)
-        self._defer: Optional[_DeferredHasher] = None
+        # the library of the native walks (None: the Python walker), and
+        # the node table and its loader, made on the first native walk
+        self._walks = native_walks() if native else None
+        self._table: Optional[int] = None
+        self._loader = None
+        self._loader_errors: list = []
 
     # -- node io -------------------------------------------------------------
     def _store(self, node) -> bytes:
-        if self._defer is not None:
-            return self._defer.store(node)
         enc = node.encode()
         h = keccak256(enc)
         self._pending[prefixed(EntryPrefix.TRIE_NODE, h)] = enc
@@ -191,8 +231,6 @@ class Trie:
         return h
 
     def _load(self, h: bytes):
-        if self._defer is not None and _DeferredHasher.is_token(h):
-            return self._defer.nodes[h]
         node = self._cache.get(h)
         if node is not None:
             self._cache.move_to_end(h)
@@ -231,28 +269,116 @@ class Trie:
     def absorb_pending(self, nodes: Dict[bytes, bytes]) -> None:
         """Adopt another trie's exported node buffer (see export_pending).
         Re-absorbing an already-persisted node is harmless — same key, same
-        encoding — it just rides the next commit batch again."""
+        encoding — it just rides the next commit batch again. The native
+        table takes the nodes too, so the next walk finds them warm."""
         self._pending.update(nodes)
+        if self._walks is None or not nodes:
+            return
+        keys = b"".join(nodes)
+        # every key is the 2-byte prefix and a 32-byte hash, or none is fed
+        if len(keys) == 34 * len(nodes):
+            encs = list(nodes.values())
+            offs = array("Q", [0])
+            offs.extend(itertools.accumulate(map(len, encs)))
+            self._walks.lt_trie_insert(
+                self._native_table(), keys, 34,
+                b"".join(encs), offs.buffer_info()[0], len(encs),
+            )
 
     def clear_cache(self) -> None:
         self._cache.clear()
+        if self._table is not None:
+            self._walks.lt_trie_clear(self._table)
+
+    # -- the native walks ----------------------------------------------------
+    def _native_table(self) -> int:
+        if self._table is None:
+            lib = self._walks
+            self._table = lib.lt_trie_new(NODE_TABLE_BYTES)
+            weakref.finalize(self, lib.lt_trie_free, self._table)
+            self._loader = _make_loader(
+                self._pending, self._kv, self._loader_errors
+            )
+        return self._table
+
+    def _flush_loads(self) -> None:
+        """Fold the table's counts into trie_node_loads_total{source}: nodes
+        a native walk or read found in the table, and nodes it fetched
+        through the loader, since the last fold."""
+        counts = (ctypes.c_uint64 * 2)()
+        self._walks.lt_trie_counts(self._table, counts)
+        if counts[0]:
+            metrics.inc("trie_node_loads_total", counts[0], {"source": "table"})
+        if counts[1]:
+            metrics.inc("trie_node_loads_total", counts[1], {"source": "store"})
+
+    def _native_failed(self, status: int, missing: bytes):
+        if status == _MISSING:
+            raise KeyError(f"missing trie node {missing.hex()}")
+        if status == _LOADER_ERROR:
+            raise self._loader_errors.pop()
+        raise ValueError("bad trie node encoding")
+
+    def _apply_native(
+        self, root: bytes, writes: Dict[bytes, Optional[bytes]]
+    ) -> bytes:
+        """apply_many in one native call: it hashes the keys, walks,
+        builds, encodes and hashes every node, and hands back the stored
+        nodes for the write-back buffer."""
+        lib = self._walks
+        table = self._native_table()
+        vals = list(writes.values())
+        klens = array("I", map(len, writes))
+        vlens = array("I", [_DELETED if v is None else len(v) for v in vals])
+        out_root = ctypes.create_string_buffer(32)
+        info = (ctypes.c_uint64 * 6)()
+        res = lib.lt_trie_apply(
+            table, root, b"".join(writes), klens.buffer_info()[0],
+            b"".join([v for v in vals if v is not None]),
+            vlens.buffer_info()[0], len(vals), int(EntryPrefix.TRIE_NODE),
+            _host_cores(), self._loader, out_root, info,
+        )
+        try:
+            status, n, keys_at, encs_at, offs_at, size = info
+            if status:
+                self._native_failed(status, out_root.raw)
+            kb = ctypes.string_at(keys_at, 34 * n)
+            eb = ctypes.string_at(encs_at, size)
+            offs = array("Q")
+            offs.frombytes(ctypes.string_at(offs_at, 8 * (n + 1)))
+        finally:
+            lib.lt_trie_result_free(res)
+            self._flush_loads()
+        keys = map(kb.__getitem__, map(slice, range(0, 34 * n, 34), range(34, 34 * n + 1, 34)))
+        encs = map(eb.__getitem__, map(slice, offs, offs[1:]))
+        self._pending.update(zip(keys, encs))
+        metrics.inc("trie_nodes_hashed_total", n)
+        return out_root.raw
+
+    def _get_native(self, root: bytes, key: bytes) -> Optional[bytes]:
+        info = (ctypes.c_uint64 * 2)()
+        n = self._walks.lt_trie_get(
+            self._native_table(), root, key, len(key), self._loader, info
+        )
+        if info[1]:  # a store load: counted now, table hits at the next fold
+            self._flush_loads()
+        if n >= 0:
+            return ctypes.string_at(info[0], n)
+        if n == -1:
+            return None
+        self._native_failed(-1 - n, ctypes.string_at(info[0], 32))
 
     def _cache_put(self, h: bytes, node) -> None:
         self._cache[h] = node
         if len(self._cache) > self._cache_size:
             self._cache.popitem(last=False)
 
-    def _trim_cache(self) -> None:
-        """One bulk trim after a bulk update, instead of per-put LRU churn
-        (recency inside one batch is meaningless anyway)."""
-        cache = self._cache
-        while len(cache) > self._cache_size:
-            cache.popitem(last=False)
-
     # -- public api ----------------------------------------------------------
     def get(self, root: bytes, key: bytes) -> Optional[bytes]:
         if root == EMPTY_ROOT:
             return None
+        if self._walks is not None:
+            return self._get_native(root, key)
         kh = keccak256(key)
         node_hash = root
         depth = 0
@@ -330,81 +456,17 @@ class Trie:
         """Apply a {key: value-or-None(delete)} batch; returns the new root
         (bit-identical to sequential put/delete in any order).
 
-        One walker; the batch picks how it hashes:
-          * below MIN_DEFER_OPS each node is hashed as it is stored;
-          * from MIN_DEFER_OPS on, nodes are encoded level-by-level
-            bottom-up and each level is hashed in one native keccak call
-            (_resolve_deferred: on the host's cores only from
-            MIN_HASH_THREAD_BYTES a level)."""
+        On a native Trie one native call does it all (_apply_native);
+        otherwise the Python walk below, each node hashed as it is
+        stored."""
         if not writes:
             return root
+        if self._walks is not None:
+            return self._apply_native(root, writes)
         entries: Dict[bytes, Optional[bytes]] = {
             keccak256(k): v for k, v in writes.items()
         }
-        ops = sorted(entries.items())
-        if len(ops) < MIN_DEFER_OPS:
-            return self._bulk(root, ops, 0)
-        self._defer = _DeferredHasher()
-        try:
-            out = self._bulk(root, ops, 0)
-        finally:
-            defer, self._defer = self._defer, None
-        resolved = self._resolve_deferred(defer)
-        if _DeferredHasher.is_token(out):
-            out = resolved[out]
-        return out
-
-    def _resolve_deferred(self, defer: _DeferredHasher) -> Dict[bytes, bytes]:
-        """Hash a deferred sink's nodes level-by-level bottom-up through
-        the native batch keccak, patching child tokens with the hashes of
-        the level below, into the pending buffer; returns token -> hash.
-        A level goes to the host's cores (_host_cores) only where it
-        carries MIN_HASH_THREAD_BYTES of encodings; a smaller one is hashed
-        on the calling thread.
-
-        HOT PATH: ~one iteration per node per 10k-tx block commit. Token
-        tests are inlined as `len(c) == 9` (real child refs are always 32
-        bytes) and leaves — the bulk of every batch — skip the patch
-        machinery entirely; the Python bookkeeping here must stay well
-        under the per-node ctypes crossing it saves, or deferral is a
-        net loss on one core."""
-        resolved: Dict[bytes, bytes] = {}
-        trie_node = int(EntryPrefix.TRIE_NODE).to_bytes(2, "big")
-        pending = self._pending
-        cache = self._cache
-        for tokens, bnodes in defer.buckets:
-            patched: List[object] = []
-            for n in bnodes:
-                if type(n) is InternalNode:
-                    ch = n.children
-                    for c in ch:
-                        if len(c) == 9:
-                            n = InternalNode(
-                                tuple(
-                                    [
-                                        resolved[c] if len(c) == 9 else c
-                                        for c in ch
-                                    ]
-                                )
-                            )
-                            break
-                patched.append(n)
-            encs = [n.encode() for n in patched]
-            big = sum(map(len, encs)) >= MIN_HASH_THREAD_BYTES
-            hashes = keccak256_batch(encs, _host_cores() if big else 1)
-            metrics.observe_hist(  # lint-allow: metric-name dimensionless batch-size distribution
-                "trie_keccak_batch_size",
-                len(encs),
-                buckets=_KECCAK_BATCH_BUCKETS,
-            )
-            # bulk C-level stores instead of a per-node interpreted loop
-            keys = [trie_node + h for h in hashes]
-            pending.update(zip(keys, encs))
-            resolved.update(zip(tokens, hashes))
-            cache.update(zip(hashes, patched))
-        self._trim_cache()
-        metrics.inc("trie_nodes_hashed_total", len(resolved))
-        return resolved
+        return self._bulk(root, sorted(entries.items()), 0)
 
     def _bulk(self, node_hash: bytes, ops, depth: int) -> bytes:
         if not ops:

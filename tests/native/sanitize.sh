@@ -34,7 +34,8 @@ g++ $CXXFLAGS -fPIC -shared -o "$BUILD/libconsensus_rt_san.so" \
     ../../lachain_tpu/consensus/native/consensus_rt.cpp
 g++ $CXXFLAGS -fPIC -shared -o "$BUILD/libbls381_san.so" \
     ../../lachain_tpu/crypto/native/bls381.cpp \
-    ../../lachain_tpu/crypto/native/secp256k1.cpp
+    ../../lachain_tpu/crypto/native/secp256k1.cpp \
+    ../../lachain_tpu/crypto/native/trie.cpp
 
 echo "== differential (sanitized) =="
 "$BUILD/check_msm"
@@ -62,8 +63,10 @@ ABS_BUILD="$(cd "$BUILD" && pwd)"
 
 echo "== native-engine slices over sanitized libconsensus_rt.so + libbls381.so =="
 # same preload discipline; the consensus router (pipelined-era driver,
-# flood protocols, trace rings) and the BLS backend (threaded batch muls,
-# grand multi-pairing) under the pytest drivers that exercise them
+# flood protocols, trace rings), the BLS backend (threaded batch muls,
+# grand multi-pairing) and the trie's native walks (node table, loader
+# callback, threaded level hashing) under the pytest slices that
+# exercise them
 (cd ../.. && \
     LD_PRELOAD="$ASAN_RT $UBSAN_RT" \
     ASAN_OPTIONS="detect_leaks=0,abort_on_error=1,verify_asan_link_order=0" \
@@ -71,5 +74,6 @@ echo "== native-engine slices over sanitized libconsensus_rt.so + libbls381.so =
     LACHAIN_BLS_LIB="$ABS_BUILD/libbls381_san.so" \
     JAX_PLATFORMS=cpu \
     python -m pytest tests/test_native_rt.py tests/test_native_backend.py \
-        -q -m "not slow" -p no:cacheprovider)
+        tests/test_trie_native.py tests/test_trie_parallel.py \
+        -q -m "not slow" -k "not share_block" -p no:cacheprovider)
 echo "SANITIZE GREEN"

@@ -42,7 +42,8 @@ g++ $CXXFLAGS -o "$BUILD/libconsensus_rt_tsan.so" \
     "$REPO/lachain_tpu/consensus/native/consensus_rt.cpp"
 g++ $CXXFLAGS -o "$BUILD/libbls381_tsan.so" \
     "$REPO/lachain_tpu/crypto/native/bls381.cpp" \
-    "$REPO/lachain_tpu/crypto/native/secp256k1.cpp"
+    "$REPO/lachain_tpu/crypto/native/secp256k1.cpp" \
+    "$REPO/lachain_tpu/crypto/native/trie.cpp"
 
 TSAN_RT="$(gcc -print-file-name=libtsan.so)"
 ABS_BUILD="$(cd "$BUILD" && pwd)"

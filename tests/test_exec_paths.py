@@ -1,15 +1,13 @@
-"""Which hashing route a block's freeze takes, and that the route never
-shows.
+"""Which route a block's freeze takes, and that the route never shows.
 
-`Trie.apply_many` picks from its input: each node hashed as it is stored
-below MIN_DEFER_OPS ops, deferred level-batched hashing from there on, and
-native hashing threads only for a level of MIN_HASH_THREAD_BYTES. Held
-here, over the benchmark's two block shapes (perfbench/traffic/full.json:
-700 transfers from 256 senders to 250 recipients; smallbank-full.json: 700
-calls of one contract) and a block of 24 disjoint groups: every route gives
-the same receipts, roots and pending node set as the immediate walk; a
-block of hb64.full's size hashes on one thread; and a freeze leaves what it
-wrote in the handle's cache on either side of the byte floor.
+A validator's state walks in native code: `Trie.apply_many` is one native
+call a tree (`native`). Any other state walks in Python, each node hashed
+as it is stored (`immediate`). Held here, over the benchmark's two block
+shapes (perfbench/traffic/full.json: 700 transfers from 256 senders to 250
+recipients; smallbank-full.json: 700 calls of one contract) and a block of
+24 disjoint groups: the native route gives the same receipts, roots and
+pending node set as the immediate walk, and a freeze leaves what it wrote
+in the handle's cache (the native table, on the native route).
 """
 import itertools
 import json
@@ -31,7 +29,8 @@ from lachain_tpu.core.types import (
 from lachain_tpu.crypto import ecdsa
 from lachain_tpu.storage.kv import MemoryKV
 from lachain_tpu.storage.state import StateManager
-from lachain_tpu.storage.trie import EMPTY_ROOT, Trie, _host_cores
+from lachain_tpu.storage.trie import EMPTY_ROOT, Trie
+from lachain_tpu.utils import metrics
 from perfbench import traffic, traffic_smallbank
 
 pytestmark = [pytest.mark.exec, pytest.mark.trie]
@@ -41,14 +40,7 @@ SEED = 39
 BLOCK = 700
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SHAPES = ["transfers", "smallbank", "groups24"]
-# route -> (MIN_DEFER_OPS, MIN_HASH_THREAD_BYTES): the immediate walk with
-# the defer floor raised past any block, deferred hashing at the program's
-# floors, and deferred hashing with every level sent to the threads
-ROUTES = {
-    "immediate": (1 << 30, trie_mod.MIN_HASH_THREAD_BYTES),
-    "deferred": (trie_mod.MIN_DEFER_OPS, trie_mod.MIN_HASH_THREAD_BYTES),
-    "threaded": (trie_mod.MIN_DEFER_OPS, 0),
-}
+ROUTES = ["immediate", "native"]
 
 
 def _mix(name):
@@ -95,28 +87,18 @@ def _blocks(shape):
     return _BLOCKS[shape]
 
 
-def _route(monkeypatch, route):
-    """Set the trie's floors for `route`; -> the list that records, per
-    native keccak batch, (threads asked for, bytes hashed)."""
-    defer_from, thread_from = ROUTES[route]
-    monkeypatch.setattr(trie_mod, "MIN_DEFER_OPS", defer_from)
-    monkeypatch.setattr(trie_mod, "MIN_HASH_THREAD_BYTES", thread_from)
-    calls = []
-    real = trie_mod.keccak256_batch
-
-    def spy(encs, threads):
-        calls.append((threads, sum(map(len, encs))))
-        return real(encs, threads)
-
-    monkeypatch.setattr(trie_mod, "keccak256_batch", spy)
-    return calls
+def _native(route):
+    """Whether `route` walks in native code; the library must be there."""
+    if route == "native":
+        assert trie_mod.native_walks() is not None
+    return route == "native"
 
 
-def _emulate(shape, blocks):
-    """`blocks` through BlockManager.emulate, each committed before the
-    next. -> per block (receipts, roots, node keys)."""
+def _emulate(shape, blocks, route):
+    """`blocks` through BlockManager.emulate on `route`, each committed
+    before the next. -> per block (receipts, roots, node keys)."""
     addrs, genesis, _ = _blocks(shape)
-    state = StateManager(MemoryKV())
+    state = StateManager(MemoryKV(), native=_native(route))
     executer = system_contracts.make_executer(CHAIN)
     snap = state.new_snapshot()
     for a in addrs:
@@ -143,44 +125,24 @@ _ORACLE = {}
 def _oracle(shape):
     """Both blocks of a shape on the immediate walk."""
     if shape not in _ORACLE:
-        with pytest.MonkeyPatch.context() as m:
-            _route(m, "immediate")
-            _ORACLE[shape] = _emulate(shape, _blocks(shape)[2])
+        _ORACLE[shape] = _emulate(shape, _blocks(shape)[2], "immediate")
     return _ORACLE[shape]
 
 
-@pytest.mark.parametrize("route", list(ROUTES))
+@pytest.mark.parametrize("route", ROUTES)
 @pytest.mark.parametrize("shape", SHAPES)
-def test_every_setting_gives_the_same_bytes(shape, route, monkeypatch):
+def test_every_setting_gives_the_same_bytes(shape, route):
     want = _oracle(shape)
-    calls = _route(monkeypatch, route)
-    got = _emulate(shape, _blocks(shape)[2])
+    hashed = metrics.counter_value("trie_nodes_hashed_total") or 0
+    got = _emulate(shape, _blocks(shape)[2], route)
+    hashed = (metrics.counter_value("trie_nodes_hashed_total") or 0) - hashed
     for height, (mine, oracle) in enumerate(zip(got, want), start=1):
         assert mine[0] == oracle[0], (shape, height, "receipts")
         assert mine[1] == oracle[1], (shape, height, "roots")
         assert mine[2] == oracle[2], (shape, height, "pending node set")
-    # the route was the one asked for
-    if route == "immediate":
-        assert calls == []
-    else:
-        assert calls
-        want_threads = _host_cores() if route == "threaded" else 1
-        assert {threads for threads, _ in calls} == {want_threads}, route
-
-
-def test_a_block_of_hb64_size_hashes_on_one_thread(monkeypatch):
-    """hb64.full's blocks hold up to 1000 transfers (perfbench/configs/
-    hb64-sim.json txs_per_block): no level of their freeze comes near
-    MIN_HASH_THREAD_BYTES, so the program's byte rule hashes each on the
-    calling thread."""
-    _, _, (first, second) = _blocks("transfers")
-    block = first + second[: 1000 - len(first)]
-    assert len(block) == 1000
-    calls = _route(monkeypatch, "deferred")
-    _emulate("transfers", [block])
-    assert calls and {threads for threads, _ in calls} == {1}
-    largest = max(size for _, size in calls)
-    assert largest < trie_mod.MIN_HASH_THREAD_BYTES // 2, largest
+    # the route was the one asked for: only the native walk counts the
+    # nodes it hashed
+    assert (hashed > 0) == (route == "native"), (route, hashed)
 
 
 class CountingKV(MemoryKV):
@@ -193,15 +155,14 @@ class CountingKV(MemoryKV):
         return super().get(key)
 
 
-@pytest.mark.parametrize("route", ["deferred", "threaded"])
-def test_the_next_freeze_reads_back_no_node_the_last_one_wrote(route, monkeypatch):
-    """Commit a freeze, then freeze again over the same keys: whichever
-    side of the byte floor the first hashed on, what it wrote is in the
-    handle's cache, so the second asks the store for none of it."""
-    calls = _route(monkeypatch, route)
+@pytest.mark.parametrize("route", ROUTES)
+def test_the_next_freeze_reads_back_no_node_the_last_one_wrote(route):
+    """Commit a freeze, then freeze again over the same keys: what the
+    first wrote is in the handle's cache (the native table on the native
+    route), so the second asks the store for none of it."""
     rng = random.Random(route)
     kv = CountingKV()
-    trie = Trie(kv)
+    trie = Trie(kv, native=_native(route))
     keys = [rng.randbytes(20) for _ in range(1536)]
 
     def commit():
@@ -218,5 +179,4 @@ def test_the_next_freeze_reads_back_no_node_the_last_one_wrote(route, monkeypatc
     kv.read.clear()
     trie.apply_many(root, {k: b"2" for k in keys[:1024]})
     assert not wrote.intersection(kv.read)
-    want_threads = _host_cores() if route == "threaded" else 1
-    assert {threads for threads, _ in calls} == {want_threads}
+    assert (trie._table is not None) == (route == "native")

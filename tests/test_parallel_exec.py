@@ -2,10 +2,10 @@
 
 A block executes on one path: the serial executor over a snapshot, then
 `Snapshot.freeze`. Pinned here: over random executor blocks (transfers,
-failing txs, system-contract calls, wasm invocations) the deferred freeze
-gives receipts, roots and trie node sets bit-identical to the immediate
-walk; the delta-checkpoint undo log; canonical ordering; the sharded
-pool; the era report's exec row; and BlockManager's one lane.
+failing txs, system-contract calls, wasm invocations) the native walk
+gives receipts, roots and trie node sets bit-identical to the Python
+walk; the delta-checkpoint undo log; canonical ordering;
+the sharded pool; the era report's exec row; and BlockManager's one lane.
 """
 import random
 import threading
@@ -67,12 +67,13 @@ def _tx(priv, to, value, nonce, gas_price=1, gas_limit=100000, invocation=b""):
     return sign_transaction(tx, priv, CHAIN)
 
 
-def _fresh_chain():
+def _fresh_chain(native=False):
     """Fresh store with every pool account funded and one counter wasm
     contract deployed, all committed at height 0 (so the trie pending
-    buffer afterwards holds exactly the block-1 node set)."""
+    buffer afterwards holds exactly the block-1 node set); its trie walks
+    natively with `native`."""
     kv = MemoryKV()
-    state = StateManager(kv)
+    state = StateManager(kv, native=native)
     snap = state.new_snapshot()
     for _, addr in _ACCOUNTS:
         execution.set_balance(snap, addr, 10**18)
@@ -84,22 +85,18 @@ def _fresh_chain():
     return state, executer, roots, caddr
 
 
-def _run(ordered, monkeypatch, defer_from, thread_from):
-    """Block 1 on the serial executor, frozen with the trie's floors at
-    `defer_from` ops (MIN_DEFER_OPS) and `thread_from` bytes a level
-    (MIN_HASH_THREAD_BYTES) -> (receipts, roots, node keys, nodes the
-    deferred route hashed)."""
-    state, executer, base, _ = _fresh_chain()
+def _run(ordered, native=False):
+    """Block 1 on the serial executor, frozen by the native walk
+    (`native`) or by the Python walker -> (receipts, roots, node keys,
+    nodes the native walk hashed)."""
+    state, executer, base, _ = _fresh_chain(native)
     snap = state.new_snapshot(base)
     receipts = [
         executer.execute(snap, stx, 1, i).receipt
         for i, stx in enumerate(ordered)
     ]
     hashed = metrics.counter_value("trie_nodes_hashed_total") or 0
-    with monkeypatch.context() as m:
-        m.setattr(trie_mod, "MIN_DEFER_OPS", defer_from)
-        m.setattr(trie_mod, "MIN_HASH_THREAD_BYTES", thread_from)
-        roots = snap.freeze()
+    roots = snap.freeze()
     hashed = (metrics.counter_value("trie_nodes_hashed_total") or 0) - hashed
     nodes = {k for k, _ in state.trie.peek_pending()}
     return receipts, roots, nodes, hashed
@@ -153,37 +150,32 @@ def _random_block(rng, caddr, min_txs=24, max_txs=48):
 
 
 # ---------------------------------------------------------------------------
-# the headline differential: deferred freeze == immediate walk, bit for bit
+# the headline differential: native walk == Python walk, bit for bit
 # ---------------------------------------------------------------------------
 
 
-def test_differential_parallel_vs_serial_randomized(monkeypatch):
+def test_differential_parallel_vs_serial_randomized():
     """200 seeded random blocks: receipts, state roots AND the trie node
-    set must be bit-identical between the immediate per-node walk and the
-    deferred level-batched freeze, every other block with each level on
-    the hashing threads."""
+    set must be bit-identical between the Python walk, which hashes each
+    node as it is stored, and the native walk."""
+    assert trie_mod.native_walks() is not None
     failed = succeeded = 0
     _, _, _, caddr = _fresh_chain()
     for seed in range(200):
         rng = random.Random(seed)
         ordered = _random_block(rng, caddr)
-        i_receipts, i_roots, i_nodes, i_hashed = _run(
-            ordered, monkeypatch, 1 << 30, trie_mod.MIN_HASH_THREAD_BYTES
-        )
-        d_receipts, d_roots, d_nodes, d_hashed = _run(
-            ordered, monkeypatch, 1,
-            0 if seed % 2 else trie_mod.MIN_HASH_THREAD_BYTES,
-        )
-        assert [r.encode() for r in d_receipts] == [
+        i_receipts, i_roots, i_nodes, i_hashed = _run(ordered)
+        n_receipts, n_roots, n_nodes, n_hashed = _run(ordered, native=True)
+        assert [r.encode() for r in n_receipts] == [
             r.encode() for r in i_receipts
-        ], f"receipt divergence at seed {seed}"
-        assert d_roots == i_roots, f"root divergence at seed {seed}"
-        assert d_roots.state_hash() == i_roots.state_hash()
-        assert d_nodes == i_nodes, f"trie node set divergence at seed {seed}"
-        # each leg took its route: only the deferred one batch-hashes
-        assert i_hashed == 0 and d_hashed == len(d_nodes), seed
-        failed += sum(r.status != 1 for r in d_receipts)
-        succeeded += sum(r.status == 1 for r in d_receipts)
+        ], f"native receipt divergence at seed {seed}"
+        assert n_roots == i_roots, f"native root divergence at seed {seed}"
+        assert n_roots.state_hash() == i_roots.state_hash()
+        assert n_nodes == i_nodes, f"native node set divergence at seed {seed}"
+        # each leg took its route: the Python walk counts no node hashed
+        assert i_hashed == 0 and n_hashed == len(n_nodes), seed
+        failed += sum(r.status != 1 for r in n_receipts)
+        succeeded += sum(r.status == 1 for r in n_receipts)
     # the mix must hold failing and applied txs or the test proves little
     assert failed > 0
     assert succeeded > 0
