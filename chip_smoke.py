@@ -12,7 +12,10 @@ that needs jax). It fails unless jax found a TPU, and then:
             and a corrupted share isolates exactly its slot; (b) the coin
             era batch, 64 signers; (c) batched Reed-Solomon at (K, N) =
             (22, 64) vs scalar ops/rs.py; (d) 2048 ECDSA recoveries vs the
-            native library; (e) the 22-point G2 MSM the coin combine uses.
+            native library; (e) the 22-point G2 MSM the coin combine uses;
+            (f) batched Reed-Solomon over GF(2^16) at (K, N) = (86, 256),
+            the first committee past GF(2^8)'s 255 shards, vs the plain
+            codec perfbench/reference_rs16.py.
   warm-up   warmup_era_kernels(64) to completion.
   devnet    BASELINE.json config 4 with the upstream block defaults: N=64,
             f=21, 1000-tx blocks, native engine, batched RBC, the TPU
@@ -305,6 +308,39 @@ def check_rs() -> None:
         )
 
 
+def check_rs16() -> None:
+    """(f) batched RS over GF(2^16) at (86, 256): 256 proposals the size of
+    a 10,000-tx block's 39-tx share and twice that, encoded and decoded from
+    the last k shards on the device, against the plain codec."""
+    from lachain_tpu.ops import rs_batch
+    from perfbench import reference_rs16
+
+    n, f = 256, 85
+    k = n - 2 * f
+    rnd = random.Random(SEED)
+    for payload in (7400, 14800):
+        before = len(rs_device_spans())
+        products = counter("rs_matmul_total", path="device", bits=16)
+        data = [rnd.randbytes(payload) for _ in range(n)]
+        enc = rs_batch.encode_batch([(d, k, n) for d in data])
+        holes = [[None] * (n - k) + shards[n - k :] for shards in enc]
+        dec = rs_batch.decode_batch([(h, k) for h in holes])
+        spans = rs_device_spans()[before:]
+        cols = sorted({s["args"]["cols"] for s in spans})
+        check(
+            enc == reference_rs16.encode_many(data, k, n)
+            and dec == data
+            and all(reference_rs16.decode(h, k) == d for h, d in zip(holes[:8], data))
+            and len(spans) == 2
+            and all(s["args"]["bits"] == 16 for s in spans)
+            and counter("rs_matmul_total", path="device", bits=16) == products + 2
+            and cols[0] >= rs_batch._DEVICE_MIN_COLS,
+            f"(f) rs encode/decode ({k},{n}) over GF(2^16) x {n} payloads of "
+            f"{payload} B: equal to the plain codec, on the device at {cols} "
+            f"columns",
+        )
+
+
 def check_ecdsa(host) -> None:
     """(d) 2048 recoveries: ops/psecp.py vs the native library."""
     from lachain_tpu.crypto import ecdsa
@@ -452,6 +488,7 @@ def main() -> None:
     check_tpke(backend, host)
     check_coins(backend, host)
     check_rs()
+    check_rs16()
     check_ecdsa(host)
     peak_bytes()
 

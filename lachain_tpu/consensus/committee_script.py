@@ -18,13 +18,18 @@ What the peers send is built two ways:
   era less one, so that every proposal executes whole), TPKE-encrypted,
   RS-coded and Merkle-branched exactly as RBC does; the peers' VALs, ECHOs
   and READYs of those proposals; every peer's decryption share of every
-  peer ciphertext; every peer's share of every coin the committee's
-  agreements toss; the peers' BinaryBroadcast votes. `workers` > 1 builds
+  peer ciphertext; every peer's share of the header's nonce coin, which
+  every era tosses; the peers' BinaryBroadcast votes. `workers` > 1 builds
   the eras' tables in that many processes, which `start` sets going and
   `join` waits for, so that the caller can do other set-up meanwhile.
 * live answers, in `react` (what does): echoes of validator 0's own shards,
   readies for its root, decryption shares of its ciphertext, and header
   signatures over the block it built, read from its own signed header.
+  The peers' shares of an agreement's coin are computed when validator 0
+  asks for that coin: with every peer answering at once, the agreements
+  decide before their lag rounds reach the first real coin wherever the
+  era outlasts them (N=128 and N=256 toss none), so a table of them would
+  be more than half of each era's build, unread.
 
 Every share is computed with that peer's own key (consensus/keys.py
 trusted_key_gen) and every shard carries its real branch, so validator 0
@@ -39,7 +44,7 @@ from __future__ import annotations
 import random
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from ..utils import metrics, tracing
 from . import messages as M
@@ -59,10 +64,9 @@ from .native_rt import (
 )
 
 # the epochs a BinaryAgreement that decides 1 at epoch 3 runs (its lag
-# rounds end past epoch 3 + 2 * EXTRA_ROUNDS) and the one real coin among
-# them (binary_agreement.py coin_schedule)
+# rounds end past epoch 3 + 2 * EXTRA_ROUNDS; binary_agreement.py
+# coin_schedule)
 BB_EPOCHS = (0, 2, 4, 6, 8)
-COIN_EPOCH = 5
 NONCE_COIN = (-1, 0)  # root_protocol.py's nonce coin
 
 _BB_KIND = {MT_BVAL: "bval", MT_AUX: "aux", MT_CONF: "conf"}
@@ -163,7 +167,6 @@ def build_era(job: _EraJob) -> EraTable:
     from ..core.block_producer import encode_tx_batch
     from ..crypto import bls12381 as bls
     from ..crypto import hashes
-    from ..crypto import threshold_sig as ts
     from ..ops import rs_batch
 
     n, era = job.n, job.era
@@ -205,19 +208,29 @@ def build_era(job: _EraJob) -> EraTable:
             n, era, j, ciphertexts[j][: bls.G1_BYTES], xs, job.threads
         )
     table.val = b"".join(vals)
-    coins = [(a, COIN_EPOCH) for a in range(n)] + [NONCE_COIN]
-    for a, e in coins:
-        msg = M.CoinId(era=era, agreement=a, epoch=e).to_bytes()
-        h = bls.g2_to_bytes(ts._hash_to_sig_point(msg))
-        xs = [job.ts_x[p] + _wrong(job, "coin", (a, e), p) for p in peers]
-        table.coin[(a, e)] = b"".join(
-            encode_seam_record(
-                p, 0, MT_OPAQUE, agreement=a, epoch=e, opq_kind=KIND_COIN,
-                data=sigma + p.to_bytes(4, "big"), era=era,
-            )
-            for p, sigma in zip(peers, _shares(h, xs, job.threads))
-        )
+    xs = [job.ts_x[p] + _wrong(job, "coin", NONCE_COIN, p) for p in peers]
+    table.coin[NONCE_COIN] = coin_blob(n, era, NONCE_COIN, xs, job.threads)
     return table
+
+
+def coin_blob(
+    n: int, era: int, coin: Tuple[int, int], xs: Sequence[int], threads: int = 0
+) -> bytes:
+    """Peers 1..n-1's shares of the coin (agreement, epoch) of `era`: its id
+    signed with each peer's threshold-signature share (`xs`, by peer)."""
+    from ..crypto import bls12381 as bls
+    from ..crypto import threshold_sig as ts
+
+    agreement, epoch = coin
+    msg = M.CoinId(era=era, agreement=agreement, epoch=epoch).to_bytes()
+    h = bls.g2_to_bytes(ts._hash_to_sig_point(msg))
+    return b"".join(
+        encode_seam_record(
+            p, 0, MT_OPAQUE, agreement=agreement, epoch=epoch, opq_kind=KIND_COIN,
+            data=sigma + p.to_bytes(4, "big"), era=era,
+        )
+        for p, sigma in zip(range(1, n), _shares(h, xs, threads))
+    )
 
 
 def ready_blob(n: int, era: int, slot: int, root: bytes) -> bytes:
@@ -289,8 +302,10 @@ class CommitteeScript:
         self.tables: Dict[int, EraTable] = {}
         self._bb: Dict[Tuple[int, int, int], bytes] = {}
         self._answered: set = set()
-        # validator 0's own proposal, as the peers rebuild it from its VALs
+        # validator 0's own proposal, as the peers rebuild it from its VALs,
+        # and the roots those VALs carried
         self._own_shards: Dict[int, Dict[int, bytes]] = {}
+        self._own_roots: Dict[int, Set[bytes]] = {}
         self.own_ciphertexts: Dict[int, bytes] = {}
         self.problems: List[str] = []
 
@@ -408,6 +423,11 @@ class CommitteeScript:
             out[0] = self.own_ciphertexts[era]
         return out
 
+    def own_vals(self, era: int) -> Tuple[Dict[int, bytes], Set[bytes]]:
+        """What validator 0 sent the peers of its own proposal in `era`: the
+        shard each peer got, by peer, and the roots the VALs carried."""
+        return dict(self._own_shards.get(era, {})), set(self._own_roots.get(era, ()))
+
     # -- live answers -----------------------------------------------------------
     def react(self, records: Sequence[SeamRecord]) -> List[Tuple[int, bytes, int]]:
         """The peers' answers to what validator 0 sent them: (era, blob of
@@ -473,6 +493,7 @@ class CommitteeScript:
             if self._once(era, "val"):
                 out.append((era, table.val, n - 1))
             self._own_shards.setdefault(era, {})[r.target] = r.data
+            self._own_roots.setdefault(era, set()).add(r.root)
             out.append(
                 (
                     era,
@@ -501,12 +522,14 @@ class CommitteeScript:
         elif kind == "coin":
             key = (r.agreement, r.epoch)
             if self._once(era, "coin", key):
-                if key in table.coin:
-                    out.append((era, table.coin[key], n - 1))
-                else:
-                    self.problems.append(
-                        f"era {era}: no table holds the peers' shares of coin {key}"
-                    )
+                blob = table.coin.get(key)
+                if blob is None:
+                    xs = [
+                        self._priv[p].ts_share.x_i + (("coin", era, key, p) in self._faults)
+                        for p in range(1, n)
+                    ]
+                    blob = coin_blob(n, era, key, xs)
+                out.append((era, blob, n - 1))
         elif kind == "dec":
             if self._once(era, "dec", r.agreement):
                 out.append((era, self._dec(era, r.agreement), n - 1))

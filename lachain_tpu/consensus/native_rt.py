@@ -1225,15 +1225,23 @@ class NativeSimulatedNetwork:
                 tracing.drain_native()
             except Exception:
                 pass
-            tracing.unregister_native_source(self._trace_source)
-            for h in self._live_engines():
-                self._lib.rt_free(h)
-            self._era_engines = {}
-            self._h = None
+            self._free_engines()
+
+    def _free_engines(self) -> None:
+        tracing.unregister_native_source(self._trace_source)
+        for h in self._live_engines():
+            self._lib.rt_free(h)
+        self._era_engines = {}
+        self._h = None
 
     def __del__(self):  # pragma: no cover
+        # the collector runs this wherever it interrupts a thread, also
+        # inside the metrics registry's lock (metrics.measure): a drain
+        # records metrics and would wait on that lock for ever, so a
+        # network nobody closed loses its undrained ring events instead
         try:
-            self.close()
+            if self._h is not None or self._era_engines:
+                self._free_engines()
         except Exception:
             pass
 

@@ -300,6 +300,7 @@ def _matmul_device(field: GF, a: np.ndarray, b: np.ndarray, key: tuple, era=None
         era=era,
         bits=field.bits,
         rows=int(a.shape[0]),
+        k=int(a.shape[1]),
         cols=int(c),
         cols_padded=int(c_pad),
         devices=int(ndev),
@@ -326,9 +327,9 @@ def _matmul(
     # a device failure propagates: the numpy path is for small products
     # and host-backend processes, not a landing for exceptions
     if b.shape[1] >= _DEVICE_MIN_COLS and device_enabled():
-        metrics.inc("rs_matmul_total", labels={"path": "device"})
+        metrics.inc("rs_matmul_total", labels={"path": "device", "bits": field.bits})
         return _matmul_device(field, a, b, key, era=era)
-    metrics.inc("rs_matmul_total", labels={"path": "host"})
+    metrics.inc("rs_matmul_total", labels={"path": "host", "bits": field.bits})
     return field.matmul(a, b)
 
 

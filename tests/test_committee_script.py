@@ -18,6 +18,7 @@ from lachain_tpu.core.block_producer import decode_tx_batch
 from lachain_tpu.core.devnet import CommitteeValidator, Devnet, devnet_keys
 from lachain_tpu.core.types import Transaction, sign_transaction
 from lachain_tpu.crypto import ecdsa
+from perfbench import reference_rs16
 from perfbench.reference_share import ShareReference, master_secret, multisig_failures
 
 CHAIN = 225
@@ -146,6 +147,74 @@ def test_peers_propose_their_own_transfers_and_every_proposal_executes(n, f):
             assert len(block.tx_hashes) >= peer_txs
             for h in block.tx_hashes:
                 assert v.node.block_manager.receipt_by_hash(h) is not None
+    finally:
+        v.close()
+
+
+def test_validator_in_a_committee_of_256_commits_and_codes_over_gf16():
+    """N=256, f=85, the first committee past GF(2^8)'s 255 shards, one
+    validator against the script (a real Devnet would be 256 engines in one
+    process): every era commits the reference's block with N - f
+    signatures; the VALs validator 0 sent the peers are the plain GF(2^16)
+    codec's shards of its proposal under the root they carry; and every
+    peer's proposal, encoded by the plain codec, gives the root the script
+    sent with it."""
+    from lachain_tpu.crypto import hashes
+
+    n, f = 256, 85
+    k, txs_per_block = n - 2 * f, 4 * n
+    keys = _clients()
+    pub, priv = devnet_keys(n, f, SEED)
+    script = CommitteeScript(
+        pub, priv, chain_id=CHAIN, seed=SEED, txs_per_block=txs_per_block,
+        eras=ERAS, workers=2,
+    )
+    vals = {}  # era -> peer -> (root, shard) of validator 0's VAL to it
+    react = script.react
+
+    def seen(records):
+        for r in records:
+            if r.type == native_rt.MT_VAL:
+                vals.setdefault(r.era, {})[r.target] = (r.root, r.data)
+        return react(records)
+
+    script.react = seen
+    script.start()
+    v = CommitteeValidator(
+        pub, priv[0], script, chain_id=CHAIN, seed=SEED,
+        txs_per_block=txs_per_block,
+        initial_balances={**_balances(keys), **script.peer_balances()},
+    )
+    try:
+        script.join()
+        for stx in _client_txs(keys):
+            assert v.submit_tx(stx)
+        ref = ShareReference(priv, f, CHAIN)
+        peers = range(1, n)
+        for era in range(1, ERAS + 1):
+            block = v.run_era(era)
+            expected = ref.block(era, script.ciphertexts(era))
+            assert sorted(expected.plaintexts) == list(range(n))
+            assert ref.compare(era, block, expected, v.router.coin_values(era)) == []
+            assert multisig_failures(block, pub.ecdsa_pub_keys, n - f) == []
+            assert len(block.tx_hashes) >= (n - 1) * (txs_per_block // n)
+            sent = vals[era]
+            assert sorted(sent) == list(peers)
+            (root,) = {r for r, _shard in sent.values()}
+            assert script.own_vals(era) == ({p: sh for p, (_r, sh) in sent.items()}, {root})
+            held = [None] + [sent[p][1] for p in peers]
+            payload = reference_rs16.decode(held, k)
+            assert payload == script.ciphertexts(era)[0]
+            shards = reference_rs16.encode(payload, k, n)
+            assert shards[1:] == held[1:]
+            assert hashes.merkle_root(hashes.keccak256_batch(shards)) == root
+            table = script.tables[era]
+            roots = {r.agreement: r.root for r in native_rt.decode_seam_records(table.val)}
+            plain = reference_rs16.encode_many([table.ciphertexts[j] for j in peers], k, n)
+            assert [hashes.merkle_root(hashes.keccak256_batch(sh)) for sh in plain] == [
+                roots[j] for j in peers
+            ]
+        assert script.problems == []
     finally:
         v.close()
 

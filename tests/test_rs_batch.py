@@ -375,10 +375,16 @@ def test_device_product_equals_numpy(name, devices, monkeypatch):
 
 
 def _device_counts():
+    """rs_matmul_total by path, summed over its `bits` label."""
     from lachain_tpu.utils import metrics
 
+    series = metrics.counters_with_prefix("rs_matmul_total")
     return tuple(
-        metrics.counter_value("rs_matmul_total", labels={"path": p})
+        sum(
+            v
+            for (name, labels), v in series.items()
+            if name == "rs_matmul_total" and ("path", p) in labels
+        )
         for p in ("device", "host")
     )
 
@@ -413,6 +419,36 @@ def test_new_matrix_same_shape_compiles_nothing(monkeypatch):
     # under the column floor: the host path, once a product
     rs_batch.encode_batch([(b"small", 3, 7), (b"other", 2, 4)])
     assert _device_counts() == (before[0] + 4, before[1] + 2)
+
+
+def test_a_product_counts_under_its_field_and_its_span_carries_its_shape(monkeypatch):
+    """rs_matmul_total{path, bits}: a GF(2^16) product counts under bits
+    16, a GF(2^8) one under bits 8; the rs.device span carries every
+    dimension of the product (rows, k, cols_padded), from which its work
+    is counted."""
+    from lachain_tpu.utils import metrics, tracing
+
+    monkeypatch.setenv("LACHAIN_RS_DEVICE", "1")
+
+    def count(bits):
+        return metrics.counter_value(
+            "rs_matmul_total", labels={"path": "device", "bits": bits}
+        )
+
+    before = count(16), count(8)
+    n, k = 300, 4
+    data = random.Random(3).randbytes(2 * k * 4100)  # 4101 columns
+    since = tracing.snapshot()[-1]["id"] if tracing.snapshot() else -1
+    assert rs_batch.encode_batch([(data, k, n)])[0] == rs_batch.encode(data, k, n)
+    assert (count(16), count(8)) == (before[0] + 2, before[1])
+    spans = [
+        s for s in tracing.snapshot() if s["name"] == "rs.device" and s["id"] > since
+    ]
+    assert spans and all(
+        (s["args"]["bits"], s["args"]["rows"], s["args"]["k"], s["args"]["cols_padded"])
+        == (16, n, k, 8192)
+        for s in spans
+    )
 
 
 # --- end-to-end: block-hash identity on vs off, both engines -----------------
